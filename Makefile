@@ -96,7 +96,7 @@ master-drill: reap
 	set -o pipefail; timeout -k 10 600 env JAX_PLATFORMS=cpu python -m pytest tests/test_master_drill.py -q -m chaos -p no:cacheprovider -p no:xdist -p no:randomly
 
 native:
-	@if [ -f elasticdl_tpu/native/Makefile ]; then $(MAKE) -C elasticdl_tpu/native; else echo "native kernels not present yet"; fi
+	python -c "from elasticdl_tpu import native; print(native.build())"
 
 # The CI lane: lint -> tier-1 -> bench regression gate, each stage runs
 # even when an earlier one fails (one run answers "what is broken"), and

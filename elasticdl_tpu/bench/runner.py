@@ -159,10 +159,29 @@ def run_full(watchdog_s=None, budget_s=None, with_matrix=True,
     _arm_flightrec()
     clock = BudgetClock(budget_s)
     windows = knobs.get_int("ELASTICDL_BENCH_WINDOWS")
+    device = jax.devices()[0]
     details = {
-        "device_kind": jax.devices()[0].device_kind,
-        "n_devices": max(jax.local_device_count(), 1),
+        "platform": device.platform,
+        "device_kind": device.device_kind,
+        "n_devices": jax.local_device_count(),
     }
+    if device.platform != "tpu":
+        # A measurement path that finds no chip fails: four rounds of
+        # CPU runs were once checked in under device-metric names.
+        details["error"] = (
+            f"no TPU: jax found platform {device.platform!r} "
+            f"({device.device_kind}); the full bench measures the chip "
+            "and has no CPU mode (use --smoke for the harness check)"
+        )
+        print(details["error"], file=sys.stderr)
+        _emit(
+            {
+                "metric": "bench (full)", "value": None, "unit": None,
+                "vs_baseline": None, "details": details,
+            },
+            out_path,
+        )
+        return 2
     if budget_s:
         details["budget_s"] = budget_s
     # Suite order: recsys + PS benches and the rejoin drill FIRST, the
@@ -231,6 +250,7 @@ def run_full(watchdog_s=None, budget_s=None, with_matrix=True,
         ),
     ]
     measured = {}
+    failures = 0
     try:
         for key, name, fn, timeout_s, round_result in suite:
             # A spent budget SKIPS remaining benchmarks instead of
@@ -252,6 +272,8 @@ def run_full(watchdog_s=None, budget_s=None, with_matrix=True,
                 timeout_s = min(timeout_s, max(clock.remaining(), 1.0))
             result = _measured(name, fn, timeout_s, measured, key)
             details[key] = _round_if_ok(result) if round_result else result
+            if not isinstance(result, dict) or "error" in result:
+                failures += 1
     finally:
         _attach_attribution(details, measured)
         deepfm = details.get("deepfm_criteo") or {}
@@ -261,6 +283,7 @@ def run_full(watchdog_s=None, budget_s=None, with_matrix=True,
             )
         if budget_s:
             details["budget_elapsed_s"] = round(clock.elapsed(), 2)
+        details["failures"] = failures
         attach_verdict(details)
         # LocalTrainer's jitted step runs on exactly one device, so its
         # examples/sec IS the per-chip figure regardless of how many
@@ -287,7 +310,8 @@ def run_full(watchdog_s=None, budget_s=None, with_matrix=True,
             },
             out_path,
         )
-    return 0
+    # The JSON line is out; a workload that failed still fails the run.
+    return 1 if failures else 0
 
 
 def run_smoke(watchdog_s=None, budget_s=None, out_path=None,

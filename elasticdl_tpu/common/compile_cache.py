@@ -1,26 +1,21 @@
 """Persistent XLA compilation cache wiring (recompile-free elasticity).
 
-The compile tracker proved that compile IS the elastic rejoin: every
-worker relaunch — the common preemption case — cold-compiled a step this
-host had already compiled, minutes of accumulated dead time at
-production pod-churn rates. jax ships a content-addressed persistent
-compilation cache (HLO-keyed executables on disk); this module is the
-one place the framework turns it on, from the registered
-`ELASTICDL_COMPILE_CACHE_DIR` knob, so that:
+Compile IS the elastic rejoin: a relaunched worker, a regrouped world
+and a speculated world all lower programs this host has already
+compiled. jax ships a content-addressed persistent compilation cache;
+this module is the one place the framework decides WHERE it lives:
 
-- a RELAUNCHED worker rehydrates its step executables from disk and
-  pays only trace+lower on its first minibatch (the `compile_cache_hit`
-  event in observability/profiling.py, not a cold `compile`);
-- a multi-host regroup that re-initializes jax.distributed (tearing
-  down every live executable) re-lowers into warm disk entries;
-- SPECULATIVE world compiles (worker/world_speculator.py) persist: even
-  when the guessed executable object dies with a backend re-init, its
-  disk entry survives for the re-lowering on the other side.
+- `JAX_COMPILATION_CACHE_DIR` set: jax reads that variable itself and
+  this module sets no directory in code — the machine (or the chip
+  tool) places the cache.
+- unset: one fixed path inside the checkout, `<repo>/.jax_cache`. Never
+  a temporary directory, a pid or a timestamp: the path is part of what
+  makes a second run hit.
 
-Both instance managers stamp the knob into every child's environment,
-so one `edl train` invocation warms a single cache for the whole job
-(all ranks lower the same SPMD program — one rank's miss is every
-later rank's hit).
+Every process of a job resolves the same directory: children inherit
+the environment, and the fixed path depends only on where the package
+is. To run cold on purpose use jax's own switch
+(`JAX_ENABLE_COMPILATION_CACHE=false`), not a second directory.
 
 Thresholds are zeroed (`min_compile_time_secs`, `min_entry_size`):
 elasticity cares about the many small programs around the step (eval
@@ -30,61 +25,49 @@ forwards, broadcast zero-templates), not only the headline compile.
 import os
 import threading
 
-from elasticdl_tpu.common import knobs
 from elasticdl_tpu.common.log_utils import get_logger
 
 logger = get_logger("common.compile_cache")
 
-CACHE_DIR_ENV = "ELASTICDL_COMPILE_CACHE_DIR"
+JAX_CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
+
+_REPO_ROOT = os.path.dirname(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+)
+FIXED_CACHE_DIR = os.path.join(_REPO_ROOT, ".jax_cache")
 
 _lock = threading.Lock()
-_configured = None  # dir string once wired, "" once checked-and-disabled
+_configured = None  # the resolved directory once wired
+
+
+def resolve_cache_dir():
+    """The directory every process of a job caches in."""
+    return os.environ.get(JAX_CACHE_DIR_ENV) or FIXED_CACHE_DIR
 
 
 def ensure_compile_cache():
-    """Idempotently point jax at the persistent compilation cache
-    directory named by ELASTICDL_COMPILE_CACHE_DIR. Returns the dir, or
-    None when the knob is unset (or jax lacks the config surface). Safe
-    to call from every trainer/bench/role entrypoint — the first caller
-    wins, later calls are a lock + string compare."""
+    """Idempotently wire jax's persistent compilation cache and return
+    its directory. Safe to call from every trainer/bench/role entrypoint
+    — the first caller wins, later calls are a lock + compare. A
+    directory that cannot be created raises: a job that silently
+    compiles everything cold is the failure this module exists to
+    prevent."""
     global _configured
     with _lock:
         if _configured is not None:
-            return _configured or None
-        cache_dir = knobs.get_str(CACHE_DIR_ENV)
-        if not cache_dir:
-            _configured = ""
-            return None
-        try:
-            os.makedirs(cache_dir, exist_ok=True)
-            import jax
+            return _configured
+        import jax
 
+        cache_dir = resolve_cache_dir()
+        os.makedirs(cache_dir, exist_ok=True)
+        if not os.environ.get(JAX_CACHE_DIR_ENV):
             jax.config.update("jax_compilation_cache_dir", cache_dir)
-            # Cache EVERYTHING: the defaults skip sub-second compiles
-            # and small executables, which is exactly the long tail a
-            # relaunched worker re-pays (eval forward, state templates).
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", 0.0
-            )
-            jax.config.update(
-                "jax_persistent_cache_min_entry_size_bytes", -1
-            )
-        except Exception:
-            logger.warning(
-                "Could not enable the persistent compilation cache at "
-                "%s; compiles will not survive relaunches",
-                cache_dir,
-                exc_info=True,
-            )
-            _configured = ""
-            return None
+        # Cache EVERYTHING: the defaults skip sub-second compiles and
+        # small executables, which is exactly the long tail a relaunched
+        # worker re-pays (eval forward, state templates).
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
         _configured = cache_dir
         logger.info("Persistent compilation cache at %s", cache_dir)
         return cache_dir
 
-
-def reset_for_tests():
-    """Drop the memoized wiring so a test can re-point the cache."""
-    global _configured
-    with _lock:
-        _configured = None

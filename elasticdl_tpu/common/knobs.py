@@ -200,9 +200,6 @@ declare("ELASTICDL_MEM_WATERMARK_RATIO", "float", 1.2,
 declare("ELASTICDL_MFU", "str", "auto",
         "MFU instrumentation: 1/true forces on, 0/false forces off, "
         "\"auto\" activates only where observability.setup() ran.")
-declare("ELASTICDL_PEAK_FLOPS", "float", 0.0,
-        "Per-device peak FLOP/s override for MFU; 0 falls back to the "
-        "device-kind table.")
 
 # -- data-plane instrumentation (observability/datapath.py) --
 declare("ELASTICDL_DATAPATH", "int", 1,
@@ -326,12 +323,6 @@ declare("ELASTICDL_PREFETCH_CACHE_STALENESS", "int", 8,
         "Negative disables the version check (never invalidate).")
 
 # -- recompile-free elasticity (common/compile_cache.py, worker/) --
-declare("ELASTICDL_COMPILE_CACHE_DIR", "str", "",
-        "Directory for jax's persistent compilation cache: step "
-        "executables are rehydrated from disk across process relaunches "
-        "(the common preemption case), so a relaunched worker's first "
-        "step pays trace+lower instead of a full XLA compile. Stamped "
-        "into child env by both instance managers; empty disables.")
 declare("ELASTICDL_AOT_SPECULATE", "str", "auto",
         "Speculative ahead-of-time world compilation: a background "
         "thread compiles the step of candidate nearby worlds (keyed by "

@@ -101,21 +101,13 @@ class LocalProcessInstanceManager:
     def _launch(self, kind, instance_id):
         argv = self._command_for(kind, instance_id)
         # Children get the master's environment (log level/format,
-        # observability dir/job, chaos schedule all ride along) plus a
+        # observability dir/job, chaos schedule, JAX_COMPILATION_CACHE_DIR
+        # all ride along — every child resolves the ONE compile cache
+        # the master resolved, common/compile_cache.py) plus a
         # per-instance ELASTICDL_ROLE stamp, so every process of one
         # chaos run logs with a correlatable identity.
         env = dict(os.environ)
         env["ELASTICDL_ROLE"] = f"{kind}-{instance_id}"
-        # Explicit stamp (not just environ inheritance): every child of
-        # this job shares ONE persistent compilation cache, so a
-        # relaunched instance rehydrates the executables its previous
-        # incarnation (or any peer lowering the same SPMD program)
-        # already compiled — the recompile-free preemption path.
-        from elasticdl_tpu.common import knobs
-
-        cache_dir = knobs.raw("ELASTICDL_COMPILE_CACHE_DIR")
-        if cache_dir:
-            env["ELASTICDL_COMPILE_CACHE_DIR"] = cache_dir
         popen = subprocess.Popen(
             argv, stdout=sys.stdout, stderr=sys.stderr, env=env
         )

@@ -283,13 +283,15 @@ class Master:
             # cache dir follows so every pod of the job shares ONE
             # persistent cache (a relaunched pod rehydrates executables
             # its predecessor or peers already compiled).
-            for var in (
-                "ELASTICDL_LOG_LEVEL",
-                "ELASTICDL_LOG_FORMAT",
-                "ELASTICDL_COMPILE_CACHE_DIR",
-            ):
+            for var in ("ELASTICDL_LOG_LEVEL", "ELASTICDL_LOG_FORMAT"):
                 if knobs.is_set(var):
                     envs[var] = knobs.raw(var)
+            for var in (
+                "JAX_COMPILATION_CACHE_DIR",
+                "JAX_ENABLE_COMPILATION_CACHE",
+            ):
+                if os.environ.get(var):
+                    envs[var] = os.environ[var]
             return K8sInstanceManager(
                 args.namespace,
                 args.job_name,

@@ -5,13 +5,18 @@ The reference reaches its C++ kernels through Go's cgo
 parameter server calls the shared library directly via ctypes — no binding
 codegen, no copy: numpy arrays pass as raw pointers.
 
-`lib()` lazily builds libedl_kernels.so with the package Makefile on first
-use (g++ is in the base image), so a fresh checkout needs no explicit build
-step; set EDL_NO_NATIVE=1 to force the pure-numpy fallbacks in
-elasticdl_tpu/ps/optimizer.py.
+`lib()` builds the library with the package Makefile on first use, from
+the committed .cc files, on the machine that runs it. The file name
+carries a hash of the sources and the Makefile, so a clean checkout and a
+used one load the same thing: a stale or foreign binary has another name
+and is never picked up. A library that cannot be built or loaded RAISES —
+a PS quietly several times slower on numpy is not a fallback anyone asked
+for. EDL_NO_NATIVE=1 is the explicit way to run the pure-numpy paths
+(elasticdl_tpu/ps/optimizer.py), which the tests keep honest.
 """
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -21,7 +26,7 @@ from elasticdl_tpu.common.log_utils import get_logger
 logger = get_logger("native")
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
-_SO = os.path.join(_HERE, "libedl_kernels.so")
+_SOURCES = ("kernels.cc", "recordio.cc", "idmap.cc")
 _lock = threading.Lock()
 _lib = None
 
@@ -86,15 +91,38 @@ def _declare(lib):
     return lib
 
 
-def build():
-    subprocess.run(
-        ["make", "-s", "-C", _HERE], check=True, capture_output=True
+def _so_path():
+    digest = hashlib.sha1()
+    for name in _SOURCES + ("Makefile",):
+        with open(os.path.join(_HERE, name), "rb") as f:
+            digest.update(f.read())
+    return os.path.join(
+        _HERE, f"libedl_kernels-{digest.hexdigest()[:12]}.so"
     )
 
 
+def build():
+    """Build the library for the current sources; returns its path.
+    Concurrent builders (two PS shards, xdist workers) each compile to a
+    private name and publish with an atomic rename."""
+    so = _so_path()
+    tmp = f"libedl_kernels-build-{os.getpid()}.so"
+    try:
+        subprocess.run(
+            ["make", "-s", "-C", _HERE, f"OUT={tmp}"],
+            check=True, capture_output=True, text=True,
+        )
+    except subprocess.CalledProcessError as e:
+        raise RuntimeError(
+            f"building the native kernels failed: {e.stderr[-2000:]}"
+        ) from e
+    os.replace(os.path.join(_HERE, tmp), so)
+    return so
+
+
 def lib():
-    """The loaded shared library, building it on first call. Returns None
-    when natives are disabled or the toolchain is unavailable."""
+    """The loaded shared library, building it on first call. None only
+    when EDL_NO_NATIVE is set; a failed build or load raises."""
     global _lib
     if _lib is not None:
         return _lib or None
@@ -104,22 +132,12 @@ def lib():
         if os.environ.get("EDL_NO_NATIVE"):
             _lib = False
             return None
-        try:
-            sources = ("kernels.cc", "recordio.cc", "idmap.cc")
-            if not os.path.exists(_SO) or any(
-                os.path.getmtime(_SO)
-                < os.path.getmtime(os.path.join(_HERE, src))
-                for src in sources
-            ):
-                build()
-            _lib = _declare(ctypes.CDLL(_SO))
-            logger.info("Loaded native kernels from %s", _SO)
-        except Exception as e:
-            logger.warning(
-                "Native kernels unavailable (%s); numpy fallbacks in use", e
-            )
-            _lib = False
-    return _lib or None
+        so = _so_path()
+        if not os.path.exists(so):
+            build()
+        _lib = _declare(ctypes.CDLL(so))
+        logger.info("Loaded native kernels from %s", so)
+    return _lib
 
 
 def available():

@@ -90,49 +90,51 @@ def host_peak_rss_bytes():
         return None
 
 
-def device_live_bytes():
-    """Sum of live jax array bytes; None when jax is absent/unloaded.
-    Only counts arrays already materialized — cheap relative to any
-    actual training step."""
+def _jax_backend_up():
+    """True only when THIS process has already initialised a jax
+    backend. The sampler reads memory from a backend that is up and
+    never brings one up: a chip belongs to one process, and the master
+    and every PS import jax (through the user's model module) without
+    ever computing on it — a sample there must not claim the device the
+    worker needs."""
     import sys
 
     if "jax" not in sys.modules:
-        return None  # never force the jax import from a sampler thread
-    try:
-        import jax
+        return False
+    from jax._src import xla_bridge
 
-        return sum(
-            int(getattr(a, "nbytes", 0)) for a in jax.live_arrays()
-        )
-    except Exception:
+    return xla_bridge.backends_are_initialized()
+
+
+def device_live_bytes():
+    """Sum of live jax array bytes; None when this process runs no jax
+    backend (see _jax_backend_up)."""
+    if not _jax_backend_up():
         return None
+    import jax
+
+    return sum(int(getattr(a, "nbytes", 0)) for a in jax.live_arrays())
 
 
 def device_memory_stats():
     """{device_label: {stat: bytes}} from backends that report them
-    (TPU/GPU); {} on CPU."""
-    import sys
-
-    if "jax" not in sys.modules:
+    (TPU/GPU); {} on CPU and in processes that run no jax backend."""
+    if not _jax_backend_up():
         return {}
+    import jax
+
     out = {}
-    try:
-        import jax
-
-        for d in jax.local_devices():
-            stats = d.memory_stats()
-            if not stats:
-                continue
-            picked = {
-                k: v
-                for k, v in stats.items()
-                if k in ("bytes_in_use", "peak_bytes_in_use",
-                         "bytes_limit")
-            }
-            if picked:
-                out[f"{d.platform}:{d.id}"] = picked
-    except Exception:
-        return {}
+    for d in jax.local_devices():
+        stats = d.memory_stats()
+        if not stats:
+            continue
+        picked = {
+            k: v
+            for k, v in stats.items()
+            if k in ("bytes_in_use", "peak_bytes_in_use", "bytes_limit")
+        }
+        if picked:
+            out[f"{d.platform}:{d.id}"] = picked
     return out
 
 

@@ -13,10 +13,12 @@ arguments to a StepCostModel once per minibatch. The model:
   known, `edl_worker_mfu` gauges that the master's aggregator re-exports
   as `edl_job_mfu{worker=...}`.
 
-Everything is guarded: a backend without cost_analysis, an un-lowerable
-step, or an unknown peak simply leaves the gauges absent — never a
-training failure. ELASTICDL_MFU=0 disables the lowering entirely;
-ELASTICDL_PEAK_FLOPS overrides (or provides) the per-device peak.
+A backend without cost_analysis or an un-lowerable step leaves the
+gauges absent — never a training failure. ELASTICDL_MFU=0 disables the
+lowering entirely. The peak comes from ONE table keyed by the
+`device_kind` jax reports (the bench reads the same table); a device
+that is not in it has no MFU — `peak_flops` raises, so nothing prints a
+utilization against a guessed denominator.
 """
 
 import threading
@@ -29,19 +31,36 @@ from elasticdl_tpu.observability.metrics import default_registry
 logger = get_logger("observability.mfu")
 
 MFU_ENV = "ELASTICDL_MFU"
-PEAK_FLOPS_ENV = "ELASTICDL_PEAK_FLOPS"
 
-# Dense peak FLOP/s by device kind (bf16, no sparsity), for the common
-# TPU generations; anything unrecognized needs ELASTICDL_PEAK_FLOPS.
-_DEVICE_PEAK_FLOPS = {
-    "TPU v2": 22.5e12,
-    "TPU v3": 61.25e12,  # per-chip: 2 cores x 30.6 TF/s
+# Peak dense bf16 FLOP/s of one chip, keyed by `device.device_kind`
+# exactly as jax reports it. Source: Google Cloud TPU documentation,
+# "System architecture" page of each generation (v4: 275 TFLOP/s;
+# v5e, reported as "TPU v5 lite": 197; v5p, reported as "TPU v5": 459;
+# v6e, reported as "TPU v6 lite": 918). No override and no default: an
+# unknown device is an error wherever an MFU would be printed.
+PEAK_BF16_FLOPS_BY_KIND = {
     "TPU v4": 275e12,
     "TPU v5 lite": 197e12,
-    "TPU v5e": 197e12,
-    "TPU v5p": 459e12,
-    "TPU v6e": 918e12,
+    "TPU v5": 459e12,
+    "TPU v6 lite": 918e12,
 }
+
+
+class UnknownDeviceError(LookupError):
+    """The device kind has no entry in PEAK_BF16_FLOPS_BY_KIND."""
+
+
+def peak_flops(device_kind):
+    """Peak bf16 FLOP/s of one `device_kind` chip; raises
+    UnknownDeviceError for a kind the table does not list."""
+    try:
+        return PEAK_BF16_FLOPS_BY_KIND[device_kind]
+    except KeyError:
+        raise UnknownDeviceError(
+            f"no peak FLOP/s known for device kind {device_kind!r}; "
+            f"known: {sorted(PEAK_BF16_FLOPS_BY_KIND)}"
+        ) from None
+
 
 _REG = default_registry()
 _STEP_FLOPS = _REG.gauge(
@@ -76,24 +95,6 @@ def enabled():
     return observability.current_handle() is not None
 
 
-def peak_flops():
-    """Per-device peak FLOP/s: env override first, then the device-kind
-    table; None when unknown (MFU gauge stays absent then)."""
-    override = knobs.get_float(PEAK_FLOPS_ENV)
-    if override:
-        return override
-    try:
-        import jax
-
-        kind = jax.devices()[0].device_kind
-    except Exception:
-        return None
-    for name, peak in _DEVICE_PEAK_FLOPS.items():
-        if kind.lower().startswith(name.lower()):
-            return peak
-    return None
-
-
 def shape_key(args):
     """Hashable (shape, dtype) signature of a step's argument pytree."""
     import jax
@@ -109,13 +110,8 @@ def _analyzed_flops(jitted, spec):
     """FLOPs from XLA's compiled-cost analysis; None when unavailable.
     `spec` is a ShapeDtypeStruct pytree (AOT lowering needs shapes only —
     never live buffers, which the real step may have donated by the time
-    the analysis thread runs). cost_analysis() returns a dict (newer jax)
-    or a list of per-module dicts (this image's 0.4.x) — handle both."""
+    the analysis thread runs)."""
     analysis = jitted.lower(*spec).compile().cost_analysis()
-    if analysis is None:
-        return None
-    if isinstance(analysis, (list, tuple)):
-        analysis = analysis[0] if analysis else None
     if not analysis:
         return None
     flops = analysis.get("flops")
@@ -132,7 +128,17 @@ class StepCostModel:
 
     def __init__(self):
         self._enabled = enabled()
-        self._peak = peak_flops() if self._enabled else None
+        self._peak = None
+        if self._enabled:
+            import jax
+
+            kind = jax.devices()[0].device_kind
+            try:
+                self._peak = peak_flops(kind)
+            except UnknownDeviceError:
+                # Not printed, so not an error: the FLOPs and period
+                # gauges still export, the MFU gauge stays absent.
+                logger.info("No MFU gauge on device kind %r", kind)
         # shape key -> float (analyzed) | None (failed) | _PENDING
         self._flops = {}
         self._last_ts = None

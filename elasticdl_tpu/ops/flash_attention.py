@@ -1,5 +1,7 @@
-"""Flash attention for TPU via Pallas — fused forward AND backward — with an
-XLA reference fallback.
+"""Flash attention for TPU via Pallas — fused forward AND backward. Off the
+TPU (the CPU test platform) the same API runs a plain XLA reference; on
+the TPU a request the kernel cannot serve raises — nothing quietly takes
+the O(S^2) path on the chip.
 
 No reference-framework counterpart (the reference is DP-only and has no
 attention ops; SURVEY.md §5 marks long-context as absent upstream) — this is
@@ -47,6 +49,10 @@ LANES = 128  # lane replication for row statistics (lse, delta)
 
 
 def _use_pallas():
+    """The kernel runs on the TPU, and on the CPU only under the
+    test-only EDL_FORCE_PALLAS_INTERPRET switch. Every other backend
+    gets `reference_attention` — which is what the CPU tests compare the
+    kernel with, not a fallback the chip path may take."""
     if os.environ.get("EDL_FORCE_PALLAS_INTERPRET"):
         return True
     return jax.default_backend() == "tpu"
@@ -54,6 +60,37 @@ def _use_pallas():
 
 def _interpret():
     return bool(os.environ.get("EDL_FORCE_PALLAS_INTERPRET"))
+
+
+def _per_batch_shard(fn):
+    """`fn` (Pallas calls over [B, H, S, ...] arrays) as the SPMD
+    partitioner can take it. A multi-device jit refuses a Mosaic kernel
+    outright ("cannot be automatically partitioned"), so where the
+    trace runs under a mesh (the trainer names it with
+    `jax.sharding.use_abstract_mesh`) whose batch axes the partitioner
+    still owns, each batch shard runs the kernel on its own rows inside
+    a shard_map. No mesh, one device, or axes that an enclosing
+    shard_map already made manual: `fn` as it is."""
+    from jax.sharding import AxisType, PartitionSpec
+
+    from elasticdl_tpu.parallel.mesh import batch_axes
+
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
+        return fn
+    types = dict(zip(mesh.axis_names, mesh.axis_types))
+    axes = tuple(
+        a
+        for a in batch_axes(mesh)
+        if mesh.shape[a] > 1 and types[a] != AxisType.Manual
+    )
+    if not axes:
+        return fn
+    spec = PartitionSpec(axes)
+    return jax.shard_map(
+        fn, in_specs=spec, out_specs=spec, axis_names=set(axes),
+        check_vma=False,
+    )
 
 
 # ---------- reference path (also the correctness oracle in tests) ----------
@@ -439,19 +476,22 @@ def _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k):
 def flash_attention(
     q, k, v, causal=False, block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K
 ):
-    """Attention over [B, H, S, D]; S must be a multiple of the (clamped)
-    block sizes on the Pallas path (the reference path has no constraint)."""
+    """Attention over [B, H, S, D]; where the kernel runs, S must be a
+    multiple of the (clamped) block sizes (ValueError otherwise)."""
     bq, bk = _clamp_blocks(q.shape[2], block_q, block_k)
     if _pallas_ok(q.shape[2], bq, bk):
-        out, _ = _flash_forward(q, k, v, causal, bq, bk, emit_lse=False)
-        return out
+        return _per_batch_shard(
+            lambda q, k, v: _flash_forward(
+                q, k, v, causal, bq, bk, emit_lse=False
+            )[0]
+        )(q, k, v)
     return reference_attention(q, k, v, causal)
 
 
 def _fit_block(s, requested):
     """Largest block <= requested that divides S (halving down to 128), so
-    raising the default block size never kicks divisible-by-512 sequence
-    lengths off the Pallas kernel onto the O(S^2) fallback."""
+    raising the default block size never refuses a divisible-by-512
+    sequence length."""
     b = min(requested, s)
     while b > 128 and s % b:
         b //= 2
@@ -463,13 +503,30 @@ def _clamp_blocks(s, block_q, block_k):
 
 
 def _pallas_ok(s, block_q, block_k):
-    return _use_pallas() and s % block_q == 0 and s % block_k == 0
+    """True: run the kernel. False: this backend has no kernel (see
+    _use_pallas). A sequence the kernel cannot tile RAISES where the
+    kernel is in use — on the chip nothing drops to the O(S^2) path in
+    silence."""
+    if not _use_pallas():
+        return False
+    if s % block_q or s % block_k:
+        raise ValueError(
+            f"flash_attention: sequence length {s} is not a multiple of "
+            f"its block sizes ({block_q}, {block_k}); pad S to a "
+            "multiple of 128 (the kernel never falls back to full-matrix "
+            "attention on the TPU)"
+        )
+    return True
 
 
 def _fwd(q, k, v, causal, block_q, block_k):
     bq, bk = _clamp_blocks(q.shape[2], block_q, block_k)
     if _pallas_ok(q.shape[2], bq, bk):
-        out, lse = _flash_forward(q, k, v, causal, bq, bk, emit_lse=True)
+        out, lse = _per_batch_shard(
+            lambda q, k, v: _flash_forward(
+                q, k, v, causal, bq, bk, emit_lse=True
+            )
+        )(q, k, v)
         return out, (q, k, v, out, lse)
     out = reference_attention(q, k, v, causal)
     return out, (q, k, v, out, None)
@@ -479,12 +536,14 @@ def _bwd(causal, block_q, block_k, residuals, g):
     q, k, v, out, lse = residuals
     bq, bk = _clamp_blocks(q.shape[2], block_q, block_k)
     if lse is not None:
-        return _flash_backward(q, k, v, out, lse, g, causal, bq, bk)
+        return _per_batch_shard(
+            lambda *a: _flash_backward(*a, causal, bq, bk)
+        )(q, k, v, out, lse, g)
     return _bwd_xla(q, k, v, out, g, causal)
 
 
 def _bwd_xla(q, k, v, out, g, causal):
-    """Full-matrix XLA backward (fallback path only): scores recomputed,
+    """Full-matrix XLA backward (backends without the kernel): scores recomputed,
     then dV = P^T g;  dP = g V^T;  dS = P * (dP - rowsum(g * out));
     dQ = dS K * scale;  dK = dS^T Q * scale."""
     scale = q.shape[-1] ** -0.5

@@ -119,16 +119,6 @@ def ensure_world(coordinator_addr, world_size, rank, epoch=None):
         shutdown_timeout_seconds=SHUTDOWN_TIMEOUT_SECONDS,
         heartbeat_timeout_seconds=HEARTBEAT_TIMEOUT_SECONDS,
     )
-    # Older jax (< 0.5) has neither timeout knob; drop what the installed
-    # signature doesn't accept rather than crash every multi-host worker.
-    import inspect
-
-    accepted = inspect.signature(
-        jax.distributed.initialize
-    ).parameters
-    init_kwargs = {
-        k: v for k, v in init_kwargs.items() if k in accepted
-    }
     jax.distributed.initialize(**init_kwargs)
     _current.update(
         coordinator=coordinator_addr,

@@ -138,7 +138,7 @@ def make_pipeline(stage_fn, mesh, axis_name="stage", batch_axis=None,
     x_micro [M, mb, ...] (optionally sharded over `batch_axis` on mb for
     DP x PP meshes); returns [M, mb, ...] outputs with x's sharding."""
     from jax.sharding import PartitionSpec as P
-    from elasticdl_tpu.common.jax_compat import shard_map
+    from jax import shard_map
 
     if remat:
         kwargs = {}
@@ -476,7 +476,7 @@ def make_lm_pipeline_1f1b(cfg, mesh, n_stages, num_microbatches,
     forward and gradients hop backward on neighbor-only ppermute rings.
     """
     import flax.linen as nn
-    from elasticdl_tpu.common.jax_compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from elasticdl_tpu.models.transformer.transformer_lm import (

@@ -22,7 +22,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
-from elasticdl_tpu.common.jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from elasticdl_tpu.models.transformer.transformer_lm import (

@@ -142,7 +142,7 @@ def make_ring_attention(mesh, axis_name="seq", causal=False,
     parallel in attention, so a head shard just runs its own ring) and
     returns the global output with the same sharding."""
     from jax.sharding import PartitionSpec as P
-    from elasticdl_tpu.common.jax_compat import shard_map
+    from jax import shard_map
 
     spec = P(batch_axis, head_axis, axis_name, None)
     return shard_map(
@@ -295,7 +295,7 @@ def make_zigzag_ring_attention(mesh, axis_name="seq", causal=True,
     """shard_map-wrapped zigzag ring attention (balanced causal SP). Same
     contract as make_ring_attention; requires an even per-device sequence."""
     from jax.sharding import PartitionSpec as P
-    from elasticdl_tpu.common.jax_compat import shard_map
+    from jax import shard_map
 
     spec = P(batch_axis, head_axis, axis_name, None)
     return shard_map(

@@ -27,7 +27,7 @@ same two collectives.
 import jax
 import jax.numpy as jnp
 import numpy as np
-from elasticdl_tpu.common.jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 import flax.linen as nn

@@ -55,7 +55,7 @@ def make_ulysses_attention(
     """shard_map-wrapped Ulysses attention over GLOBAL [B, H, S, D] arrays
     sharded on S (and optionally on B along `batch_axis`)."""
     from jax.sharding import PartitionSpec as P
-    from elasticdl_tpu.common.jax_compat import shard_map
+    from jax import shard_map
 
     spec = P(batch_axis, None, axis_name, None)
     return shard_map(

@@ -53,7 +53,7 @@ import jax
 import numpy as np
 
 from elasticdl_tpu.common import knobs
-from elasticdl_tpu.common.jax_compat import shard_map
+from jax import shard_map
 from elasticdl_tpu.common.log_utils import get_logger
 from elasticdl_tpu.observability import emit_event
 from elasticdl_tpu.observability.metrics import default_registry
@@ -1113,12 +1113,7 @@ class AllReduceTrainer(JaxTrainer):
             elif self._quantized_grads:
                 step_fn = self._quantized_step_fn()
             else:
-
-                def step_fn(variables, opt_state, rng, features, labels):
-                    return self._step_body(
-                        variables, opt_state, rng, features, labels,
-                        slice_to,
-                    )
+                step_fn = self._dp_step_fn(self._mesh, slice_to)
 
             # Donate (variables, opt_state) in single-process worlds:
             # the outputs alias the inputs, so XLA updates the
@@ -1165,6 +1160,22 @@ class AllReduceTrainer(JaxTrainer):
             self._sharded_steps[key] = step
         return step
 
+    def _dp_step_fn(self, mesh, slice_to):
+        """The plain data-parallel step body for `mesh` (live or a
+        speculated candidate). The trace runs under the mesh's abstract
+        twin so ops that the partitioner cannot split on its own (the
+        Pallas flash attention) can see which axes shard the batch."""
+        abstract_mesh = mesh.abstract_mesh
+
+        def step_fn(variables, opt_state, rng, features, labels):
+            with jax.sharding.use_abstract_mesh(abstract_mesh):
+                return self._step_body(
+                    variables, opt_state, rng, features, labels,
+                    slice_to,
+                )
+
+        return step_fn
+
     # ---------- speculative AOT planning ----------
 
     def plan_step_for_spec(self, spec, real_n):
@@ -1195,12 +1206,7 @@ class AllReduceTrainer(JaxTrainer):
                 mesh=mesh, tp=spec.tp > 1
             )
         else:
-
-            def step_fn(variables, opt_state, rng, features, labels):
-                return self._step_body(
-                    variables, opt_state, rng, features, labels,
-                    slice_to,
-                )
+            step_fn = self._dp_step_fn(mesh, slice_to)
 
         var_sh, opt_sh, donate = self._plan_shardings(mesh, spec)
         from elasticdl_tpu.observability.profiling import tracked_jit
@@ -1572,6 +1578,13 @@ class AllReduceTrainer(JaxTrainer):
             # The hook rejected the config during world init: fall through
             # to the monolithic path below (stages was reset to 1).
         first_init = self._variables is None
+        if first_init:
+            # Parameter shapes do not depend on the batch: initialise
+            # from ONE row. The base class runs model.init eagerly on
+            # the default device, and the global batch of a multi-chip
+            # world (16 x 4096 tokens of flagship logits) does not fit
+            # one chip — found on the first four-chip run.
+            features = jax.tree_util.tree_map(lambda a: a[:1], features)
         super().init_variables_if_needed(features)
         if self._mesh is None:
             self.init_world_if_needed(force=True)

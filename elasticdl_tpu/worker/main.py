@@ -1,6 +1,7 @@
 """`python -m elasticdl_tpu.worker.main` — worker process entrypoint
 (reference /root/reference/elasticdl/python/worker/main.py:28-82)."""
 
+import os
 import sys
 
 from elasticdl_tpu import observability
@@ -9,6 +10,7 @@ from elasticdl_tpu.common.constants import DistributionStrategy, JobType
 from elasticdl_tpu.common.log_utils import get_logger
 from elasticdl_tpu.common.model_utils import get_model_spec
 from elasticdl_tpu.data.reader import create_data_reader
+from elasticdl_tpu.observability import memory
 from elasticdl_tpu.worker.master_client import MasterClient
 from elasticdl_tpu.worker.worker import Worker
 
@@ -81,12 +83,50 @@ def build_trainer(args, spec, master_client):
     return LocalTrainer(model, spec.loss, optimizer_spec, seed=args.seed)
 
 
+def open_devices():
+    """Initialise the jax backend — the ONE place a job touches the
+    accelerator (the master and the PS never do; a chip belongs to one
+    process). What this worker got is logged and lands as a
+    `worker_devices` event, so a job's record says which platform its
+    steps ran on. A backend that cannot be opened — typically another
+    process holding the chip — ends the worker here, at once, with the
+    cause named; the master's relaunch budget is the retry."""
+    import jax
+
+    try:
+        devices = jax.devices()
+    except RuntimeError as e:
+        raise RuntimeError(
+            "worker could not open its accelerator (requested platforms "
+            f"JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS', '')!r}). If "
+            "the error below names the libtpu lockfile or says the TPU "
+            "is in use, another process on this host holds the chip: one "
+            f"process per chip. Backend error: {e}"
+        ) from e
+    first = devices[0]
+    logger.info(
+        "Worker devices: %d x %s (platform %s)",
+        len(devices), first.device_kind, first.platform,
+    )
+    observability.emit_event(
+        "worker_devices",
+        platform=first.platform,
+        device_kind=first.device_kind,
+        count=len(devices),
+    )
+
+
 def main(argv=None):
     args = worker_parser().parse_args(argv)
     validate_args(args)
     obs = observability.setup(
         role=f"worker-{args.worker_id}", job=args.job_name
     )
+    if not args.multi_host:
+        # A multi-host world initialises jax.distributed first (the
+        # trainer's regroup owns that order); every other worker opens
+        # its devices up front so a busy chip fails before any work.
+        open_devices()
     if args.model_zoo:
         sys.path.insert(0, args.model_zoo)
     spec = get_model_spec(args.model_def)
@@ -130,8 +170,6 @@ def main(argv=None):
     if args.profile_dir:
         # Per-worker subdir: concurrent workers on one host must not
         # interleave trace events in a single profile directory.
-        import os
-
         profile_dir = os.path.join(
             args.profile_dir, f"worker{args.worker_id}"
         )
@@ -169,6 +207,12 @@ def main(argv=None):
     ).start()
     try:
         worker.run()
+        # What the devices held when the work was done, by the
+        # runtime's own count (empty on the CPU).
+        observability.emit_event(
+            "worker_exit_memory",
+            device_stats=memory.device_memory_stats(),
+        )
     finally:
         # Leave any distributed world deterministically: interpreter-exit
         # shutdown from N processes at scattered times fails the shutdown

@@ -24,7 +24,8 @@ Semantics the trainer relies on:
   object the live path would build (`donate_argnums` captured at
   lower time), so consuming it keeps the in-place update aliasing.
 - **Everything lands in the persistent cache too**: when
-  ELASTICDL_COMPILE_CACHE_DIR is set, a speculative compile writes its
+  the persistent compile cache is on (it is by default — see
+  common/compile_cache.py), a speculative compile writes its
   disk entry even if the executable object later dies with a backend
   re-init (multi-host regroups) — the re-lowering on the other side
   rehydrates it (`compile_cache_hit`), which is how speculation helps

@@ -729,7 +729,9 @@ def test_repo_lints_clean_without_importing_jax():
     # Only enforced when the box isn't already saturated — a loaded
     # 1-core host stretches wall time severalfold with no regression
     # (the flake class the ROADMAP says not to chase).
-    if load_before < 4.0:
+    # "Saturated" is relative to the cores this process may use: pinned
+    # to two, a load of 3 already doubles every wall time.
+    if load_before < 0.5 * len(os.sched_getaffinity(0)):
         assert payload["seconds"] < 10, payload["seconds"]
     # Per-rule timings ride the payload (surfaced by `make ci`).
     assert set(payload["rule_seconds"]) == set(payload["rules"])
@@ -777,7 +779,7 @@ def test_compile_tracker_allows_tracked_and_out_of_scope(tmp_path):
             # a compile boundary on its own.
             "elasticdl_tpu/worker/tracked.py": """
             from elasticdl_tpu.observability.profiling import tracked_jit
-            from elasticdl_tpu.common.jax_compat import shard_map
+            from jax import shard_map
 
             def build(step, mesh):
                 inner = shard_map(step, mesh=mesh)
@@ -1361,5 +1363,5 @@ def test_lint_changed_reuses_cached_analysis():
     assert payload["cache"] is True
     # Budget enforced only off a saturated box (see the timing note in
     # test_repo_lints_clean_without_importing_jax).
-    if load_before < 4.0:
+    if load_before < 0.5 * len(os.sched_getaffinity(0)):
         assert payload["seconds"] < 3, payload["seconds"]

@@ -611,7 +611,11 @@ def test_step_cost_model_records_flops_and_mfu(monkeypatch):
     from elasticdl_tpu.observability.metrics import default_registry
 
     monkeypatch.setenv(mfu.MFU_ENV, "1")
-    monkeypatch.setenv(mfu.PEAK_FLOPS_ENV, "1e12")
+    # The CPU has no entry in the peak table; give the test device one
+    # so the MFU gauge is exercised end to end.
+    monkeypatch.setitem(
+        mfu.PEAK_BF16_FLOPS_BY_KIND, jax.devices()[0].device_kind, 1e12
+    )
     model = mfu.StepCostModel()
     step = jax.jit(lambda x: (x @ x).sum())
     x = jnp.ones((32, 32))
@@ -635,8 +639,8 @@ def test_step_cost_model_degrades_without_analysis(monkeypatch):
     from elasticdl_tpu.observability import mfu
 
     monkeypatch.setenv(mfu.MFU_ENV, "1")
-    monkeypatch.setenv(mfu.PEAK_FLOPS_ENV, "1e12")
     model = mfu.StepCostModel()
+    assert model._peak is None  # the CPU is not in the peak table
 
     class Unlowerable:
         def lower(self, *a, **k):
@@ -647,6 +651,14 @@ def test_step_cost_model_degrades_without_analysis(monkeypatch):
     model.observe(Unlowerable(), (1.0,))
     model.observe(Unlowerable(), (1.0,))
     assert list(model._flops.values()) == [None]  # cached, no retries
+
+
+def test_peak_table_is_keyed_by_device_kind_and_unknown_raises():
+    from elasticdl_tpu.observability import mfu
+
+    assert mfu.peak_flops("TPU v5 lite") == 197e12
+    with pytest.raises(mfu.UnknownDeviceError, match="cpu"):
+        mfu.peak_flops("cpu")
 
 
 def test_step_cost_model_disabled(monkeypatch):

@@ -24,6 +24,13 @@ from elasticdl_tpu.observability.metrics import default_registry
 
 from test_utils import start_master
 
+import pytest
+
+# These tests read the compile tracker's cold/rebuild/mesh_change causes:
+# every lowering must really compile, whatever an earlier run left in the
+# persistent cache.
+pytestmark = pytest.mark.usefixtures("no_persistent_compile_cache")
+
 
 def _fresh_name():
     return f"t_{uuid.uuid4().hex[:8]}"

@@ -8,7 +8,7 @@ import pytest
 import jax.numpy as jnp
 import numpy as np
 import optax
-from elasticdl_tpu.common.jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from elasticdl_tpu.parallel.quantized import (

@@ -41,6 +41,9 @@ class _EchoPserver:
     def push_gradients(self, request, context):
         return pb.PushGradientsResponse(accepted=True, version=self.version + 1)
 
+    # The packed transport shares push_gradients' response.
+    push_gradients_packed = push_gradients
+
 
 def test_stub_server_roundtrip():
     servicer = _EchoPserver()

@@ -181,7 +181,7 @@ class _FakeMasterClient:
         return nxt
 
     def report_task_result(self, task_id, err_message="",
-                           exec_counters=None):
+                           exec_counters=None, lease_token=0):
         self.reported.append((task_id, err_message))
 
 

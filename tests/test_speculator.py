@@ -396,25 +396,6 @@ def test_hinted_world_compiled_and_consumed(tmp_path, monkeypatch):
             mc.close()
 
 
-def test_compile_cache_knob_wiring(tmp_path, monkeypatch):
-    """ensure_compile_cache: unset knob -> disabled (memoized); the
-    instance manager stamps the dir into child env."""
-    from elasticdl_tpu.common import compile_cache
-
-    monkeypatch.delenv("ELASTICDL_COMPILE_CACHE_DIR", raising=False)
-    compile_cache.reset_for_tests()
-    try:
-        assert compile_cache.ensure_compile_cache() is None
-        # Memoized: setting the knob after the first check is ignored
-        # until reset (process-lifetime wiring, like jax's own config).
-        monkeypatch.setenv(
-            "ELASTICDL_COMPILE_CACHE_DIR", str(tmp_path / "cc")
-        )
-        assert compile_cache.ensure_compile_cache() is None
-    finally:
-        compile_cache.reset_for_tests()
-
-
 def test_relaunch_with_warm_cache_skips_cold_compile(tmp_path):
     """Two incarnations of the same training process share one cache
     dir: the first cold-compiles (a `compile` event), the second
@@ -444,7 +425,7 @@ recent = [
 print("RESULT:" + json.dumps(recent))
 """.format(repo=REPO)
     env = dict(os.environ)
-    env["ELASTICDL_COMPILE_CACHE_DIR"] = cache
+    env["JAX_COMPILATION_CACHE_DIR"] = cache
 
     def run():
         out = subprocess.run(

@@ -1,0 +1,183 @@
+"""AOT compiles for a DESCRIBED TPU v5e — no chip, so never a chip run.
+
+The TPU compiler is installed here and compiles for a topology that is
+described, not attached: what it refuses (an unaligned tile, too much
+VMEM, a program over 16 GB, a kernel the partitioner cannot split) costs
+no chip time. Kept to this one file on purpose: only one process may load
+the TPU library, so the topology is described inside a module-scoped
+fixture — never at import, in a skipif, in parametrize or in conftest —
+and every compile runs in this process. The persistent compile cache is
+off around them (an AOT entry cannot be read back without a chip).
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from elasticdl_tpu.ops import flash_attention as fa
+
+pytestmark = pytest.mark.usefixtures("no_persistent_compile_cache")
+
+HBM_BYTES = 16 * 2**30
+
+# [B, H, S, D]: the flagship (8 x 128 heads, S=4096, minibatch 4), its
+# S=8192 sibling, and the 64-wide-head variant.
+FLAGSHIP_SHAPES = [
+    (4, 8, 4096, 128),
+    (2, 8, 8192, 128),
+    (4, 16, 4096, 64),
+]
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def kernel_on(monkeypatch):
+    """jax.default_backend() is the CPU here, so the dispatch would take
+    its CPU branch; steer it as the chip would (in the test, not through
+    an option of the program)."""
+    monkeypatch.delenv("EDL_FORCE_PALLAS_INTERPRET", raising=False)
+    monkeypatch.setattr(fa, "_use_pallas", lambda: True)
+
+
+def _qkv(shape, sharding):
+    return [
+        jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sharding)
+    ] * 3
+
+
+@pytest.mark.parametrize("shape", FLAGSHIP_SHAPES, ids=str)
+def test_flash_forward_compiles_for_v5e(one_chip, kernel_on, shape):
+    compiled = (
+        jax.jit(lambda q, k, v: fa.flash_attention(q, k, v, True))
+        .lower(*_qkv(shape, one_chip))
+        .compile()
+    )
+    assert compiled.as_text().count("tpu_custom_call") == 1
+
+
+@pytest.mark.parametrize("shape", FLAGSHIP_SHAPES, ids=str)
+def test_flash_forward_backward_compiles_for_v5e(
+    one_chip, kernel_on, shape
+):
+    def loss(q, k, v):
+        return jnp.sum(fa.flash_attention(q, k, v, True))
+
+    compiled = (
+        jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+        .lower(*_qkv(shape, one_chip))
+        .compile()
+    )
+    # forward (with lse), dq, dk/dv.
+    assert compiled.as_text().count("tpu_custom_call") == 3
+
+
+def test_flash_kernel_partitions_over_a_data_mesh(topo, kernel_on):
+    """A multi-device jit refuses a bare Mosaic kernel ("cannot be
+    automatically partitioned"); under the trainer's abstract mesh each
+    batch shard runs the kernel on its own rows."""
+    mesh = jax.sharding.Mesh(np.array(topo.devices), ("data",))
+    sharded = NamedSharding(mesh, P("data"))
+
+    def loss(q, k, v):
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return jnp.sum(fa.flash_attention(q, k, v, True))
+
+    compiled = (
+        jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+        .lower(*_qkv((16, 8, 4096, 128), sharded))
+        .compile()
+    )
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 3
+    # Per device: 4 of the 16 rows.
+    assert "f32[4,8,4096,128]" in text
+
+
+def test_unservable_sequence_raises_where_the_kernel_runs(kernel_on):
+    q = jnp.zeros((1, 1, 1100, 128), jnp.float32)
+    with pytest.raises(ValueError, match="not a multiple"):
+        jax.eval_shape(lambda q: fa.flash_attention(q, q, q, True), q)
+
+
+def test_flagship_step_compiles_and_fits_one_v5e(topo, kernel_on,
+                                                 monkeypatch):
+    """The WHOLE flagship training step of AllReduceTrainer — the program
+    `edl train` runs at `flagship_config()` widths, minibatch 4 — for one
+    described chip: it contains the Pallas calls and fits 16 GB."""
+    from elasticdl_tpu.models.transformer import transformer_lm_flagship as m
+    from elasticdl_tpu.parallel.mesh import WorldTopology, resolve_world_spec
+    from elasticdl_tpu.worker.allreduce_trainer import AllReduceTrainer
+
+    class NoMaster:
+        worker_host = "127.0.0.1"
+
+    batch, seq = 4, 4096
+    trainer = AllReduceTrainer(
+        m.custom_model(), m.loss, m.optimizer(), NoMaster()
+    )
+    try:
+        tokens = jax.ShapeDtypeStruct((batch, seq), jnp.int32)
+        rng = jax.random.PRNGKey(0)
+        variables = jax.eval_shape(
+            lambda r, f: dict(
+                trainer._model.init(
+                    {"params": r, "dropout": r}, f, training=False
+                )
+            ),
+            rng, tokens,
+        )
+        trainer._variables = variables
+        trainer._opt_state = jax.eval_shape(
+            trainer._optax.init, variables["params"]
+        )
+        trainer._step_rng_base = rng
+        trainer._note_batch_abstract(tokens, tokens, batch)
+        n_params = sum(
+            int(np.prod(p.shape))
+            for p in jax.tree_util.tree_leaves(variables["params"])
+        )
+        assert n_params > 200e6  # 151M transformer + embeddings + head
+
+        # The trainer builds its mesh from jax.devices(): hand it the
+        # described chip.
+        monkeypatch.setattr(
+            jax, "devices", lambda *a, **k: [topo.devices[0]]
+        )
+        spec = resolve_world_spec(
+            trainer._parallel_config(),
+            WorldTopology(n_devices=1, local_devices=1, n_processes=1),
+            param_check=trainer._param_check,
+        )
+        _, step, abstract = trainer.plan_step_for_spec(spec, batch)
+        compiled = step.lower(*abstract).compile()
+    finally:
+        trainer.close()
+    assert compiled.as_text().count("tpu_custom_call") == 36  # 12 x 3
+    mem = compiled.memory_analysis()
+    resident = (
+        mem.argument_size_in_bytes + mem.output_size_in_bytes
+        - mem.alias_size_in_bytes + mem.temp_size_in_bytes
+    )
+    assert resident < HBM_BYTES, f"{resident / 2**30:.2f} GiB"

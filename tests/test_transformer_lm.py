@@ -3,6 +3,7 @@ log(branching) CE floor; the DP+SP (ring attention) sharded step from
 __graft_entry__ runs on the virtual 8-device mesh."""
 
 import numpy as np
+import pytest
 
 from elasticdl_tpu.data.gen.synthetic import synthetic_lm_tokens
 from elasticdl_tpu.models.transformer import transformer_lm as tlm
@@ -32,6 +33,7 @@ def test_lm_loss_drops_toward_markov_floor():
     assert last < 2.0, (first, last)
 
 
+@pytest.mark.slow  # fifteen sharded phases, each its own compiles: ~95 s
 def test_dryrun_multichip_dp_sp():
     import __graft_entry__ as graft
 

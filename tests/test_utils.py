@@ -51,13 +51,46 @@ def start_master(
         server.stop(0)
 
 
-def run_edl(*argv, timeout=240, include_tests_on_path=True):
+# Multi-process worlds on the CPU reduce through gloo, whose operations
+# wait 30 minutes by default: under load two ranks can enter independent
+# collectives in different orders and then both sit there, the whole job
+# at ~0% CPU, until the suite is cut. A bounded wait turns that wedge into
+# a failed step, which the trainer's regroup path retries.
+CPU_COLLECTIVE_TIMEOUT_FLAG = "--xla_cpu_collective_timeout_seconds=60"
+MULTIHOST_XLA_FLAGS = (
+    f"--xla_force_host_platform_device_count=4 {CPU_COLLECTIVE_TIMEOUT_FLAG}"
+)
+
+
+def coordinator_block():
+    """A free coordinator port block from this xdist worker's own slice
+    of the range (two workers can otherwise win the same block)."""
+    import os
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, os.path.join(repo, "tools"))
+    from elastic_drill import free_coordinator_block
+
+    worker = os.environ.get("PYTEST_XDIST_WORKER", "gw0")
+    return free_coordinator_block(lane=int(worker[2:] or 0), lanes=16)
+
+
+def run_edl(*argv, timeout=240, include_tests_on_path=True,
+            extra_env=None):
     """Run the `edl` CLI as a subprocess on the virtual CPU platform (the
     outer environment may point JAX at the real TPU). One definition so
-    the CLI-launch recipe can't drift between test files."""
+    the CLI-launch recipe can't drift between test files.
+
+    The job is its own process group and logs to files: at `timeout` the
+    whole group is killed (the master's workers too — no orphans), and
+    no pipe can block a role or the final read. Raises
+    subprocess.TimeoutExpired with the output so far."""
     import os
+    import signal
     import subprocess
     import sys
+    import tempfile
 
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
@@ -65,13 +98,39 @@ def run_edl(*argv, timeout=240, include_tests_on_path=True):
         f"{repo}:{repo}/tests" if include_tests_on_path else repo
     )
     env["JAX_PLATFORMS"] = "cpu"
-    return subprocess.run(
-        [sys.executable, "-m", "elasticdl_tpu.client.main", *argv],
-        capture_output=True,
-        text=True,
-        timeout=timeout,
-        env=env,
-        cwd=repo,
+    env.update(extra_env or {})
+    if CPU_COLLECTIVE_TIMEOUT_FLAG not in env.get("XLA_FLAGS", ""):
+        env["XLA_FLAGS"] = (
+            env.get("XLA_FLAGS", "") + " " + CPU_COLLECTIVE_TIMEOUT_FLAG
+        ).strip()
+    cmd = [sys.executable, "-m", "elasticdl_tpu.client.main", *argv]
+    with tempfile.TemporaryFile("w+") as out, tempfile.TemporaryFile(
+        "w+"
+    ) as err:
+        job = subprocess.Popen(
+            cmd, stdout=out, stderr=err, env=env, cwd=repo,
+            start_new_session=True,
+        )
+        timed_out = False
+        try:
+            job.wait(timeout=timeout)
+        except subprocess.TimeoutExpired:
+            timed_out = True
+        finally:
+            try:
+                os.killpg(job.pid, signal.SIGKILL)
+            except (ProcessLookupError, PermissionError):
+                pass
+            job.wait()
+        out.seek(0)
+        err.seek(0)
+        stdout, stderr = out.read(), err.read()
+    if timed_out:
+        raise subprocess.TimeoutExpired(
+            cmd, timeout, output=stdout, stderr=stderr[-3000:]
+        )
+    return subprocess.CompletedProcess(
+        cmd, job.returncode, stdout, stderr
     )
 
 

@@ -61,6 +61,7 @@ def test_zoo_model_trains(spec_name, steps, ratio):
         assert np.isfinite(metric.result())
 
 
+@pytest.mark.slow  # one conv-net train-step compile: ~50 s of XLA:CPU cold
 def test_resnet50_builds_and_steps():
     """ResNet50 is too heavy for a CPU convergence test; one step with
     finite loss + the expected parameter count validates the architecture.
@@ -151,6 +152,7 @@ def test_deepfm_distributed_with_ps():
             s.stop()
 
 
+@pytest.mark.slow  # one conv-net train-step compile: ~60 s of XLA:CPU cold
 def test_mobilenetv2_builds_and_steps():
     """MobileNetV2 (reference benchmark model, ftlib_benchmark.md:138-156):
     one finite step + the expected ~3.5M parameter count."""

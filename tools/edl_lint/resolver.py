@@ -2,8 +2,8 @@
 
 ModuleInfo answers "what does this Name/Attribute chain actually refer
 to" inside one module: import aliases are expanded to dotted targets
-(`jnp.dot` -> `jax.numpy.dot`, a bare `shard_map` imported from
-jax_compat -> `elasticdl_tpu.common.jax_compat.shard_map`), module-level
+(`jnp.dot` -> `jax.numpy.dot`, a bare `shard_map` imported from jax ->
+`jax.shard_map`), module-level
 string constants are tracked for env-key resolution, and logger bindings
 (`logger = get_logger(...)`) are recognized for the jit-purity pass.
 

@@ -4,8 +4,8 @@ The driver bench's PS-mode DeepFM spends 80-95% of its step in
 `push_gradients` while the device step is ~0.1 ms. This probe measures
 every component of that phase IN ISOLATION, with the exact shapes the
 bench pushes (batch 16384 x 39 Criteo fields, wide [V,1] + deep [V,8]
-adam tables on 2 shards), so `PERF_SNAPSHOT.json` can carry the same
-kind of limiter decomposition the ResNet entry has:
+adam tables on 2 shards), so the PS cell can carry a limiter
+decomposition (PERF.md, "Where the time goes"):
 
   1. client prep      - dedup (native radix), per-shard scatter, tobytes
   2. wire bytes       - ids + values + proto overhead, per shard

@@ -27,16 +27,15 @@ def _flagship_mfu(cfg, n_params, tokens_per_sec):
     excluded, LM head included) + 12*L*d*S per token for the attention
     score/value matmuls. Remat recompute is deliberately NOT counted —
     MFU measures model math retired, not hardware work."""
-    from elasticdl_tpu.bench.workloads import _peak_flops
+    from elasticdl_tpu.observability.mfu import peak_flops
 
     embed_params = cfg.vocab * cfg.d_model + cfg.max_len * cfg.d_model
     matmul_params = n_params - embed_params
     flops_per_token = (
         6 * matmul_params + 12 * cfg.n_layers * cfg.d_model * cfg.max_len
     )
-    peak = _peak_flops()
-    if not peak:
-        return None, flops_per_token
+    # An unknown device raises: no MFU against a guessed denominator.
+    peak = peak_flops(jax.devices()[0].device_kind)
     return flops_per_token * tokens_per_sec / peak, flops_per_token
 
 
