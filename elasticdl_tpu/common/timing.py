@@ -14,6 +14,7 @@ A Timing can mirror every sample into a labeled observability Histogram
 """
 
 import contextlib
+import logging
 import threading
 import time
 
@@ -104,8 +105,12 @@ class Timing:
 
     def report(self, logger, reset=False):
         """DEBUG-log the per-phase breakdown (the reference's
-        report_timing shape)."""
-        for phase, s in sorted(self.summary().items()):
+        report_timing shape). Called once a task: the summary (a sort of
+        every phase's reservoir) is only worked out when it is logged."""
+        phases = (
+            self.summary() if logger.isEnabledFor(logging.DEBUG) else {}
+        )
+        for phase, s in sorted(phases.items()):
             logger.debug(
                 "%s: %.6gs total / %d calls / %.6gs mean / "
                 "%.6gs p50 / %.6gs p99",

@@ -6,15 +6,16 @@ whether the time went to waiting on the master for a task lease, to the
 record reader, to decode, or to the h2d copy. This module decomposes the
 feed path into named stages and lands every stage three ways at once:
 
-- a `Timing` phase (``input_<stage>``) on whatever Timing object the
-  call site binds, so `bench/attribution.py` can split `input_wait`
-  into sub-fractions from the same phase summaries it already reads;
+- a `Timing` phase (``input_<stage>``) on the Timing object the call
+  site passes (the PS and local trainers' h2d), so
+  `bench/attribution.py` can split `input_wait` into sub-fractions
+  from the same phase summaries it already reads;
 - a tracing span (``datapath.<stage>``) so Perfetto shows the feed
-  path interleaved with train_step/push/pull spans;
+  path interleaved with train_step/push/pull spans, and, while a
+  jax.profiler session is open, on the device trace's own clock;
 - Prometheus series: `edl_datapath_seconds_total{stage}` and
   `edl_datapath_records_total` for fleet rollups (per-worker
-  starvation share, decode throughput), plus a per-stage duration
-  histogram `edl_datapath_stage_seconds{stage}`.
+  starvation share, decode throughput).
 
 Stage model (docs/OBSERVABILITY.md "Data plane"):
 
@@ -37,14 +38,13 @@ high-watermark events (`datapath_backpressure`) and an
 `edl_datapath_backpressure_total{queue}` counter when a bounded queue
 crosses ELASTICDL_DATAPATH_QUEUE_WATERMARK of its capacity.
 
-Overhead is bounded by design: one wall-clock timestamp pair and a
-counter bump per stage; ELASTICDL_DATAPATH=0 turns every stage() into a
+Overhead is bounded by design: one wall-clock timestamp pair (the
+span's) and a counter bump per stage; ELASTICDL_DATAPATH=0 turns every stage() into a
 no-op yield.
 """
 
 import contextlib
 import threading
-import time
 
 from elasticdl_tpu.common import knobs
 from elasticdl_tpu.observability import emit_event, tracing
@@ -58,12 +58,6 @@ QUEUE_WATERMARK_ENV = "ELASTICDL_DATAPATH_QUEUE_WATERMARK"
 # bench attribution layer can bucket them under input_wait.
 STAGES = ("task", "read", "decode", "collate", "h2d", "starve")
 
-# Stage-duration buckets: feed stages live in the 50us..1s range, well
-# below the latency-shaped registry default (1ms..100s).
-_STAGE_BUCKETS = (
-    5e-5, 2e-4, 1e-3, 4e-3, 0.016, 0.064, 0.25, 1.0, 4.0,
-)
-
 _registry = default_registry()
 _SECONDS = _registry.counter(
     "edl_datapath_seconds_total",
@@ -73,12 +67,6 @@ _SECONDS = _registry.counter(
 _RECORDS = _registry.counter(
     "edl_datapath_records_total",
     "Records delivered by the input pipeline",
-)
-_STAGE_HIST = _registry.histogram(
-    "edl_datapath_stage_seconds",
-    "Per-call duration of each input-pipeline stage",
-    labelnames=("stage",),
-    buckets=_STAGE_BUCKETS,
 )
 _QUEUE_DEPTH = _registry.gauge(
     "edl_datapath_queue_depth",
@@ -108,14 +96,13 @@ class Datapath:
 
     One instance per process (module singleton via get()); Timing
     mirroring is per-call-site — pass `timing=` so the phase lands on
-    the Timing object whose summary the caller reports (the worker loop
-    Timing for read/decode, the trainer's own Timing for h2d)."""
+    the Timing object whose summary the caller reports (the PS and
+    local trainers' own Timing for h2d)."""
 
     def __init__(self, enabled=None):
         if enabled is None:
             enabled = knobs.get_int(DATAPATH_ENV) != 0
         self._enabled = bool(enabled)
-        self._timing = None
         self._lock = threading.Lock()
         # Per-flush accumulation for the `datapath` event trail:
         # {stage: seconds} plus a record count, swapped out whole by
@@ -127,10 +114,6 @@ class Datapath:
     def enabled(self):
         return self._enabled
 
-    def bind_timing(self, timing):
-        """Default Timing object for stages that do not pass their own."""
-        self._timing = timing
-
     @contextlib.contextmanager
     def stage(self, name, records=0, timing=None):
         """Time one stage execution. Yields a holder whose .records the
@@ -139,15 +122,15 @@ class Datapath:
         if not self._enabled:
             yield holder
             return
-        start = time.time()
+        # One clock pair: the span's. An annotation in the profiler's
+        # trace cannot be entered after the fact, so the span wraps the
+        # body and the counters are fed from its duration.
+        sp = tracing.span("datapath." + name, cat="datapath")
         try:
-            yield holder
+            with sp:
+                yield holder
         finally:
-            dur = time.time() - start
-            tracing.record_span(
-                "datapath." + name, start, dur, cat="datapath"
-            )
-            self.add(name, dur, records=holder.records, timing=timing)
+            self.add(name, sp.dur, records=holder.records, timing=timing)
 
     def add(self, name, seconds, records=0, timing=None):
         """Account an already-measured stage interval (for producer
@@ -155,12 +138,10 @@ class Datapath:
         if not self._enabled or seconds < 0:
             return
         _SECONDS.labels(stage=name).inc(seconds)
-        _STAGE_HIST.labels(stage=name).observe(seconds)
         if records:
             _RECORDS.inc(records)
-        t = timing if timing is not None else self._timing
-        if t is not None:
-            t.add("input_" + name, seconds)
+        if timing is not None:
+            timing.add("input_" + name, seconds)
         with self._lock:
             self._acc[name] = self._acc.get(name, 0.0) + seconds
             self._acc_records += records

@@ -508,9 +508,12 @@ def capture_device_profile(seconds, out_dir):
         os.makedirs(target, exist_ok=True)
         _events.emit("profile_start", dir=target, seconds=seconds)
         jax.profiler.start_trace(target)
+        # The program's spans land in this trace too while it is open.
+        tracing.set_profiler_session(True)
         try:
             time.sleep(seconds)
         finally:
+            tracing.set_profiler_session(False)
             jax.profiler.stop_trace()
         files, total = [], 0
         for root, _, names in os.walk(target):

@@ -16,7 +16,6 @@ always on (a few string pairs per RPC); recording costs nothing until
 observability.setup() installs a recorder.
 """
 
-import contextlib
 import contextvars
 import json
 import os
@@ -233,23 +232,73 @@ def get_recorder():
     return _recorder
 
 
-@contextlib.contextmanager
-def span(name, cat="edl", **args):
-    """Record a span around the with-body (no-op without a recorder or
-    sink; the body's exceptions still propagate and the span still
-    closes)."""
-    rec = _recorder
-    if rec is None and not _sinks:
-        yield
-        return
-    start = time.time()
-    try:
-        yield
-    finally:
-        dur = time.time() - start
+# (TraceAnnotation, StepTraceAnnotation) while a jax.profiler session is
+# open in this process, else None. Set by whoever calls start_trace /
+# stop_trace; never imported here (the master imports this module and
+# stays jax-free).
+_annotations = None
+
+
+def set_profiler_session(is_open):
+    """Tell span() whether a jax.profiler session is open in this
+    process. Called by the owner of the session right after start_trace
+    and right before stop_trace."""
+    global _annotations
+    if is_open:
+        from jax.profiler import StepTraceAnnotation, TraceAnnotation
+
+        _annotations = (TraceAnnotation, StepTraceAnnotation)
+    else:
+        _annotations = None
+
+
+class span:
+    """Mark one boundary: `with tracing.span(name, cat, **args) as s:`.
+
+    One clock pair (`time.time`), whoever listens: after the body
+    `s.start` and `s.dur` (seconds) are set, so the caller feeds its own
+    counters from the same pair. The span goes to the JSONL recorder and
+    the sinks when they are installed, and, while a jax.profiler session
+    is open in this process, also onto the calling thread's line of the
+    profiler's host plane — the device trace's own clock — as a
+    TraceAnnotation (a StepTraceAnnotation when `step_num` is among the
+    args). The body's exceptions propagate and the span still closes."""
+
+    __slots__ = ("name", "cat", "args", "start", "dur", "_annotation")
+
+    def __init__(self, name, cat="edl", **args):
+        self.name = name
+        self.cat = cat
+        self.args = args
+        self.start = self.dur = 0.0
+        self._annotation = None
+
+    def __enter__(self):
+        annotations = _annotations
+        if annotations is not None:
+            if "step_num" in self.args:
+                self._annotation = annotations[1](
+                    self.name, step_num=self.args["step_num"]
+                )
+            else:
+                self._annotation = annotations[0](self.name)
+            self._annotation.__enter__()
+        self.start = time.time()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        self.dur = time.time() - self.start
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+        rec = _recorder
         if rec is not None:
-            rec.record(name, start, dur, cat=cat, args=args)
-        _feed_sinks(name, start, dur, cat, args)
+            rec.record(
+                self.name, self.start, self.dur, cat=self.cat,
+                args=self.args,
+            )
+        if _sinks:
+            _feed_sinks(self.name, self.start, self.dur, self.cat, self.args)
+        return False
 
 
 def record_span(name, start_s, dur_s, cat="edl", args=None):

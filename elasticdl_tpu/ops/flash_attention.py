@@ -267,6 +267,7 @@ def _flash_forward(q, k, v, causal, block_q, block_k, emit_lse):
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         interpret=_interpret(),
+        name="flash_fwd",
     )(
         q.reshape(bh, s, d), k.reshape(bh, s, d), v.reshape(bh, s, d)
     )
@@ -418,6 +419,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k):
         out_shape=jax.ShapeDtypeStruct((bh, s, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=_interpret(),
+        name="flash_bwd_dq",
     )(q3, k3, v3, g3, lse_fat, delta_fat)
 
     # dk/dv: grid (bh, k, q) — k-indexed tiles are major, q-indexed minor.
@@ -460,6 +462,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k):
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
         interpret=_interpret(),
+        name="flash_bwd_dkv",
     )(q3, k3, v3, g3, lse_fat, delta_fat)
 
     return (

@@ -55,7 +55,7 @@ import numpy as np
 from elasticdl_tpu.common import knobs
 from jax import shard_map
 from elasticdl_tpu.common.log_utils import get_logger
-from elasticdl_tpu.observability import emit_event
+from elasticdl_tpu.observability import datapath, emit_event, tracing
 from elasticdl_tpu.observability.metrics import default_registry
 from elasticdl_tpu.parallel import broadcast, distributed
 from elasticdl_tpu.parallel.mesh import (
@@ -1608,14 +1608,13 @@ class AllReduceTrainer(JaxTrainer):
         sync_step = self._steps_since_check >= self._steps_per_world_check
         if sync_step:
             self._steps_since_check = 0
-            with self.timing.record("world_check"):
+            with tracing.span("trainer.world_check"):
                 self.init_world_if_needed()
         features = jax.tree_util.tree_map(np.asarray, features)
         labels = jax.tree_util.tree_map(np.asarray, labels)
         for attempt in range(self._max_comm_retries):
             try:
-                with self.timing.record("sharded_step_dispatch"):
-                    loss = self._run_sharded_step(features, labels)
+                loss = self._run_sharded_step(features, labels)
                 if sync_step:
                     # Async dispatch means a collective failure surfaces on
                     # materialization, not dispatch. Block here — on the
@@ -1623,8 +1622,9 @@ class AllReduceTrainer(JaxTrainer):
                     # a host round trip — so comm errors land inside this
                     # try block and the re-mesh/retry path below runs,
                     # instead of exploding later at a logging float().
-                    # edl-lint: disable=hot-path-sync
-                    jax.block_until_ready(loss)
+                    with tracing.span("trainer.world_check"):
+                        # edl-lint: disable=hot-path-sync
+                        jax.block_until_ready(loss)
                 return True, self._version, loss
             except RETRYABLE_ERRORS:
                 if attempt == self._max_comm_retries - 1:
@@ -1679,13 +1679,18 @@ class AllReduceTrainer(JaxTrainer):
         # compile, microseconds before the under-lock swap below — and
         # _state_provider retries across exactly that window.
         with self._mesh:
-            new_variables, new_opt_state, loss = step(
-                self._variables,
-                self._opt_state,
-                step_rng,
-                shard_batch(padded_f, self._mesh),
-                shard_batch(padded_l, self._mesh),
-            )
+            with datapath.get().stage("h2d"):
+                device_f = shard_batch(padded_f, self._mesh)
+                device_l = shard_batch(padded_l, self._mesh)
+            # The enqueue; a compile, when there is one, is inside it.
+            with tracing.span("trainer.dispatch"):
+                new_variables, new_opt_state, loss = step(
+                    self._variables,
+                    self._opt_state,
+                    step_rng,
+                    device_f,
+                    device_l,
+                )
         with self._state_lock:
             self._variables = new_variables
             self._opt_state = new_opt_state

@@ -6,6 +6,7 @@ tasks interleaved into training, prediction output processing, train-end
 callback task handling.
 """
 
+import time
 import traceback
 
 import grpc
@@ -17,16 +18,17 @@ from elasticdl_tpu.common.constants import (
 from elasticdl_tpu.common.log_utils import get_logger
 from elasticdl_tpu.common.model_utils import Modes
 from elasticdl_tpu.common.timing import Timing
-from elasticdl_tpu.observability import datapath, tracing
+from elasticdl_tpu.observability import datapath, emit_event, tracing
 from elasticdl_tpu.observability.metrics import default_registry
 from elasticdl_tpu.proto import elasticdl_tpu_pb2 as pb
+from elasticdl_tpu.worker.step_clock import StepDoneClock
 from elasticdl_tpu.worker.task_data_service import TaskDataService
 
 logger = get_logger("worker.worker")
 
 _REG = default_registry()
 _STEPS = _REG.counter(
-    "edl_worker_steps_total", "Minibatch steps this worker completed"
+    "edl_worker_steps_total", "Minibatch steps this worker dispatched"
 )
 _TASKS = _REG.counter(
     "edl_worker_tasks_total",
@@ -35,7 +37,7 @@ _TASKS = _REG.counter(
 )
 _PHASE_SECONDS = _REG.histogram(
     "edl_phase_seconds",
-    "Worker phase latency (task_process/batch_process + trainer phases)",
+    "Worker phase latency (batch_process + trainer phases)",
     labelnames=("phase",),
 )
 
@@ -73,11 +75,8 @@ class Worker:
         self._lease_mode = lease_mode
         self._steps = 0
         self._timing = Timing().bind_histogram(_PHASE_SECONDS)
-        # Data-plane stages recorded off the worker loop (task acquire,
-        # read/starve, decode) mirror into this Timing as input_<stage>
-        # phases; the trainer's h2d stage binds its own Timing at the
-        # call site so bench attribution sees it in the trainer summary.
-        datapath.get().bind_timing(self._timing)
+        # When each dispatched step left the device, without a fence.
+        self._step_clock = StepDoneClock()
         trainer_timing = getattr(trainer, "timing", None)
         if trainer_timing is not None:
             # Trainer phases (pull/step/push) reach /metrics through the
@@ -92,6 +91,8 @@ class Worker:
         self._profile_start_step = profile_start_step
         self._profile_steps = profile_steps
         self._profiling = False
+        self._profile_first_step = 0
+        self._profile_started = 0.0
         self._callbacks = (
             model_spec.callbacks() if model_spec.callbacks else []
         ) + list(extra_callbacks)
@@ -130,6 +131,7 @@ class Worker:
             # A short job can end inside the profiled window; an unclosed
             # trace would be empty on disk.
             self._stop_profile_if_running()
+            self._step_clock.close()
 
     # ---------- job loops ----------
 
@@ -150,9 +152,10 @@ class Worker:
                 # report only delays the next eval trigger — never worth a
                 # worker's life during a master blip.
                 try:
-                    self._mc.report_version(
-                        self._trainer.get_model_version()
-                    )
+                    with tracing.span("worker.report_version"):
+                        self._mc.report_version(
+                            self._trainer.get_model_version()
+                        )
                 except grpc.RpcError:
                     logger.warning(
                         "report_version failed (master unreachable?); "
@@ -177,8 +180,6 @@ class Worker:
         membership change abandons the lease (the master requeues it). The
         loop returns when training work is exhausted — evaluation and
         train-end tasks drain through the regular task loop after."""
-        import time as _time
-
         import jax
 
         while True:
@@ -195,7 +196,7 @@ class Worker:
                 self._mc.report_liveness()
                 if self._job_type == JobType.TRAINING_WITH_EVALUATION:
                     self._drain_eval_tasks()
-                _time.sleep(0.5)
+                time.sleep(0.5)
                 continue
             try:
                 records = self._read_lease_records(lease.ranges)
@@ -237,31 +238,11 @@ class Worker:
             tracing.set_context(lease_epoch=lease.epoch)
             try:
                 loss = None
-                dp = datapath.get()
                 for i in range(lease.n_steps):
-                    # Cycle this rank's records to fill every batch: all
-                    # ranks must dispatch identically-shaped steps.
-                    with dp.stage("collate"):
-                        rows = [
-                            records[(i * B + j) % len(records)]
-                            for j in range(B)
-                        ]
-                    with dp.stage("decode"):
-                        features, labels = self._spec.feed(
-                            rows, Modes.TRAINING, self._metadata
-                        )
-                    loss = self._trainer.train_lease_minibatch(
-                        features, labels
-                    )
-                    self._steps += 1
-                    _STEPS.inc()
-                    if self._steps % self._log_loss_steps == 0:
-                        logger.info(
-                            "Step %d (lease %d) loss %.6f",
-                            self._steps,
-                            lease.lease_id,
-                            float(loss),
-                        )
+                    with tracing.span(
+                        "worker.step", step_num=self._steps + 1
+                    ):
+                        loss = self._lease_step(records, i, lease.lease_id)
                 # Async dispatch: a peer failure surfaces at
                 # materialization. Block before reporting so "success"
                 # means the steps actually ran.
@@ -290,10 +271,35 @@ class Worker:
                     self._mc.report_lease(
                         lease.lease_id, lease.rank, False, str(e)
                     )
-                    _time.sleep(0.5)
+                    time.sleep(0.5)
                 continue
             self._mc.report_lease(lease.lease_id, lease.rank, True)
             self._mc.report_version(self._trainer.get_model_version())
+
+    def _lease_step(self, records, i, lease_id):
+        """Step `i` of a lease over this rank's `records`."""
+        B = self._minibatch_size
+        dp = datapath.get()
+        # Cycle this rank's records to fill every batch: all ranks must
+        # dispatch identically-shaped steps.
+        with dp.stage("collate"):
+            rows = [records[(i * B + j) % len(records)] for j in range(B)]
+        with dp.stage("decode"):
+            features, labels = self._spec.feed(
+                rows, Modes.TRAINING, self._metadata
+            )
+        loss = self._trainer.train_lease_minibatch(features, labels)
+        self._steps += 1
+        _STEPS.inc()
+        self._step_clock.dispatched(self._steps, loss)
+        if self._steps % self._log_loss_steps == 0:
+            logger.info(
+                "Step %d (lease %d) loss %.6f",
+                self._steps,
+                lease_id,
+                self._fenced(loss),
+            )
+        return loss
 
     def _read_lease_records(self, ranges):
         records = []
@@ -342,18 +348,19 @@ class Worker:
         # trace_report.py stitch the task's cross-process chain together.
         tracing.set_context(task_id=task.task_id)
         try:
-            with self._timing.record("task_process"), tracing.span(
+            with tracing.span(
                 "task_process",
                 task_type=pb.TaskType.Name(task.type),
             ):
                 for records in self._tds.read_batches(
                     task, self._minibatch_size
                 ):
-                    with self._timing.record("batch_process"), tracing.span(
-                        "batch_process"
-                    ):
+                    with tracing.span("batch_process") as batch:
                         self._process_with_retries(process_batch, records)
-            self._tds.report_task(task.task_id)
+                    # The aggregator's straggler score reads this phase.
+                    self._timing.add("batch_process", batch.dur)
+            with tracing.span("worker.report_task"):
+                self._tds.report_task(task.task_id)
             _TASKS.labels(result="success").inc()
         except Exception as e:
             logger.error(
@@ -395,20 +402,25 @@ class Worker:
                 )
 
     def _process_train_batch(self, records):
-        with datapath.get().stage("decode"):
-            features, labels = self._spec.feed(
-                records, Modes.TRAINING, self._metadata
-            )
         if self._profile_dir:
-            # Before the dispatch, so the trace window covers exactly the
-            # steps the log names.
+            # Before the step's span opens and before its dispatch, so
+            # the trace window covers exactly the steps the log names.
             self._maybe_profile(self._steps + 1)
-        accepted, version, loss = self._trainer.train_minibatch(
-            features, labels
-        )
-        if accepted:
+        # One iteration, decode to the end of any fence: in a profiler
+        # session this is the step marker of the host plane.
+        with tracing.span("worker.step", step_num=self._steps + 1):
+            with datapath.get().stage("decode"):
+                features, labels = self._spec.feed(
+                    records, Modes.TRAINING, self._metadata
+                )
+            accepted, version, loss = self._trainer.train_minibatch(
+                features, labels
+            )
+            if not accepted:
+                return
             self._steps += 1
             _STEPS.inc()
+            self._step_clock.dispatched(self._steps, loss)
             if self._steps % self._log_loss_steps == 0:
                 # Only materialize the (lazy, on-device) loss when logging;
                 # every other step stays dispatch-ahead.
@@ -416,8 +428,15 @@ class Worker:
                     "Step %d (version %d) loss %.6f",
                     self._steps,
                     version,
-                    float(loss),
+                    self._fenced(loss),
                 )
+
+    @staticmethod
+    def _fenced(loss):
+        """The loss as a float: waits for the device to finish every
+        step up to this one, with the dispatch loop stalled meanwhile."""
+        with tracing.span("worker.loss_fence"):
+            return float(loss)
 
     def _maybe_profile(self, next_step):
         """Open/close the trace window around `next_step` (the step about
@@ -432,7 +451,11 @@ class Worker:
             import jax
 
             self._profiling = True
+            self._profile_first_step = next_step
+            self._profile_started = time.time()
             jax.profiler.start_trace(self._profile_dir)
+            # From here tracing.span() also writes into this trace.
+            tracing.set_profiler_session(True)
             logger.info(
                 "Profiling steps %d-%d to %s",
                 next_step,
@@ -448,12 +471,24 @@ class Worker:
         import jax
 
         self._profiling = False
+        tracing.set_profiler_session(False)
+        stopped = time.time()
         try:
             jax.profiler.stop_trace()
             logger.info(
                 "Profile written to %s (view: tensorboard --logdir %s)",
                 self._profile_dir,
                 self._profile_dir,
+            )
+            # Where the trace is and what it covers, for a reader that
+            # has the event log and not this process's log.
+            emit_event(
+                "profile_written",
+                dir=self._profile_dir,
+                first_step=self._profile_first_step,
+                last_step=self._steps,
+                t_start=self._profile_started,
+                t_stop=stopped,
             )
         except Exception:
             logger.warning("Failed to finalize profile", exc_info=True)
