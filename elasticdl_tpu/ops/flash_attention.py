@@ -18,10 +18,14 @@ matrix never materializes — in EITHER pass:
   [bh, S, 128] (the (8,128) tiling makes a plain 1-D row vector an illegal
   block; lane replication is the canonical TPU layout for row stats, cf.
   jax.experimental.pallas.ops.tpu.flash_attention's MIN_BLOCK_SIZE scratch).
-- backward runs two streaming kernels: dq over (bh, q_blocks, k_blocks)
-  and combined dk/dv over (bh, k_blocks, q_blocks), each recomputing P
-  one [block_q, block_k] tile at a time from the saved lse, so backward
-  memory is O(S) + tiles, not O(S^2).
+- backward is one streaming kernel over (bh, k_blocks, q_blocks): each
+  [block_q, block_k] tile of P is rebuilt once from the saved lse and
+  feeds dv, dk AND dq (five products a tile). dk/dv of a k tile finish
+  within its q loop; dq's sum runs over the outer k axis, so one
+  batch*head's [S, D] float32 row of dq stays in VMEM for the row's grid
+  steps and is written back once. Backward memory is O(S) + tiles, not
+  O(S^2); the call asks for its VMEM (`_bwd_vmem_bytes`), and a row
+  VMEM cannot hold raises, naming ring/Ulysses attention.
 - delta = rowsum(dout * out) is precomputed in one cheap fused XLA
   elementwise pass and streamed like lse.
 
@@ -46,6 +50,9 @@ DEFAULT_BLOCK_Q = 1024
 DEFAULT_BLOCK_K = 1024
 NEG_INF = -1e30
 LANES = 128  # lane replication for row statistics (lse, delta)
+# What one backward call may ask of VMEM (a v5e core has 128 MiB; Mosaic's
+# default scoped limit is 16 MiB, under what 1024x1024 float32 tiles need).
+VMEM_BUDGET_BYTES = 96 * 2**20
 
 
 def _use_pallas():
@@ -278,58 +285,25 @@ def _flash_forward(q, k, v, causal, block_q, block_k, emit_lse):
     return out.reshape(b, h, s, d), lse[:, :, 0].reshape(b, h, s)
 
 
-# ---------- backward kernels ----------
+# ---------- backward kernel ----------
 
 
-def _dq_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_scr,
-    *, block_q, block_k, num_k_blocks, causal, scale,
-):
-    from jax.experimental import pallas as pl
-
-    i = pl.program_id(1)  # q block
-    j = pl.program_id(2)  # k block (fastest)
-    last_j = _last_kj(i, block_q, block_k, num_k_blocks, causal)
-
-    @pl.when(j == 0)
-    def _init():
-        dq_scr[:] = jnp.zeros(dq_scr.shape, jnp.float32)
-
-    relevant = (j <= last_j) if causal else True
-
-    @pl.when(relevant)
-    def _accumulate():
-        q = q_ref[:].astype(jnp.float32)
-        k = k_ref[:].astype(jnp.float32)
-        v = v_ref[:].astype(jnp.float32)
-        do = do_ref[:].astype(jnp.float32)
-        lse = lse_ref[:, :1]  # [block_q, 1]
-        delta = delta_ref[:, :1]
-        scores = scale * jnp.dot(q, k.T, preferred_element_type=jnp.float32)
-        if causal:
-            scores = _causal_mask_scores(scores, i, j, block_q, block_k)
-        p = jnp.exp(scores - lse)
-        dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)
-        dq_scr[:] = dq_scr[:] + scale * jnp.dot(
-            ds, k, preferred_element_type=jnp.float32
-        )
-
-    @pl.when(j == last_j)
-    def _finalize():
-        dq_ref[:] = dq_scr[:].astype(dq_ref.dtype)
-
-
-def _dkv_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref,
-    dk_scr, dv_scr,
-    *, block_q, block_k, num_q_blocks, causal, scale,
+def _bwd_kernel(
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
+    dq_scr, dk_scr, dv_scr,
+    *, block_q, block_k, num_q_blocks, num_k_blocks, causal, scale,
 ):
     from jax.experimental import pallas as pl
 
     j = pl.program_id(1)  # k block
     i = pl.program_id(2)  # q block (fastest)
     first_i = _first_qi(j, block_q, block_k, causal)
+
+    # dq's sum runs over j, the outer axis: the whole [S, D] row of this
+    # batch*head stays in VMEM from the row's first grid step to its last.
+    @pl.when((j == 0) & (i == 0))
+    def _init_row():
+        dq_scr[:] = jnp.zeros(dq_scr.shape, jnp.float32)
 
     @pl.when(i == 0)
     def _init():
@@ -346,7 +320,7 @@ def _dkv_kernel(
         k = k_ref[:].astype(jnp.float32)
         v = v_ref[:].astype(jnp.float32)
         do = do_ref[:].astype(jnp.float32)
-        lse = lse_ref[:, :1]
+        lse = lse_ref[:, :1]  # [block_q, 1]
         delta = delta_ref[:, :1]
         scores = scale * jnp.dot(q, k.T, preferred_element_type=jnp.float32)
         if causal:
@@ -360,11 +334,35 @@ def _dkv_kernel(
         dk_scr[:] = dk_scr[:] + scale * jnp.dot(
             ds.T, q, preferred_element_type=jnp.float32
         )
+        rows = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
+        dq_scr[rows, :] = dq_scr[rows, :] + scale * jnp.dot(
+            ds, k, preferred_element_type=jnp.float32
+        )
 
     @pl.when(i == num_q_blocks - 1)
     def _finalize():
         dk_ref[:] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[:] = dv_scr[:].astype(dv_ref.dtype)
+
+    @pl.when((j == num_k_blocks - 1) & (i == num_q_blocks - 1))
+    def _finalize_row():
+        dq_ref[:] = dq_scr[:].astype(dq_ref.dtype)
+
+
+def _bwd_vmem_bytes(s, d, block_q, block_k, itemsize):
+    """VMEM the backward call asks for, from its shapes: the float32
+    score-sized tiles (scores, p, dp, ds and the two transposes), the
+    double-buffered input and output blocks, dq's row (scratch plus its
+    double-buffered output block) and the dk/dv scratch."""
+    tiles = 6 * block_q * block_k * 4
+    blocks = 2 * (
+        2 * (block_q + block_k) * d * itemsize  # q, dO; k, v
+        + 2 * block_q * LANES * 4  # lse, delta
+        + 2 * block_k * d * itemsize  # dk, dv
+    )
+    dq_row = s * d * (4 + 2 * itemsize)
+    scratch = 2 * block_k * d * 4
+    return tiles + blocks + dq_row + scratch
 
 
 def _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k):
@@ -374,7 +372,15 @@ def _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k):
     b, h, s, d = q.shape
     bh = b * h
     num_q, num_k = s // block_q, s // block_k
-    scale = d**-0.5
+    vmem_bytes = _bwd_vmem_bytes(s, d, block_q, block_k, q.dtype.itemsize)
+    if vmem_bytes > VMEM_BUDGET_BYTES:
+        raise ValueError(
+            f"flash_attention: the backward keeps one [{s}, {d}] float32 "
+            f"row of dq in VMEM and would need {vmem_bytes >> 20} MiB of "
+            f"it (budget {VMEM_BUDGET_BYTES >> 20} MiB); shard the "
+            "sequence with ring or Ulysses attention "
+            "(parallel/ring_attention.py, parallel/ulysses.py)"
+        )
 
     q3, k3, v3 = (x.reshape(bh, s, d) for x in (q, k, v))
     g3 = g.reshape(bh, s, d)
@@ -387,82 +393,59 @@ def _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k):
     )
     delta_fat = jnp.broadcast_to(delta[:, :, None], (bh, s, LANES))
 
-    # dq: grid (bh, q, k) — q-indexed tiles are major, k-indexed minor.
-    dq = pl.pallas_call(
-        functools.partial(
-            _dq_kernel,
-            block_q=block_q,
-            block_k=block_k,
-            num_k_blocks=num_k,
-            causal=causal,
-            scale=scale,
-        ),
-        grid=(bh, num_q, num_k),
-        in_specs=[
-            pl.BlockSpec((None, block_q, d), lambda b_, i, j: (b_, i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((None, block_k, d), lambda b_, i, j: (b_, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((None, block_k, d), lambda b_, i, j: (b_, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((None, block_q, d), lambda b_, i, j: (b_, i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((None, block_q, LANES), lambda b_, i, j: (b_, i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((None, block_q, LANES), lambda b_, i, j: (b_, i, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec(
-            (None, block_q, d), lambda b_, i, j: (b_, i, 0),
-            memory_space=pltpu.VMEM,
-        ),
-        out_shape=jax.ShapeDtypeStruct((bh, s, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        interpret=_interpret(),
-        name="flash_bwd_dq",
-    )(q3, k3, v3, g3, lse_fat, delta_fat)
+    # Grid (bh, k, q): k-indexed tiles are major, q-indexed minor. Steps
+    # above the diagonal re-address the first relevant q tile.
+    def q_index(b_, j, i):
+        return (
+            b_, jnp.maximum(i, _first_qi(j, block_q, block_k, causal)), 0
+        )
 
-    # dk/dv: grid (bh, k, q) — k-indexed tiles are major, q-indexed minor.
-    dk, dv = pl.pallas_call(
+    def q_spec(width):
+        return pl.BlockSpec(
+            (None, block_q, width), q_index, memory_space=pltpu.VMEM
+        )
+
+    k_spec = pl.BlockSpec(
+        (None, block_k, d), lambda b_, j, i: (b_, j, 0),
+        memory_space=pltpu.VMEM,
+    )
+    dq, dk, dv = pl.pallas_call(
         functools.partial(
-            _dkv_kernel,
+            _bwd_kernel,
             block_q=block_q,
             block_k=block_k,
             num_q_blocks=num_q,
+            num_k_blocks=num_k,
             causal=causal,
-            scale=scale,
+            scale=d**-0.5,
         ),
         grid=(bh, num_k, num_q),
         in_specs=[
-            pl.BlockSpec((None, block_q, d), lambda b_, j, i: (b_, i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((None, block_k, d), lambda b_, j, i: (b_, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((None, block_k, d), lambda b_, j, i: (b_, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((None, block_q, d), lambda b_, j, i: (b_, i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((None, block_q, LANES), lambda b_, j, i: (b_, i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((None, block_q, LANES), lambda b_, j, i: (b_, i, 0),
-                         memory_space=pltpu.VMEM),
+            q_spec(d), k_spec, k_spec, q_spec(d),
+            q_spec(LANES), q_spec(LANES),
         ],
         out_specs=[
-            pl.BlockSpec((None, block_k, d), lambda b_, j, i: (b_, j, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((None, block_k, d), lambda b_, j, i: (b_, j, 0),
-                         memory_space=pltpu.VMEM),
+            # Indexed by the row alone: written back once a row.
+            pl.BlockSpec(
+                (None, s, d), lambda b_, j, i: (b_, 0, 0),
+                memory_space=pltpu.VMEM,
+            ),
+            k_spec,
+            k_spec,
         ],
         out_shape=[
+            jax.ShapeDtypeStruct((bh, s, d), q.dtype),
             jax.ShapeDtypeStruct((bh, s, d), k.dtype),
             jax.ShapeDtypeStruct((bh, s, d), v.dtype),
         ],
         scratch_shapes=[
+            pltpu.VMEM((s, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_bytes),
         interpret=_interpret(),
-        name="flash_bwd_dkv",
+        name="flash_bwd",
     )(q3, k3, v3, g3, lse_fat, delta_fat)
 
     return (

@@ -129,21 +129,40 @@ def test_flash_attention_kernel_interpret(qkv, monkeypatch):
         )
 
 
-def test_flash_attention_gradients(qkv, monkeypatch):
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+@pytest.mark.parametrize(
+    "s,block_q,block_k",
+    [
+        (128, 128, 128),  # one tile a side: grid (bh, 1, 1)
+        (256, 128, 128),
+        (512, 128, 128),  # dq summed over 4 steps of the outer axis
+        (512, 128, 256),
+        (512, 256, 128),
+    ],
+)
+def test_flash_attention_gradients(monkeypatch, causal, s, block_q, block_k):
+    """The one backward kernel (interpret mode) against the gradients of
+    full attention: dq is summed across the outer grid axis and, causal,
+    across the first_i clamp; float32 sums earn a float32 tolerance."""
     monkeypatch.setenv("EDL_FORCE_PALLAS_INTERPRET", "1")
-    q, k, v = qkv
+    rng = np.random.default_rng(s + block_q)
+    q, k, v = (
+        jnp.asarray(rng.normal(size=(1, 2, s, 32)), jnp.float32)
+        for _ in range(3)
+    )
 
     def loss_flash(q, k, v):
-        return jnp.sum(flash_attention(q, k, v, True, 128, 128) ** 2)
+        out = flash_attention(q, k, v, causal, block_q, block_k)
+        return jnp.sum(out ** 2)
 
     def loss_ref(q, k, v):
-        return jnp.sum(reference_attention(q, k, v, causal=True) ** 2)
+        return jnp.sum(reference_attention(q, k, v, causal=causal) ** 2)
 
     gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
     gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(gf, gr):
         np.testing.assert_allclose(
-            np.asarray(a), np.asarray(b), atol=5e-2
+            np.asarray(a), np.asarray(b), atol=1e-4, rtol=1e-4
         )
 
 

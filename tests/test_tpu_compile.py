@@ -89,8 +89,9 @@ def test_flash_forward_backward_compiles_for_v5e(
         .lower(*_qkv(shape, one_chip))
         .compile()
     )
-    # forward (with lse), dq, dk/dv.
-    assert compiled.as_text().count("tpu_custom_call") == 3
+    # forward (with lse) and the one backward kernel; S 8192 is the case
+    # that asks the most VMEM (dq's float32 row is 4 MiB there).
+    assert compiled.as_text().count("tpu_custom_call") == 2
 
 
 def test_flash_kernel_partitions_over_a_data_mesh(topo, kernel_on):
@@ -110,7 +111,7 @@ def test_flash_kernel_partitions_over_a_data_mesh(topo, kernel_on):
         .compile()
     )
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") == 3
+    assert text.count("tpu_custom_call") == 2
     # Per device: 4 of the 16 rows.
     assert "f32[4,8,4096,128]" in text
 
@@ -119,6 +120,23 @@ def test_unservable_sequence_raises_where_the_kernel_runs(kernel_on):
     q = jnp.zeros((1, 1, 1100, 128), jnp.float32)
     with pytest.raises(ValueError, match="not a multiple"):
         jax.eval_shape(lambda q: fa.flash_attention(q, q, q, True), q)
+
+
+def test_overlong_dq_row_raises_where_the_kernel_runs(kernel_on):
+    """The backward holds one [S, D] float32 row of dq in VMEM; a sequence
+    whose row cannot be held names the sequence-parallel paths instead of
+    taking a second one in silence. The forward still serves it."""
+    q = jax.ShapeDtypeStruct((1, 1, 65536, 128), jnp.float32)
+
+    def loss(q):
+        return jnp.sum(fa.flash_attention(q, q, q, True))
+
+    assert jax.eval_shape(loss, q).shape == ()
+    with pytest.raises(ValueError, match="ring or Ulysses"):
+        jax.eval_shape(jax.grad(loss), q)
+    # The longest row the budget admits at these blocks is served.
+    q = jax.ShapeDtypeStruct((1, 1, 32768, 128), jnp.float32)
+    assert jax.eval_shape(jax.grad(loss), q).shape == q.shape
 
 
 def test_flagship_step_compiles_and_fits_one_v5e(topo, kernel_on,
@@ -174,7 +192,8 @@ def test_flagship_step_compiles_and_fits_one_v5e(topo, kernel_on,
         compiled = step.lower(*abstract).compile()
     finally:
         trainer.close()
-    assert compiled.as_text().count("tpu_custom_call") == 36  # 12 x 3
+    # 12 layers x (flash_fwd, flash_bwd)
+    assert compiled.as_text().count("tpu_custom_call") == 24
     mem = compiled.memory_analysis()
     resident = (
         mem.argument_size_in_bytes + mem.output_size_in_bytes
