@@ -9,6 +9,9 @@ XLA inserting the all-to-alls. Dropped tokens (over capacity) pass through
 the residual unchanged, the standard Switch behavior.
 """
 
+import functools
+from typing import Optional, Tuple
+
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
@@ -121,3 +124,246 @@ def moe_param_specs(params, expert_axis=MODEL_AXIS):
         else:
             specs[path] = P()
     return nest_at(specs)
+
+
+# ---------- top-k routed experts, this chip's share ----------
+
+ROUTING_SCOPE = "moe_routing"
+GROUPED_SCOPE = "moe_grouped"
+SHARED_SCOPE = "moe_shared"
+
+
+def route_top_k(scores, correction_bias, k, norm_topk_prob, scaling_factor):
+    """scores [T, E] float32 (after the sigmoid) -> (experts [T, k] int32,
+    weights [T, k] float32). The k experts are the largest of scores +
+    correction_bias; the weights are the scores themselves at the chosen
+    (without the bias), divided by their sum if `norm_topk_prob`, times
+    `scaling_factor`."""
+    _, experts = jax.lax.top_k(scores + correction_bias, k)
+    weights = jnp.take_along_axis(scores, experts, axis=-1)
+    if norm_topk_prob:
+        weights = weights / (jnp.sum(weights, -1, keepdims=True) + 1e-20)
+    return experts.astype(jnp.int32), weights * scaling_factor
+
+
+def plan_held_blocks(experts, first, count, block):
+    """Sort the assignments that fall on the held experts [first, first +
+    count) by expert, and cut each expert's run into blocks of `block`
+    rows. Nothing is dropped: the number of blocks follows the load, and an
+    expert without an assignment takes none.
+
+    experts [T, k] int32. Returns a dict of small int32 arrays:
+      order       [T*k + block] assignment ids (token * k + slot) sorted by
+                  held expert (the others last), padded so that a block's
+                  slice never runs off the end
+      counts      [count] assignments on each held expert
+      group_start [count] where each expert's run starts in `order`
+      block_end   [count] running number of blocks up to and with each expert
+      n_blocks    []      blocks in all
+    """
+    flat = experts.reshape(-1)
+    held = (flat >= first) & (flat < first + count)
+    local = jnp.where(held, flat - first, count)
+    _, order = jax.lax.sort(
+        (local, jnp.arange(flat.shape[0], dtype=jnp.int32)),
+        num_keys=1, is_stable=True)
+    counts = jnp.sum(
+        local[:, None] == jnp.arange(count, dtype=jnp.int32)[None], axis=0,
+        dtype=jnp.int32)
+    group_start = jnp.cumsum(counts) - counts
+    block_end = jnp.cumsum((counts + block - 1) // block)
+    return {
+        "order": jnp.pad(order, (0, block)),
+        "counts": counts, "group_start": group_start,
+        "block_end": block_end, "n_blocks": block_end[-1],
+    }
+
+
+def _block_rows(plan, i, block, k):
+    """Block i of the plan: (held expert, where its rows start in `order`,
+    assignment ids [block], tokens [block], which rows are real)."""
+    e = jnp.sum(i >= plan["block_end"], dtype=jnp.int32)
+    first_block = jnp.where(e > 0, plan["block_end"][e - 1], 0)
+    offset = (i - first_block) * block
+    start = plan["group_start"][e] + offset
+    ids = jax.lax.dynamic_slice(plan["order"], (start,), (block,))
+    real = offset + jnp.arange(block, dtype=jnp.int32) < plan["counts"][e]
+    return e, start, ids, jnp.where(real, ids // k, 0), real
+
+
+def _relu2_expert(rows, w_up, w_down):
+    u = jnp.dot(rows, w_up, preferred_element_type=jnp.float32)
+    a = jnp.maximum(u, 0.0)
+    h = (a * a).astype(rows.dtype)
+    return a, h, jnp.dot(h, w_down, preferred_element_type=jnp.float32)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def grouped_relu2_experts(x, weights, w_up, w_down, plan, block, k):
+    """sum over the held assignments (t, e) of weights[t, e] *
+    down_e(relu(up_e(x_t))^2), as [T, D] float32.
+
+    x [T, D]; weights [T, k] float32; w_up [count, D, F] and w_down
+    [count, F, D] float32 (cast to x's dtype for the products); plan from
+    `plan_held_blocks`. One loop iteration a block of `block` sorted rows,
+    all of one expert: gather the rows, two products, scatter-add. The trip
+    count is the number of blocks the load needs, so no token is dropped
+    and no product runs on padding beyond an expert's last block."""
+    return _grouped_forward(x, weights, w_up, w_down, plan, block, k)
+
+
+def _grouped_forward(x, weights, w_up, w_down, plan, block, k):
+    dtype = x.dtype
+    up, down = w_up.astype(dtype), w_down.astype(dtype)
+    flat_w = weights.reshape(-1)
+
+    def body(i, y):
+        e, _, ids, tokens, real = _block_rows(plan, i, block, k)
+        gate = jnp.where(real, flat_w[ids], 0.0)
+        _, _, out = _relu2_expert(x[tokens], up[e], down[e])
+        return y.at[tokens].add(gate[:, None] * out)
+
+    with jax.named_scope(GROUPED_SCOPE):
+        return jax.lax.fori_loop(
+            0, plan["n_blocks"], body, jnp.zeros(x.shape, jnp.float32))
+
+
+def _grouped_fwd(x, weights, w_up, w_down, plan, block, k):
+    y = _grouped_forward(x, weights, w_up, w_down, plan, block, k)
+    return y, (x, weights, w_up, w_down, plan)
+
+
+def _grouped_bwd(block, k, residuals, dy):
+    x, weights, w_up, w_down, plan = residuals
+    dtype, f32 = x.dtype, jnp.float32
+    up, down = w_up.astype(dtype), w_down.astype(dtype)
+    flat_w = weights.reshape(-1)
+    dy = dy.astype(dtype)
+
+    def body(i, carry):
+        dx, d_up, d_down, d_sorted = carry
+        e, start, ids, tokens, real = _block_rows(plan, i, block, k)
+        gate = jnp.where(real, flat_w[ids], 0.0)
+        rows, g = x[tokens], dy[tokens]
+        a, h, out = _relu2_expert(rows, up[e], down[e])
+        d_gate = jnp.sum(out * g.astype(f32), axis=-1)
+        d_out = (gate[:, None] * g.astype(f32)).astype(dtype)
+        d_h = jnp.dot(d_out, down[e].T, preferred_element_type=f32)
+        d_u = (d_h * 2.0 * a).astype(dtype)
+        d_rows = jnp.dot(d_u, up[e].T, preferred_element_type=f32)
+        d_down = d_down.at[e].add(
+            jnp.dot(h.T, d_out, preferred_element_type=f32))
+        d_up = d_up.at[e].add(
+            jnp.dot(rows.T, d_u, preferred_element_type=f32))
+        # A block's tail belongs to the next expert's run: keep what is
+        # there.
+        was = jax.lax.dynamic_slice(d_sorted, (start,), (block,))
+        d_sorted = jax.lax.dynamic_update_slice(
+            d_sorted, jnp.where(real, d_gate, was), (start,))
+        return dx.at[tokens].add(d_rows), d_up, d_down, d_sorted
+
+    with jax.named_scope(GROUPED_SCOPE):
+        dx, d_up, d_down, d_sorted = jax.lax.fori_loop(
+            0, plan["n_blocks"], body,
+            (jnp.zeros(x.shape, f32), jnp.zeros(w_up.shape, f32),
+             jnp.zeros(w_down.shape, f32),
+             jnp.zeros(plan["order"].shape, f32)))
+        # Back from sorted order to [T, k].
+        n = flat_w.shape[0]
+        _, d_flat = jax.lax.sort(
+            (plan["order"][:n], d_sorted[:n]), num_keys=1)
+    return (dx.astype(dtype), d_flat.reshape(weights.shape),
+            d_up.astype(w_up.dtype), d_down.astype(w_down.dtype), None)
+
+
+grouped_relu2_experts.defvjp(_grouped_fwd, _grouped_bwd)
+
+
+class RoutedExperts(nn.Module):
+    """Top-k of `num_experts` by sigmoid scores with a correction bias,
+    experts `down(relu(up(x))^2)` without a gate, and a shared expert of the
+    same form for every token (DeepSeek-V3's routing as the HF `nemotron_h`
+    model uses it). No auxiliary loss, no capacity: nothing is dropped.
+
+    The layer is told which experts it holds, `held = (first, count)`: it
+    routes over all `num_experts`, computes its own experts' part of the
+    result and leaves out what the others would have added (expert
+    parallelism without the exchange: on one chip there is none). The
+    shared expert is whole on every chip. Returns (y [B, S, D], stats):
+    token-expert assignments made, those that fell on held experts, the
+    largest and the mean count over the held experts."""
+
+    num_experts: int
+    num_experts_per_tok: int
+    d_hidden: int
+    d_shared: int = 0
+    held: Optional[Tuple[int, int]] = None
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    block_rows: int = 1024
+    # Not None: the router's logits are replaced, in the forward pass, by
+    # uniform noise drawn from this seed for each (row, position, expert),
+    # the same at every step; the gradient goes straight through to the
+    # router. Every expert then takes its 1/num_experts of the assignments
+    # whatever the weights: Megatron-Core's benchmark mode
+    # `--moe-router-force-load-balancing` (its `RandomSTE`).
+    force_balance_seed: Optional[int] = None
+    dtype: str = "bfloat16"
+    kernel_init: nn.initializers.Initializer = nn.initializers.normal(0.02)
+
+    @nn.compact
+    def __call__(self, x):
+        dtype, f32 = jnp.dtype(self.dtype), jnp.float32
+        b, s, d = x.shape
+        first, count = self.held or (0, self.num_experts)
+        if first < 0 or count < 1 or first + count > self.num_experts:
+            raise ValueError(
+                f"held experts [{first}, {first + count}) are not among "
+                f"the {self.num_experts}")
+        tokens = x.reshape(-1, d).astype(dtype)
+        k = self.num_experts_per_tok
+        with jax.named_scope(ROUTING_SCOPE):
+            router = self.param(
+                "router", self.kernel_init, (self.num_experts, d), f32)
+            # Untrained buffer (its update speed is not in the config):
+            # held at zero, it takes no gradient through top_k.
+            bias = self.variable(
+                "buffers", "e_score_correction_bias",
+                jnp.zeros, (self.num_experts,), f32)
+            logits = jnp.einsum(
+                "td,ed->te", tokens.astype(f32), router,
+                precision=jax.lax.Precision.HIGHEST)
+            if self.force_balance_seed is not None:
+                noise = jax.random.uniform(
+                    jax.random.PRNGKey(self.force_balance_seed),
+                    (b, s, self.num_experts), f32).reshape(logits.shape)
+                logits = logits + jax.lax.stop_gradient(noise - logits)
+            scores = jax.nn.sigmoid(logits)
+            experts, weights = route_top_k(
+                scores, jax.lax.stop_gradient(bias.value), k,
+                self.norm_topk_prob, self.routed_scaling_factor)
+            plan = plan_held_blocks(experts, first, count, self.block_rows)
+        w_up = self.param(
+            "w_up", self.kernel_init, (count, d, self.d_hidden), f32)
+        w_down = self.param(
+            "w_down", self.kernel_init, (count, self.d_hidden, d), f32)
+        y = grouped_relu2_experts(
+            tokens, weights, w_up, w_down, plan, self.block_rows, k)
+        if self.d_shared:
+            with jax.named_scope(SHARED_SCOPE):
+                h = nn.Dense(
+                    self.d_shared, use_bias=False, dtype=dtype,
+                    kernel_init=self.kernel_init, name="shared_up")(tokens)
+                h = jnp.square(jax.nn.relu(h))
+                y = y + nn.Dense(
+                    d, use_bias=False, dtype=dtype,
+                    kernel_init=self.kernel_init, name="shared_down",
+                )(h).astype(f32)
+        counts = plan["counts"].astype(f32)
+        stats = {
+            "moe_assignments": jnp.asarray(tokens.shape[0] * k, f32),
+            "moe_assignments_held": jnp.sum(counts),
+            "moe_held_load_max": jnp.max(counts),
+            "moe_held_load_mean": jnp.mean(counts),
+        }
+        return y.reshape(b, s, d).astype(x.dtype), stats

@@ -74,7 +74,7 @@ from elasticdl_tpu.parallel.mesh import (
     resolve_world_spec,
     shard_batch,
 )
-from elasticdl_tpu.worker.trainer import JaxTrainer
+from elasticdl_tpu.worker.trainer import JaxTrainer, split_stats
 from elasticdl_tpu.worker.world_speculator import (
     SpeculativeWorldCompiler,
     speculation_enabled,
@@ -1430,6 +1430,9 @@ class AllReduceTrainer(JaxTrainer):
             loss, grads, new_state = self._apply_train(
                 params, state, rng, features, labels, None
             )
+            # A model's statistics are per shard here; this path does
+            # not hand them back.
+            loss, _ = split_stats(loss)
             if ZERO_AXIS in axes:
                 # Intra-host leg stays exact f32 on ICI.
                 grads = jax.lax.pmean(grads, ZERO_AXIS)
@@ -1615,13 +1618,16 @@ class AllReduceTrainer(JaxTrainer):
         for attempt in range(self._max_comm_retries):
             try:
                 loss = self._run_sharded_step(features, labels)
-                if sync_step:
+                if sync_step and self._world_size > 1:
                     # Async dispatch means a collective failure surfaces on
                     # materialization, not dispatch. Block here — on the
                     # same cadence as the world check, which already costs
                     # a host round trip — so comm errors land inside this
                     # try block and the re-mesh/retry path below runs,
                     # instead of exploding later at a logging float().
+                    # A world of one has no peer to lose a collective to,
+                    # and the wait would drain its device for nothing
+                    # (PERF.md section 6, PR 27).
                     with tracing.span("trainer.world_check"):
                         # edl-lint: disable=hot-path-sync
                         jax.block_until_ready(loss)
@@ -1694,6 +1700,7 @@ class AllReduceTrainer(JaxTrainer):
         with self._state_lock:
             self._variables = new_variables
             self._opt_state = new_opt_state
+            loss, self.last_step_stats = split_stats(loss)
             self._version += 1
             # The eval host copy is stale from this step on; free it now
             # rather than pinning ~model-size host RAM until the next

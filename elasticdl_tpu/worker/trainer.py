@@ -58,6 +58,25 @@ def _to_device_batch(features):
     return jax.tree_util.tree_map(jnp.asarray, features)
 
 
+# A model's training output may carry a small pytree of statistics under
+# this key. The step then returns {"loss", "stats"} where it returns the
+# loss (one more output of the same program: no launch, no fence), and
+# `split_stats` takes it apart on the host. A model without the key gets
+# the bare loss and the step program it always had.
+STATS_KEY = "stats"
+
+
+def with_stats(loss, stats):
+    return loss if stats is None else {"loss": loss, STATS_KEY: stats}
+
+
+def split_stats(step_loss):
+    """(loss, stats or None) of what a step returned as its loss."""
+    if isinstance(step_loss, dict):
+        return step_loss["loss"], step_loss[STATS_KEY]
+    return step_loss, None
+
+
 class JaxTrainer(Trainer):
     """Shared JAX machinery: lazy variable init, jitted train/forward steps.
 
@@ -84,6 +103,9 @@ class JaxTrainer(Trainer):
         self._version = 0
         self._train_step = None
         self._forward = None
+        # Device arrays of the newest step's model statistics, or None;
+        # ready once that step's loss has been read.
+        self.last_step_stats = None
         # Checkpoint path to restore from right after lazy init (worker-side
         # resume for strategies whose state lives in the worker).
         self.restore_on_init = None
@@ -105,11 +127,17 @@ class JaxTrainer(Trainer):
             return
         self._rng, init_rng = jax.random.split(self._rng)
         device_features = _to_device_batch(features)
-        variables = self._model.init(
-            {"params": init_rng, "dropout": init_rng},
-            device_features,
-            training=False,
-        )
+        # One program, not an eager operation a tensor: a model of some
+        # hundred tensors took tens of seconds of set-up eagerly, and the
+        # program is served from the compile cache by the next job.
+        from elasticdl_tpu.observability.profiling import tracked_jit
+
+        variables = tracked_jit(
+            lambda rng, feats: self._model.init(
+                {"params": rng, "dropout": rng}, feats, training=False
+            ),
+            name="model_init",
+        )(init_rng, device_features)
         self._variables = jax.tree_util.tree_map(jnp.asarray, dict(variables))
         self._opt_state = self._optax.init(self._variables["params"])
         n_params = sum(
@@ -149,6 +177,12 @@ class JaxTrainer(Trainer):
                 mutable=mutable if mutable else False,
             )
             outputs, new_state = out if mutable else (out, state)
+            # What the model reports of its step (a routed layer's
+            # counts): handed back beside the loss, never differentiated.
+            stats = None
+            if isinstance(outputs, dict) and STATS_KEY in outputs:
+                outputs = dict(outputs)
+                stats = jax.lax.stop_gradient(outputs.pop(STATS_KEY))
             labels_real = labels
             if slice_to is not None:
                 # Only leaves carrying the batch dim get sliced back to
@@ -168,12 +202,12 @@ class JaxTrainer(Trainer):
 
                 outputs = jax.tree_util.tree_map(trim, outputs)
                 labels_real = jax.tree_util.tree_map(trim, labels)
-            return self._loss_fn(labels_real, outputs), new_state
+            return self._loss_fn(labels_real, outputs), (new_state, stats)
 
-        (loss, new_state), grads = jax.value_and_grad(
+        (loss, (new_state, stats)), grads = jax.value_and_grad(
             loss_of, has_aux=True
         )(params)
-        return loss, grads, new_state
+        return with_stats(loss, stats), grads, new_state
 
     def _step_body(self, variables, opt_state, rng, features, labels,
                    slice_to=None, model=None):
@@ -234,6 +268,7 @@ class JaxTrainer(Trainer):
         self._variables, self._opt_state, loss = self._train_step(
             *step_args
         )
+        loss, self.last_step_stats = split_stats(loss)
         self._version += 1
         # Lazy device scalar: converting to float here would block the host
         # on every step and serialize dispatch (the round-1 bench ceiling).

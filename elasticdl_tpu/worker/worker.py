@@ -35,6 +35,17 @@ _TASKS = _REG.counter(
     "Tasks this worker processed, by result",
     labelnames=("result",),
 )
+_MODEL_STATS_TOTAL = _REG.counter(
+    "edl_model_stats_total",
+    "What the model's step reported (a routed layer's assignment counts, "
+    "...), summed over the steps whose loss was read",
+    labelnames=("name",),
+)
+_MODEL_STATS = _REG.gauge(
+    "edl_model_stats",
+    "What the model's step reported, newest step whose loss was read",
+    labelnames=("name",),
+)
 _PHASE_SECONDS = _REG.histogram(
     "edl_phase_seconds",
     "Worker phase latency (batch_process + trainer phases)",
@@ -74,6 +85,10 @@ class Worker:
         # driven by whole-world leases instead of independent task pulls.
         self._lease_mode = lease_mode
         self._steps = 0
+        # The newest logged step whose loss is still unread: (step,
+        # version, loss, model stats). It is read once the next step is
+        # on the device's queue (_log_unread_loss).
+        self._unread_loss = None
         self._timing = Timing().bind_histogram(_PHASE_SECONDS)
         # When each dispatched step left the device, without a fence.
         self._step_clock = StepDoneClock()
@@ -139,6 +154,7 @@ class Worker:
         while True:
             task = self._tds.get_task()
             if task is None:
+                self._log_unread_loss()
                 # Batched leases: results buffered past the last fetch
                 # must land before the loop exits.
                 self._tds.flush_reports()
@@ -297,7 +313,11 @@ class Worker:
                 "Step %d (lease %d) loss %.6f",
                 self._steps,
                 lease_id,
-                self._fenced(loss),
+                self._fenced(
+                    self._steps,
+                    loss,
+                    getattr(self._trainer, "last_step_stats", None),
+                ),
             )
         return loss
 
@@ -348,6 +368,9 @@ class Worker:
         # trace_report.py stitch the task's cross-process chain together.
         tracing.set_context(task_id=task.task_id)
         try:
+            if task.type != pb.TRAINING:
+                # No training step will follow the last one soon.
+                self._log_unread_loss()
             with tracing.span(
                 "task_process",
                 task_type=pb.TaskType.Name(task.type),
@@ -416,6 +439,9 @@ class Worker:
             accepted, version, loss = self._trainer.train_minibatch(
                 features, labels
             )
+            # With this step queued behind it: the device goes from the
+            # logged step to this one while the host wakes up and logs.
+            self._log_unread_loss()
             if not accepted:
                 return
             self._steps += 1
@@ -424,19 +450,51 @@ class Worker:
             if self._steps % self._log_loss_steps == 0:
                 # Only materialize the (lazy, on-device) loss when logging;
                 # every other step stays dispatch-ahead.
-                logger.info(
-                    "Step %d (version %d) loss %.6f",
+                self._unread_loss = (
                     self._steps,
                     version,
-                    self._fenced(loss),
+                    loss,
+                    getattr(self._trainer, "last_step_stats", None),
                 )
 
-    @staticmethod
-    def _fenced(loss):
-        """The loss as a float: waits for the device to finish every
-        step up to this one, with the dispatch loop stalled meanwhile."""
+    def _log_unread_loss(self):
+        """Read and log the loss of the last logging step. Called once
+        the step after it is dispatched (and where none will be: before
+        an evaluation task, at the end of the job), so that the task
+        report, the next task's fetch and the wake-up from the wait all
+        happen while the device works: read at its own step, a loss cost
+        the device an idle gap at every fence, and a busy host made that
+        gap several times longer (PERF.md section 6, PR 27)."""
+        if self._unread_loss is None:
+            return
+        (step, version, loss, stats), self._unread_loss = (
+            self._unread_loss, None)
+        logger.info(
+            "Step %d (version %d) loss %.6f",
+            step,
+            version,
+            self._fenced(step, loss, stats),
+        )
+
+    def _fenced(self, step, loss, stats):
+        """The loss of `step` as a float: waits for the device to finish
+        every step up to it, with the dispatch loop stalled meanwhile.
+        What the model reported of that step is ready then too (one
+        program wrote both), and is published here and nowhere else."""
         with tracing.span("worker.loss_fence"):
-            return float(loss)
+            value = float(loss)
+        if stats is not None:
+            self._publish_model_stats(step, stats)
+        return value
+
+    def _publish_model_stats(self, step, stats):
+        import jax
+
+        stats = {k: float(v) for k, v in jax.device_get(stats).items()}
+        emit_event("model_stats", step=step, **stats)
+        for name, value in stats.items():
+            _MODEL_STATS_TOTAL.labels(name=name).inc(value)
+            _MODEL_STATS.labels(name=name).set(value)
 
     def _maybe_profile(self, next_step):
         """Open/close the trace window around `next_step` (the step about

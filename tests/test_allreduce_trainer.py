@@ -500,3 +500,33 @@ def test_multihost_eval_host_copy_cached_per_version(monkeypatch):
         finally:
             t.close()
             mc.close()
+
+
+@pytest.mark.parametrize("world_size, waits", [(1, 0), (2, 1)])
+def test_a_sync_step_waits_for_the_device_only_with_a_peer(
+    monkeypatch, world_size, waits
+):
+    """The wait after a sync step's dispatch is there to catch a lost
+    collective inside the retry block; a world of one has none to lose,
+    and keeps its device fed."""
+    import jax
+
+    with start_master(
+        training_shards={"f": (0, 100)}, with_membership=True
+    ) as m:
+        t, mc = _make_trainer(m, "127.0.0.1", 0, steps_per_world_check=1)
+        try:
+            x, y = _batch(16, seed=0)
+            t.train_minibatch(x, y)
+            assert t.world_size == 1
+            t._world_size = world_size
+            blocked = []
+            real = jax.block_until_ready
+            monkeypatch.setattr(
+                jax, "block_until_ready",
+                lambda tree: blocked.append(1) or real(tree))
+            t.train_minibatch(x, y)
+            assert len(blocked) == waits
+        finally:
+            t.close()
+            mc.close()

@@ -211,14 +211,20 @@ def test_a_profiled_job_writes_every_span_into_the_profilers_host_plane(
             assert inside, f"{name} lies outside every worker.step"
         elif name in OUTSIDE_STEP:
             assert not inside, f"{name} lies inside a worker.step"
-    # Step 4 is a sync step (the world check before the dispatch, the
-    # wait for the device after it) and a logged one.
-    order = [e[0] for e in main
-             if steps[1][1] <= e[1] and e[2] <= steps[1][2]
-             and e[0] in NESTED_IN_STEP]
-    assert order == ["datapath.decode", "trainer.world_check",
-                     "datapath.h2d", "trainer.dispatch",
-                     "trainer.world_check", "worker.loss_fence"]
+    def order(step):
+        return [e[0] for e in main
+                if step[1] <= e[1] and e[2] <= step[2]
+                and e[0] in NESTED_IN_STEP]
+
+    # Step 2 was a logged one: its loss is read in step 3, once step 3
+    # is dispatched behind it.
+    assert order(steps[0]) == ["datapath.decode", "datapath.h2d",
+                               "trainer.dispatch", "worker.loss_fence"]
+    # Step 4 is a sync step (the world check before the dispatch; a
+    # world of one does not wait for the device after it) and a logged
+    # one, read in step 5, after the trace.
+    assert order(steps[1]) == ["datapath.decode", "trainer.world_check",
+                               "datapath.h2d", "trainer.dispatch"]
 
 
 # ---------- the step-done clock ----------

@@ -224,3 +224,62 @@ def test_profile_start_step_zero_still_captures(tmp_path):
     for root, _, files in os.walk(profile_dir):
         found += [f for f in files if f.endswith(".xplane.pb")]
     assert found, f"no trace artifacts under {profile_dir}"
+
+
+class _OrderedLoss:
+    """A loss that notes when it is read."""
+
+    def __init__(self, step, seen):
+        self.step, self.seen = step, seen
+
+    def __float__(self):
+        self.seen.append(("read", self.step))
+        return 0.5
+
+    def block_until_ready(self):
+        return self
+
+
+def test_a_logged_loss_is_read_once_the_next_step_is_dispatched():
+    """The device never drains for a log line: the loss of a logging step
+    is read after the step behind it is queued, the last one at the end
+    of the job, each once and under its own step number."""
+    import logging
+
+    records = test_module.make_linear_records(96)
+    reader = InMemoryReader(records)
+    seen = []
+    logged = []
+    handler = logging.Handler()
+    handler.emit = lambda r: logged.append(r.getMessage())
+    log = logging.getLogger("elasticdl_tpu.worker.worker")
+    log.addHandler(handler)
+    try:
+        _run_ordered_job(reader, seen)
+    finally:
+        log.removeHandler(handler)
+    assert seen == [
+        ("dispatch", 1), ("dispatch", 2), ("dispatch", 3), ("read", 2),
+        ("dispatch", 4), ("dispatch", 5), ("read", 4), ("dispatch", 6),
+        ("read", 6),
+    ]
+    assert [line for line in logged if line.startswith("Step ")] == [
+        f"Step {s} (version {s}) loss 0.500000" for s in (2, 4, 6)]
+
+
+def _run_ordered_job(reader, seen):
+    with start_master(
+        training_shards=reader.create_shards(), records_per_task=32,
+    ) as m:
+        worker = make_worker(m["addr"], reader, JobType.TRAINING_ONLY)
+        worker._log_loss_steps = 2
+        real = worker.trainer.train_minibatch
+
+        def train_minibatch(features, labels):
+            accepted, version, _ = real(features, labels)
+            seen.append(("dispatch", version))
+            return accepted, version, _OrderedLoss(version, seen)
+
+        worker.trainer.train_minibatch = train_minibatch
+        worker.run()
+        assert worker.steps == 6
