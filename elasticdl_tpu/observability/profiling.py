@@ -257,10 +257,13 @@ class CompileTracker:
         return hist, CAUSE_SHAPE
 
     def record(self, name, cause, seconds, wall_seconds, sig=None,
-               mesh_token="", cache_hit=False):
+               mesh_token="", cache_hit=False, event_fields=None):
         """One observed compile: metrics + event + recent-report entry.
         The trace span is recorded by the caller (it owns the start
-        timestamp). `cache_hit=True` means the persistent compilation
+        timestamp). `event_fields` are what the builder of this jit said
+        about its form (`tracked_jit(event_fields=...)`, e.g. the sharded
+        step's `dp_overlap`); they ride on the event as they are.
+        `cache_hit=True` means the persistent compilation
         cache rehydrated the executable: the lowering updates the
         classification history (later re-lowerings of the same signature
         still read as rebuilds) but lands as a `compile_cache_hit` event
@@ -291,6 +294,7 @@ class CompileTracker:
             self._events.append(entry)
             del self._events[: -self._events_cap]
         world = current_mesh()[1]
+        event_fields = event_fields or {}
         if cache_hit:
             _C_CACHE_HITS.labels(fn=name, cause=cause).inc()
             _events.emit(
@@ -299,6 +303,7 @@ class CompileTracker:
                 cause=cause,
                 seconds=round(seconds, 4),
                 world_size=world,
+                **event_fields,
             )
             return
         _C_COMPILES.labels(fn=name, cause=cause).inc()
@@ -311,6 +316,7 @@ class CompileTracker:
             seconds=round(seconds, 4),
             first_call_seconds=round(wall_seconds, 4),
             world_size=world,
+            **event_fields,
         )
 
     def snapshot(self):
@@ -353,10 +359,11 @@ class TrackedFunction:
     working against the wrapped object.
     """
 
-    def __init__(self, jitted, name, key_argnums=None):
+    def __init__(self, jitted, name, key_argnums=None, event_fields=None):
         self._jitted = jitted
         self._name = name
         self._key_argnums = key_argnums
+        self._event_fields = event_fields
         self._seen = set()
         self._expected_cache = 0
 
@@ -420,6 +427,7 @@ class TrackedFunction:
                     _tracker.record(
                         self._name, CAUSE_DONATION, 0.0, 0.0,
                         mesh_token=mesh_token,
+                        event_fields=self._event_fields,
                     )
             return out
         _install_listener()
@@ -446,6 +454,7 @@ class TrackedFunction:
         _tracker.record(
             self._name, cause, compile_s, wall, sig=sig,
             mesh_token=mesh_token, cache_hit=cache_hit,
+            event_fields=self._event_fields,
         )
         tracing.record_span(
             f"compile:{self._name}", start, wall, cat="compile",
@@ -463,18 +472,23 @@ class TrackedFunction:
         return out
 
 
-def tracked_jit(fn, *, name, key_argnums=None, **jit_kwargs):
+def tracked_jit(fn, *, name, key_argnums=None, event_fields=None,
+                **jit_kwargs):
     """`jax.jit` with compile accounting. `name` is the logical step
     name the metrics/events carry (stable across rebuilds); `key_argnums`
     restricts the per-call shape signature to the argument positions
     that actually vary (trainers pass the batch so the hot path never
-    flattens the parameter tree)."""
+    flattens the parameter tree); `event_fields` is a dict the `compile`
+    / `compile_cache_hit` events of this function carry beside their own
+    fields (which form the builder took), at no cost to a warm call."""
     import jax
 
     jitted = jax.jit(fn, **jit_kwargs)
     if not tracker_enabled():
         return jitted
-    return TrackedFunction(jitted, name, key_argnums=key_argnums)
+    return TrackedFunction(
+        jitted, name, key_argnums=key_argnums, event_fields=event_fields
+    )
 
 
 # ---------------------------------------------------------------------------

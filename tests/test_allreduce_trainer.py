@@ -530,3 +530,122 @@ def test_a_sync_step_waits_for_the_device_only_with_a_peer(
         finally:
             t.close()
             mc.close()
+
+
+def _fake_mesh(platform, **axes):
+    """What `_dp_overlap_for` looks at of a mesh, for devices this
+    sandbox does not have."""
+    import types
+
+    n = int(np.prod(list(axes.values())))
+    device = types.SimpleNamespace(platform=platform)
+    return types.SimpleNamespace(
+        shape=dict(axes),
+        devices=np.array([device] * n, dtype=object).reshape(
+            tuple(axes.values())
+        ),
+    )
+
+
+@pytest.mark.parametrize(
+    "platform, axes, zero1, overlapped",
+    [
+        ("tpu", {"data": 4}, False, True),
+        ("tpu", {"data": 2, "zero": 2}, False, True),
+        ("tpu", {"data": 1}, False, False),
+        ("cpu", {"data": 8}, False, False),
+        ("tpu", {"data": 2, "model": 2}, False, False),
+        ("tpu", {"data": 2, "stage": 2}, False, False),
+        ("tpu", {"data": 2, "model": 1}, False, True),
+        ("tpu", {"data": 4}, True, False),
+    ],
+    ids=[
+        "tpu_dp4", "tpu_data_x_zero", "one_tpu", "cpu_dp8", "tpu_dp_x_tp",
+        "tpu_dp_x_stage", "tpu_model_axis_of_1", "tpu_zero1",
+    ],
+)
+def test_dp_overlap_is_decided_by_the_mesh(platform, axes, zero1,
+                                           overlapped):
+    """The overlapped gradient all-reduce is taken exactly for pure data
+    parallelism over more than one TPU; no knob enters."""
+    import types
+
+    trainer = types.SimpleNamespace(_zero1=zero1)
+    mesh = _fake_mesh(platform, **axes)
+    assert AllReduceTrainer._dp_overlap_for(trainer, mesh) is overlapped
+
+
+@pytest.mark.parametrize("overlap", [False, True],
+                         ids=["as_decided_here", "as_on_tpus"])
+def test_live_build_and_planner_hand_the_jit_the_same_arguments(
+    monkeypatch, tmp_path, overlap
+):
+    """`_sharded_step_for` and `plan_step_for_spec` both go through
+    `_jit_step`: for one (mesh, spec) the speculator's executable is
+    lowered from the jit arguments a local compile gets, compiler options
+    included, and the step's compile event says which form it took. A CPU
+    mesh of several devices takes no TPU option (its compiler would
+    refuse one) and trains as before."""
+    from elasticdl_tpu.observability import events as obs_events
+    from elasticdl_tpu.observability import profiling
+    from elasticdl_tpu.worker import allreduce_trainer as art
+
+    seen = []
+    real = profiling.tracked_jit
+
+    def recording(fn, **kwargs):
+        seen.append(dict(kwargs))
+        # A CPU compiler refuses the TPU compiler's options.
+        kwargs.pop("compiler_options", None)
+        return real(fn, **kwargs)
+
+    monkeypatch.setattr(profiling, "tracked_jit", recording)
+    if overlap:
+        monkeypatch.setattr(
+            AllReduceTrainer, "_dp_overlap_for", lambda self, mesh: True
+        )
+    log = obs_events.EventLog(
+        str(tmp_path / "events.jsonl"), job="t", role="test"
+    )
+    prev = obs_events.get_event_log()
+    obs_events.set_event_log(log)
+    try:
+        with start_master(
+            training_shards={"f": (0, 100)}, with_membership=True
+        ) as m:
+            t, mc = _make_trainer(m, "127.0.0.1", 0, seed=3)
+            try:
+                losses = []
+                for step in range(3):
+                    x, y = _batch(16, seed=step)
+                    _, _, loss = t.train_minibatch(x, y)
+                    losses.append(float(loss))
+                assert losses[-1] < losses[0]
+                assert dict(t._mesh.shape) == {"data": 8}
+                (live,) = [k for k in seen if k["name"] == "allreduce_step"]
+                plan = t.plan_step_for_spec(t._world_spec, 16)
+                assert plan is not None
+                planned = [
+                    k for k in seen if k["name"] == "allreduce_step"
+                ][-1]
+            finally:
+                t.close()
+                mc.close()
+    finally:
+        obs_events.set_event_log(prev)
+        log.close()
+    assert planned is not live
+    assert planned == live
+    assert live["event_fields"] == {"dp_overlap": overlap}
+    if overlap:
+        assert live["compiler_options"] == art.DP_OVERLAP_COMPILER_OPTIONS
+    else:
+        assert "compiler_options" not in live
+    step_events = [
+        e
+        for e in obs_events.read_events(str(tmp_path / "events.jsonl"))
+        if e["kind"] in ("compile", "compile_cache_hit")
+        and e["fn"] == "allreduce_step"
+    ]
+    assert step_events
+    assert all(e["dp_overlap"] is overlap for e in step_events)

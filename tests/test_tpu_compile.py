@@ -11,6 +11,7 @@ off around them (an AOT entry cannot be read back without a chip).
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -139,19 +140,17 @@ def test_overlong_dq_row_raises_where_the_kernel_runs(kernel_on):
     assert jax.eval_shape(jax.grad(loss), q).shape == q.shape
 
 
-def test_flagship_step_compiles_and_fits_one_v5e(topo, kernel_on,
-                                                 monkeypatch):
-    """The WHOLE flagship training step of AllReduceTrainer — the program
-    `edl train` runs at `flagship_config()` widths, minibatch 4 — for one
-    described chip: it contains the Pallas calls and fits 16 GB."""
-    from elasticdl_tpu.models.transformer import transformer_lm_flagship as m
+def _plan_step(m, rows_a_chip, seq, topo, monkeypatch, n_devices=1):
+    """The training step of AllReduceTrainer for model-def module `m` as
+    the speculator plans it for the first `n_devices` described chips:
+    (trainer, step, abstract args, mesh). The caller closes the trainer."""
     from elasticdl_tpu.parallel.mesh import WorldTopology, resolve_world_spec
     from elasticdl_tpu.worker.allreduce_trainer import AllReduceTrainer
 
     class NoMaster:
         worker_host = "127.0.0.1"
 
-    batch, seq = 4, 4096
+    batch = rows_a_chip * n_devices
     trainer = AllReduceTrainer(
         m.custom_model(), m.loss, m.optimizer(), NoMaster()
     )
@@ -172,34 +171,175 @@ def test_flagship_step_compiles_and_fits_one_v5e(topo, kernel_on,
         )
         trainer._step_rng_base = rng
         trainer._note_batch_abstract(tokens, tokens, batch)
-        n_params = sum(
-            int(np.prod(p.shape))
-            for p in jax.tree_util.tree_leaves(variables["params"])
-        )
-        assert n_params > 200e6  # 151M transformer + embeddings + head
-
         # The trainer builds its mesh from jax.devices(): hand it the
-        # described chip.
-        monkeypatch.setattr(
-            jax, "devices", lambda *a, **k: [topo.devices[0]]
-        )
+        # described chips.
+        devices = list(topo.devices)[:n_devices]
+        monkeypatch.setattr(jax, "devices", lambda *a, **k: devices)
         spec = resolve_world_spec(
             trainer._parallel_config(),
-            WorldTopology(n_devices=1, local_devices=1, n_processes=1),
+            WorldTopology(
+                n_devices=n_devices, local_devices=n_devices, n_processes=1
+            ),
             param_check=trainer._param_check,
         )
         _, step, abstract = trainer.plan_step_for_spec(spec, batch)
+        return trainer, step, abstract, spec.build_mesh()
+    except BaseException:
+        trainer.close()
+        raise
+
+
+def _plan_flagship_step(topo, monkeypatch, n_devices):
+    """The flagship at `flagship_config()` widths, 4 rows of 4096 a chip:
+    the program `edl train` runs in `lm_flagship.steady` and `.dp4`."""
+    from elasticdl_tpu.models.transformer import transformer_lm_flagship as m
+
+    planned = _plan_step(m, 4, 4096, topo, monkeypatch, n_devices)
+    n_params = sum(
+        int(np.prod(p.shape))
+        for p in jax.tree_util.tree_leaves(planned[0]._variables["params"])
+    )
+    if n_params < 200e6:  # 151M transformer + embeddings + head
+        planned[0].close()
+        raise AssertionError(f"not the flagship: {n_params} parameters")
+    return planned
+
+
+def _resident_bytes(compiled):
+    mem = compiled.memory_analysis()
+    return (
+        mem.argument_size_in_bytes + mem.output_size_in_bytes
+        - mem.alias_size_in_bytes + mem.temp_size_in_bytes
+    )
+
+
+def test_flagship_step_compiles_and_fits_one_v5e(topo, kernel_on,
+                                                 monkeypatch):
+    """The WHOLE flagship training step of AllReduceTrainer — the program
+    `edl train` runs at `flagship_config()` widths, minibatch 4 — for one
+    described chip: it contains the Pallas calls and fits 16 GB."""
+    trainer, step, abstract, _ = _plan_flagship_step(topo, monkeypatch, 1)
+    try:
         compiled = step.lower(*abstract).compile()
     finally:
         trainer.close()
     # 12 layers x (flash_fwd, flash_bwd)
     assert compiled.as_text().count("tpu_custom_call") == 24
-    mem = compiled.memory_analysis()
-    resident = (
-        mem.argument_size_in_bytes + mem.output_size_in_bytes
-        - mem.alias_size_in_bytes + mem.temp_size_in_bytes
-    )
+    resident = _resident_bytes(compiled)
     assert resident < HBM_BYTES, f"{resident / 2**30:.2f} GiB"
+
+
+_HLO_OP = re.compile(r"\s*(?:ROOT )?%\S+ = (.*?) ([a-z][a-z0-9\-]*)\(")
+_HLO_ARRAY = re.compile(r"(bf16|f32)\[([0-9,]*)\]")
+
+
+def _all_reduces(hlo_text):
+    """(blocking, fused): the `all-reduce` operations of the entry
+    computation, which hold the core for their whole length, and those the
+    compiler placed inside compute fusions (`%async_collective_fusion.N`),
+    whose steps run beside the fusions' own work; each as {result shape
+    without layouts: bytes}. A fused one is split over several such
+    fusions, each naming the whole buffer, so it is counted once a
+    shape."""
+    blocking, fused = {}, {}
+    computation = None
+    for line in hlo_text.split("\n"):
+        if line.startswith(("%", "ENTRY")):
+            computation = line.split(" ")[0]
+            continue
+        m = _HLO_OP.match(line)
+        if m is None or m.group(2) != "all-reduce":
+            continue
+        shape = re.sub(r"\{[^{}]*\}", "", m.group(1))
+        size = sum(
+            int(np.prod([int(d) for d in dims.split(",") if d]))
+            * (2 if dtype == "bf16" else 4)
+            for dtype, dims in _HLO_ARRAY.findall(shape)
+        )
+        if computation == "ENTRY":
+            blocking[shape] = blocking.get(shape, 0) + size
+        elif computation.startswith("%async_collective_fusion"):
+            fused[shape] = size
+    return blocking, fused
+
+
+def test_flagship_dp4_step_overlaps_its_gradient_all_reduces(
+    topo, kernel_on, monkeypatch
+):
+    """The flagship step over the described v5e:2x2 (global minibatch 16,
+    `lm_flagship.dp4`): it compiles, fits one chip's 16 GB, keeps its 24
+    kernels, and the all-reduces the TPU compiler can overlap run inside
+    compute fusions. Without `_dp_overlap_for`'s options this program
+    holds six blocking all-reduces of 512 MB a step in its entry
+    computation. An `all-reduce` there that merely carries
+    `async_collective_name` is one the compiler made asynchronous, found
+    no fusion for and folded back, so the assertion is on where the
+    operations are, not on that attribute. The compiler fuses an
+    all-reduce of one array only: what stays blocking is the combiner's
+    tuples (the layers' bf16 matrices, the biases, the norm vectors)."""
+    trainer, step, abstract, mesh = _plan_flagship_step(
+        topo, monkeypatch, 4
+    )
+    try:
+        assert trainer._dp_overlap_for(mesh)
+        compiled = step.lower(*abstract).compile()
+    finally:
+        trainer.close()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 24
+    resident = _resident_bytes(compiled)
+    assert resident < HBM_BYTES, f"{resident / 2**30:.2f} GiB"
+    blocking, fused = _all_reduces(text)
+    # The LM head's float32 weight gradient (134 MB, the largest single
+    # reduction of the step) and the embedding's scatter-add (67 MB).
+    assert fused == {
+        "f32[1024,32768]": 1024 * 32768 * 4,
+        "bf16[32768,1024]": 32768 * 1024 * 2,
+    }
+    # No all-reduce of a single array is left holding the core.
+    assert blocking and all(s.startswith("(") for s in blocking), blocking
+    assert sum(blocking.values()) < 320e6
+
+
+def test_flagship_one_chip_step_takes_no_option(topo, kernel_on,
+                                                monkeypatch):
+    """A world of one device has no all-reduce: `_dp_overlap_for` says no,
+    the step's jit gets no compiler option, and what it lowers is text for
+    text the plain `jax.jit` of the same step (the parent's program), so
+    the one-chip cells' cache entries and numerics cannot move."""
+    from elasticdl_tpu.observability import profiling
+
+    seen = []
+    real = profiling.tracked_jit
+
+    def recording(fn, **kwargs):
+        seen.append(kwargs)
+        return real(fn, **kwargs)
+
+    monkeypatch.setattr(profiling, "tracked_jit", recording)
+    trainer, step, abstract, mesh = _plan_flagship_step(topo, monkeypatch, 1)
+    try:
+        assert not trainer._dp_overlap_for(mesh)
+        (kwargs,) = seen
+        assert "compiler_options" not in kwargs
+        assert kwargs["event_fields"] == {"dp_overlap": False}
+        planned = step.lower(*abstract).as_text()
+        repl = NamedSharding(mesh, P())
+        data = NamedSharding(mesh, P("data"))
+        plain = jax.jit(
+            trainer._dp_step_fn(mesh, abstract[3].shape[0]),
+            in_shardings=(repl, repl, repl, data, data),
+            out_shardings=(repl, repl, repl),
+            donate_argnums=(0, 1),
+        ).lower(*abstract).as_text()
+    finally:
+        trainer.close()
+    # A Mosaic kernel's serialized body carries the call stack it was
+    # traced under (jax 0.9.0 keeps debug locations there), which differs
+    # between the two jit objects; everything else has to be the same.
+    payload = re.compile(r"[A-Za-z0-9+/=]{64,}")
+    assert planned.count("tpu_custom_call") == 24
+    assert payload.sub("KERNEL", planned) == payload.sub("KERNEL", plain)
 
 
 def test_nemotron_h_cut_step_compiles_and_fits_one_v5e(topo, kernel_on,
@@ -211,42 +351,9 @@ def test_nemotron_h_cut_step_compiles_and_fits_one_v5e(topo, kernel_on,
     loop of the grouped expert product; it fits 16 GB with the remat the
     model-def states, and hands its statistics back beside the loss."""
     from elasticdl_tpu.models.nemotron_h import nemotron_h_twotower_cut as m
-    from elasticdl_tpu.parallel.mesh import WorldTopology, resolve_world_spec
-    from elasticdl_tpu.worker.allreduce_trainer import AllReduceTrainer
 
-    class NoMaster:
-        worker_host = "127.0.0.1"
-
-    batch, seq = 2, 8192
-    trainer = AllReduceTrainer(
-        m.custom_model(), m.loss, m.optimizer(), NoMaster()
-    )
+    trainer, step, abstract, _ = _plan_step(m, 2, 8192, topo, monkeypatch)
     try:
-        tokens = jax.ShapeDtypeStruct((batch, seq), jnp.int32)
-        rng = jax.random.PRNGKey(0)
-        variables = jax.eval_shape(
-            lambda r, f: dict(
-                trainer._model.init(
-                    {"params": r, "dropout": r}, f, training=False
-                )
-            ),
-            rng, tokens,
-        )
-        trainer._variables = variables
-        trainer._opt_state = jax.eval_shape(
-            trainer._optax.init, variables["params"]
-        )
-        trainer._step_rng_base = rng
-        trainer._note_batch_abstract(tokens, tokens, batch)
-        monkeypatch.setattr(
-            jax, "devices", lambda *a, **k: [topo.devices[0]]
-        )
-        spec = resolve_world_spec(
-            trainer._parallel_config(),
-            WorldTopology(n_devices=1, local_devices=1, n_processes=1),
-            param_check=trainer._param_check,
-        )
-        _, step, abstract = trainer.plan_step_for_spec(spec, batch)
         lowered = step.lower(*abstract)
         out = jax.tree_util.tree_structure(lowered.out_info)
         compiled = lowered.compile()
@@ -257,10 +364,7 @@ def test_nemotron_h_cut_step_compiles_and_fits_one_v5e(topo, kernel_on,
     # One attention layer: flash_fwd (and its rematerialised twin) and
     # flash_bwd.
     assert 2 <= compiled.as_text().count("tpu_custom_call") <= 3
-    mem = compiled.memory_analysis()
-    resident = (
-        mem.argument_size_in_bytes + mem.output_size_in_bytes
-        - mem.alias_size_in_bytes + mem.temp_size_in_bytes
-    )
+    resident = _resident_bytes(compiled)
     assert resident < HBM_BYTES, f"{resident / 2**30:.2f} GiB"
-    assert mem.argument_size_in_bytes > 7.9e9  # params + Adam m and v
+    # params + Adam m and v
+    assert compiled.memory_analysis().argument_size_in_bytes > 7.9e9
