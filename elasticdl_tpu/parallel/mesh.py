@@ -442,11 +442,11 @@ def resolve_world_spec(
             axes, multi, topo, tp=mp_eff, sp=sp_eff, notes=notes
         )
 
-    if config.zero1 and multi and local_n > 1:
+    if config.zero1 and multi:
         from elasticdl_tpu.parallel.zero1 import zero_axis_demand
 
         demand = zero_axis_demand(local_n)
-        if demand.infeasible_reason(topo) is None:
+        if local_n > 1 and demand.infeasible_reason(topo) is None:
             # Factor pure DP into (data across processes, zero within):
             # the batch shards over both; optimizer state shards over
             # "zero" only, staying replicated across processes.
@@ -457,6 +457,11 @@ def resolve_world_spec(
                 zero1=True,
                 notes=notes,
             )
+        notes.append(
+            "zero1 has no effect in this world: there is no "
+            "intra-process axis to shard optimizer state over, so it "
+            "stays replicated"
+        )
     return _dp()
 
 

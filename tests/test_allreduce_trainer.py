@@ -533,8 +533,8 @@ def test_a_sync_step_waits_for_the_device_only_with_a_peer(
 
 
 def _fake_mesh(platform, **axes):
-    """What `_dp_overlap_for` looks at of a mesh, for devices this
-    sandbox does not have."""
+    """What `step_plan.dp_overlap_for` looks at of a mesh, for devices
+    this sandbox does not have."""
     import types
 
     n = int(np.prod(list(axes.values())))
@@ -568,27 +568,61 @@ def test_dp_overlap_is_decided_by_the_mesh(platform, axes, zero1,
                                            overlapped):
     """The overlapped gradient all-reduce is taken exactly for pure data
     parallelism over more than one TPU; no knob enters."""
-    import types
+    from elasticdl_tpu.parallel import step_plan
 
-    trainer = types.SimpleNamespace(_zero1=zero1)
     mesh = _fake_mesh(platform, **axes)
-    assert AllReduceTrainer._dp_overlap_for(trainer, mesh) is overlapped
+    assert step_plan.dp_overlap_for(mesh, zero1) is overlapped
 
 
-@pytest.mark.parametrize("overlap", [False, True],
-                         ids=["as_decided_here", "as_on_tpus"])
+def test_step_plan_imports_nothing_from_the_worker():
+    """`parallel/` lies below `worker/`: the step's builder is told what
+    it needs of the trainer (`StepModel`) and imports none of it."""
+    import ast
+
+    from elasticdl_tpu.parallel import step_plan
+
+    with open(step_plan.__file__) as f:
+        tree = ast.parse(f.read())
+    imported = set()
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            imported.update(a.name for a in node.names)
+        elif isinstance(node, ast.ImportFrom):
+            imported.update(f"{node.module}.{a.name}" for a in node.names)
+    assert any(n.startswith("elasticdl_tpu.parallel") for n in imported)
+    assert not [n for n in imported if n.startswith("elasticdl_tpu.worker")]
+
+
+@pytest.mark.parametrize(
+    "overlap, kw, axes",
+    [
+        (False, {}, {"data": 8}),
+        (True, {}, {"data": 8}),
+        (False, {"zero1": True}, {"data": 8}),
+        (False, {"quantized_grads": True}, {"data": 8}),
+        (False, {"model_parallel_size": 2,
+                 "param_specs_fn": test_module.param_specs},
+         {"data": 4, "model": 2}),
+        (False, {"model_parallel_size": 2, "quantized_grads": True,
+                 "param_specs_fn": test_module.param_specs},
+         {"data": 4, "model": 2}),
+    ],
+    ids=["as_decided_here", "as_on_tpus", "zero1", "quantized_grads",
+         "dp_x_tp", "dp_x_tp_quantized"],
+)
 def test_live_build_and_planner_hand_the_jit_the_same_arguments(
-    monkeypatch, tmp_path, overlap
+    monkeypatch, tmp_path, overlap, kw, axes
 ):
-    """`_sharded_step_for` and `plan_step_for_spec` both go through
-    `_jit_step`: for one (mesh, spec) the speculator's executable is
-    lowered from the jit arguments a local compile gets, compiler options
-    included, and the step's compile event says which form it took. A CPU
-    mesh of several devices takes no TPU option (its compiler would
-    refuse one) and trains as before."""
+    """`_sharded_step_for` and `plan_step_for_spec` both call
+    `step_plan.build_step`, one with the live world and one with a
+    spec's: for the live spec the speculator's executable is lowered
+    from the jit arguments a local compile gets (shardings, donation,
+    compiler options), and the step's compile event says which form it
+    took. A CPU mesh of several devices takes no TPU option (its compiler
+    would refuse one) and trains as before."""
     from elasticdl_tpu.observability import events as obs_events
     from elasticdl_tpu.observability import profiling
-    from elasticdl_tpu.worker import allreduce_trainer as art
+    from elasticdl_tpu.parallel import step_plan
 
     seen = []
     real = profiling.tracked_jit
@@ -602,7 +636,7 @@ def test_live_build_and_planner_hand_the_jit_the_same_arguments(
     monkeypatch.setattr(profiling, "tracked_jit", recording)
     if overlap:
         monkeypatch.setattr(
-            AllReduceTrainer, "_dp_overlap_for", lambda self, mesh: True
+            step_plan, "dp_overlap_for", lambda mesh, zero1: True
         )
     log = obs_events.EventLog(
         str(tmp_path / "events.jsonl"), job="t", role="test"
@@ -613,7 +647,7 @@ def test_live_build_and_planner_hand_the_jit_the_same_arguments(
         with start_master(
             training_shards={"f": (0, 100)}, with_membership=True
         ) as m:
-            t, mc = _make_trainer(m, "127.0.0.1", 0, seed=3)
+            t, mc = _make_trainer(m, "127.0.0.1", 0, seed=3, **kw)
             try:
                 losses = []
                 for step in range(3):
@@ -621,7 +655,7 @@ def test_live_build_and_planner_hand_the_jit_the_same_arguments(
                     _, _, loss = t.train_minibatch(x, y)
                     losses.append(float(loss))
                 assert losses[-1] < losses[0]
-                assert dict(t._mesh.shape) == {"data": 8}
+                assert dict(t._mesh.shape) == axes
                 (live,) = [k for k in seen if k["name"] == "allreduce_step"]
                 plan = t.plan_step_for_spec(t._world_spec, 16)
                 assert plan is not None
@@ -638,7 +672,9 @@ def test_live_build_and_planner_hand_the_jit_the_same_arguments(
     assert planned == live
     assert live["event_fields"] == {"dp_overlap": overlap}
     if overlap:
-        assert live["compiler_options"] == art.DP_OVERLAP_COMPILER_OPTIONS
+        assert (
+            live["compiler_options"] == step_plan.DP_OVERLAP_COMPILER_OPTIONS
+        )
     else:
         assert "compiler_options" not in live
     step_events = [

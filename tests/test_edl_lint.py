@@ -1288,6 +1288,23 @@ def test_real_tree_clean_under_the_dataflow_rules():
         assert run_rule(project, rule) == [], rule
 
 
+def test_the_sharded_step_is_followed_from_its_builder_to_its_call():
+    """`allreduce_step` is constructed in `step_plan.jit_step`, handed up
+    by `build_step` inside a tuple and returned by `_sharded_step_for`:
+    the engine follows the builders to the one call, so the donation and
+    hot-path-sync rules see the step's results as device values."""
+    from tools.edl_lint.dataflow import get_engine
+
+    engine = get_engine(Project.load(REPO))
+    (site,) = [
+        s for s in engine.jit_sites if s.jit_name == "allreduce_step"
+    ]
+    assert site.rel == "elasticdl_tpu/parallel/step_plan.py"
+    assert [caller.key[1] for caller, _ in site.call_sites] == [
+        "AllReduceTrainer._run_sharded_step"
+    ]
+
+
 def test_real_defect_pins_source_level():
     """Belt-and-braces pins on the exact fixes (the rules above are the
     behavioral pin; these catch a rule being weakened instead)."""
@@ -1298,7 +1315,7 @@ def test_real_defect_pins_source_level():
     assert "donate_argnums=(0, 1)" in ps  # ps_local_apply
     assert "float(loss)" not in ps  # sync path returns the lazy loss
     ar = open(
-        os.path.join(REPO, "elasticdl_tpu/worker/allreduce_trainer.py")
+        os.path.join(REPO, "elasticdl_tpu/parallel/step_plan.py")
     ).read()
     assert "donate_argnums=donate" in ar
     moe = open(os.path.join(REPO, "elasticdl_tpu/layers/moe.py")).read()

@@ -21,6 +21,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from elasticdl_tpu.ops import flash_attention as fa
+from elasticdl_tpu.parallel import step_plan
 
 pytestmark = pytest.mark.usefixtures("no_persistent_compile_cache")
 
@@ -269,7 +270,7 @@ def test_flagship_dp4_step_overlaps_its_gradient_all_reduces(
     """The flagship step over the described v5e:2x2 (global minibatch 16,
     `lm_flagship.dp4`): it compiles, fits one chip's 16 GB, keeps its 24
     kernels, and the all-reduces the TPU compiler can overlap run inside
-    compute fusions. Without `_dp_overlap_for`'s options this program
+    compute fusions. Without `dp_overlap_for`'s options this program
     holds six blocking all-reduces of 512 MB a step in its entry
     computation. An `all-reduce` there that merely carries
     `async_collective_name` is one the compiler made asynchronous, found
@@ -281,7 +282,7 @@ def test_flagship_dp4_step_overlaps_its_gradient_all_reduces(
         topo, monkeypatch, 4
     )
     try:
-        assert trainer._dp_overlap_for(mesh)
+        assert step_plan.dp_overlap_for(mesh, zero1=False)
         compiled = step.lower(*abstract).compile()
     finally:
         trainer.close()
@@ -303,7 +304,7 @@ def test_flagship_dp4_step_overlaps_its_gradient_all_reduces(
 
 def test_flagship_one_chip_step_takes_no_option(topo, kernel_on,
                                                 monkeypatch):
-    """A world of one device has no all-reduce: `_dp_overlap_for` says no,
+    """A world of one device has no all-reduce: `dp_overlap_for` says no,
     the step's jit gets no compiler option, and what it lowers is text for
     text the plain `jax.jit` of the same step (the parent's program), so
     the one-chip cells' cache entries and numerics cannot move."""
@@ -319,7 +320,7 @@ def test_flagship_one_chip_step_takes_no_option(topo, kernel_on,
     monkeypatch.setattr(profiling, "tracked_jit", recording)
     trainer, step, abstract, mesh = _plan_flagship_step(topo, monkeypatch, 1)
     try:
-        assert not trainer._dp_overlap_for(mesh)
+        assert not step_plan.dp_overlap_for(mesh, zero1=False)
         (kwargs,) = seen
         assert "compiler_options" not in kwargs
         assert kwargs["event_fields"] == {"dp_overlap": False}
@@ -327,7 +328,9 @@ def test_flagship_one_chip_step_takes_no_option(topo, kernel_on,
         repl = NamedSharding(mesh, P())
         data = NamedSharding(mesh, P("data"))
         plain = jax.jit(
-            trainer._dp_step_fn(mesh, abstract[3].shape[0]),
+            step_plan.dp_step_fn(
+                trainer._step_model(), mesh, abstract[3].shape[0]
+            ),
             in_shardings=(repl, repl, repl, data, data),
             out_shardings=(repl, repl, repl),
             donate_argnums=(0, 1),

@@ -73,6 +73,18 @@ def self_attr_chain(node):
     return None
 
 
+def _returns_name(fn_node, name):
+    """True when the function returns the local `name`, alone or as an
+    element of a returned tuple."""
+    for node in ast.walk(fn_node):
+        if not isinstance(node, ast.Return) or node.value is None:
+            continue
+        for elt in getattr(node.value, "elts", [node.value]):
+            if isinstance(elt, ast.Name) and elt.id == name:
+                return True
+    return False
+
+
 class FunctionInfo:
     """One analyzable function: module file, qualified name, AST node."""
 
@@ -460,13 +472,7 @@ class Engine:
                 if isinstance(target, ast.Name):
                     bound_local = target.id
             if isinstance(parent, ast.Return) or (
-                bound_local
-                and any(
-                    isinstance(n, ast.Return)
-                    and isinstance(n.value, ast.Name)
-                    and n.value.id == bound_local
-                    for n in ast.walk(owner.node)
-                )
+                bound_local and _returns_name(owner.node, bound_local)
             ):
                 self._jit_returning[owner.key] = site
                 continue
@@ -476,50 +482,40 @@ class Engine:
                     (owner.key, bound_local), []
                 ).append(site)
 
-        # Pass 2: attr bindings THROUGH builder methods —
-        # `self._train_step = self._build_train_step()` where the builder
-        # returns a construction; and locals bound from jit-returning
-        # method calls (`step = self._sharded_step_for(...)`).
-        for info in self.functions.values():
-            if not info.class_name:
-                continue
-            for node in ast.walk(info.node):
-                if not (
-                    isinstance(node, ast.Assign)
-                    and len(node.targets) == 1
-                    and isinstance(node.value, ast.Call)
-                ):
-                    continue
-                callee_attr = self_attr(node.value.func)
-                if not callee_attr:
-                    continue
+        # Pass 2: bindings THROUGH builders, to a fixpoint over the call
+        # graph — `self._train_step = self._build_train_step()` where the
+        # builder returns a construction, and chains of them: the sharded
+        # step is constructed in `step_plan.jit_step`, handed up by
+        # `build_step` inside a tuple, unpacked and returned by
+        # `_sharded_step_for`, and called in `_run_sharded_step`.
+        callees = {}
+        for edge in self.edges:
+            if not edge.deferred:
+                callees.setdefault(id(edge.call), []).append(edge.callee)
+        assigned_calls = [
+            (info, node)
+            for info in self.functions.values()
+            for node in ast.walk(info.node)
+            if isinstance(node, ast.Assign)
+            and len(node.targets) == 1
+            and id(node.value) in callees
+        ]
+        changed = True
+        while changed:
+            changed = False
+            for info, node in assigned_calls:
                 sites = [
                     self._jit_returning[key]
-                    for key in self._method_candidates(
-                        info.class_name, callee_attr
-                    )
+                    for key in callees[id(node.value)]
                     if key in self._jit_returning
                 ]
                 if not sites:
                     continue
                 target = node.targets[0]
-                attr = self_attr(target)
-                if attr:
-                    for site in sites:
-                        if site.binding is None:
-                            site.binding = ("attr", info.class_name, attr)
-                        self._jit_attr_bindings.setdefault(
-                            (info.class_name, attr), []
-                        ).append(site)
-                elif isinstance(target, ast.Name):
-                    for site in sites:
-                        if site.binding is None:
-                            site.binding = (
-                                "local", info.key, target.id
-                            )
-                        self._jit_local_bindings.setdefault(
-                            (info.key, target.id), []
-                        ).append(site)
+                # A tuple target binds every element: a builder's other
+                # results are never called, so it costs nothing.
+                for elt in getattr(target, "elts", [target]):
+                    changed |= self._bind_built(info, elt, sites)
 
         # Pass 3: call sites of every binding.
         for info in self.functions.values():
@@ -538,6 +534,34 @@ class Engine:
                         (info.key, func.id), ()
                     ):
                         site.call_sites.append((info, node))
+
+    def _bind_built(self, info, target, sites):
+        """Bind `target` (an attr or a local of `info`) to the jit sites a
+        builder call returned; True when `info` thereby became a builder
+        itself (it returns that local)."""
+        attr = self_attr(target)
+        if attr and info.class_name:
+            index, key = self._jit_attr_bindings, (info.class_name, attr)
+            binding = ("attr",) + key
+        elif isinstance(target, ast.Name):
+            index, key = self._jit_local_bindings, (info.key, target.id)
+            binding = ("local",) + key
+        else:
+            return False
+        bound = index.setdefault(key, [])
+        for site in sites:
+            if site.binding is None:
+                site.binding = binding
+            if site not in bound:
+                bound.append(site)
+        if (
+            binding[0] == "local"
+            and info.key not in self._jit_returning
+            and _returns_name(info.node, target.id)
+        ):
+            self._jit_returning[info.key] = sites[0]
+            return True
+        return False
 
     # -- queries ---------------------------------------------------------
 
