@@ -262,8 +262,8 @@ class CompileTracker:
         The trace span is recorded by the caller (it owns the start
         timestamp). `event_fields` are what the builder of this jit said
         about its form (`tracked_jit(event_fields=...)`, e.g. the sharded
-        step's `dp_overlap`); they ride on the event as they are.
-        `cache_hit=True` means the persistent compilation
+        step's `dp_overlap` and `update_apart`); they ride on the event as
+        they are. `cache_hit=True` means the persistent compilation
         cache rehydrated the executable: the lowering updates the
         classification history (later re-lowerings of the same signature
         still read as rebuilds) but lands as a `compile_cache_hit` event

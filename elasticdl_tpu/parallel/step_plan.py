@@ -56,8 +56,9 @@ class StepModel(NamedTuple):
     """What the trainer knows of the model, as the step needs it.
 
     `step_body(variables, opt_state, rng, features, labels, slice_to,
-    model=None)` is forward, backward and update; `apply_train(params,
-    state, rng, features, labels, slice_to)` forward and backward alone.
+    model=None, update_apart=False)` is forward, backward and update;
+    `apply_train(params, state, rng, features, labels, slice_to)` forward
+    and backward alone.
     `pipeline_build` and `sp_model` are the model spec's pipeline and
     context-parallel hooks bound to the world's mesh, None without one.
     """
@@ -252,10 +253,24 @@ def dp_overlap_for(mesh, zero1):
     return all(d.platform == "tpu" for d in mesh.devices.flat)
 
 
+def update_apart_for(mesh):
+    """Whether the plain data-parallel step for `mesh` keeps the
+    optimizer's update out of the weight-gradient products' fusions (an
+    `optimization_barrier` a gradient leaf, `step_body`'s
+    `update_apart`), decided like `dp_overlap_for` from what the mesh
+    shows and from nothing else. Taken when the mesh has one device:
+    nothing then stands between the backward and the update, and the
+    compiler fuses Adam into each product (PERF.md section 6, PR 31).
+    Over several devices a reduction or a resharding already stands
+    there, so the products compile alone and a barrier only perturbs
+    the schedule: such a world compiles the program it always did."""
+    return mesh.devices.size == 1
+
+
 # ---------- step bodies ----------
 
 
-def dp_step_fn(model, mesh, slice_to):
+def dp_step_fn(model, mesh, slice_to, update_apart):
     """The plain data-parallel step body for `mesh`. The trace runs
     under the mesh's abstract twin so ops that the partitioner cannot
     split on its own (the Pallas flash attention) can see which axes
@@ -266,6 +281,7 @@ def dp_step_fn(model, mesh, slice_to):
         with jax.sharding.use_abstract_mesh(abstract_mesh):
             return model.step_body(
                 variables, opt_state, rng, features, labels, slice_to,
+                update_apart=update_apart,
             )
 
     return step_fn
@@ -409,10 +425,11 @@ def pipeline_step_fn(model, mesh):
 # ---------- the build ----------
 
 
-def jit_step(step_fn, mesh, var_sh, opt_sh, donate, dp_overlap):
+def jit_step(step_fn, mesh, var_sh, opt_sh, donate, dp_overlap,
+             update_apart):
     """The `tracked_jit` of the sharded step. `dp_overlap` picks the
-    compiler options and rides on the step's `compile` /
-    `compile_cache_hit` events."""
+    compiler options; it and `update_apart` (what `step_fn` was built
+    with) ride on the step's `compile` / `compile_cache_hit` events."""
     from elasticdl_tpu.observability.profiling import tracked_jit
 
     repl = replicated_sharding(mesh)
@@ -425,7 +442,9 @@ def jit_step(step_fn, mesh, var_sh, opt_sh, donate, dp_overlap):
         step_fn,
         name="allreduce_step",
         key_argnums=(3, 4),
-        event_fields={"dp_overlap": dp_overlap},
+        event_fields={
+            "dp_overlap": dp_overlap, "update_apart": update_apart,
+        },
         in_shardings=(var_sh, opt_sh, repl, data, data),
         out_shardings=(var_sh, opt_sh, repl),
         donate_argnums=donate,
@@ -450,7 +469,7 @@ def build_step(model, mesh, n_processes, real_n, variables, opt_state):
     # final partial minibatch is (slightly) reweighted, matching
     # the reference's ragged-last-batch Horovod averaging.
     slice_to = real_n if n_processes == 1 else None
-    dp_overlap = False
+    dp_overlap = update_apart = False
     if model.pipeline_build is not None:
         step_fn = pipeline_step_fn(model, mesh)
     elif sp_active(model, mesh):
@@ -460,7 +479,8 @@ def build_step(model, mesh, n_processes, real_n, variables, opt_state):
     elif model.quantized_grads:
         step_fn = quantized_step_fn(model, mesh)
     else:
-        step_fn = dp_step_fn(model, mesh, slice_to)
+        update_apart = update_apart_for(mesh)
+        step_fn = dp_step_fn(model, mesh, slice_to, update_apart)
         dp_overlap = dp_overlap_for(mesh, model.zero1)
     var_sh = variables_sharding(model, mesh, variables)
     # Under TP and pipeline, optimizer-state shardings are deliberately
@@ -475,5 +495,7 @@ def build_step(model, mesh, n_processes, real_n, variables, opt_state):
         else opt_placement(model, mesh, n_processes, opt_state)
     )
     donate = donation_for(opt_sh, n_processes)
-    step = jit_step(step_fn, mesh, var_sh, opt_sh, donate, dp_overlap)
+    step = jit_step(
+        step_fn, mesh, var_sh, opt_sh, donate, dp_overlap, update_apart
+    )
     return (real_n, padded_n), step

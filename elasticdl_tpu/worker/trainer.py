@@ -8,6 +8,7 @@ forward, backward and optimizer update into one program, and the same step
 function is reused by the AllReduce trainer under shard_map.
 """
 
+import functools
 from abc import ABC, abstractmethod
 
 import jax
@@ -210,14 +211,29 @@ class JaxTrainer(Trainer):
         return with_stats(loss, stats), grads, new_state
 
     def _step_body(self, variables, opt_state, rng, features, labels,
-                   slice_to=None, model=None):
+                   slice_to=None, model=None, update_apart=False):
         """fwd + bwd + optimizer update; shared by every on-device-update
-        strategy (local and AllReduce)."""
+        strategy (local and AllReduce).
+
+        `update_apart` keeps the optimizer's update out of the fusions
+        that end in a weight-gradient product. With nothing between the
+        backward and the update the TPU compiler pulls Adam's elementwise
+        work over three more float32 operands into each product's output
+        fusion, and the product then runs at 53 to 84% of its roof where
+        it reaches 93 to 97% alone (PERF.md section 6, PR 31). The same
+        values reach the update either way; only the fusion boundary
+        moves. One barrier a leaf, never one over the tree: that would
+        hold every gradient live at once. The step's builder says when
+        (`step_plan.update_apart_for`)."""
         params = variables["params"]
         state = {k: v for k, v in variables.items() if k != "params"}
         loss, grads, new_state = self._apply_train(
             params, state, rng, features, labels, slice_to, model=model
         )
+        if update_apart:
+            grads = jax.tree_util.tree_map(
+                jax.lax.optimization_barrier, grads
+            )
         updates, new_opt_state = self._optax.update(
             grads, opt_state, params
         )
@@ -233,8 +249,13 @@ class JaxTrainer(Trainer):
         # MFU cache already refused to pay.
         from elasticdl_tpu.observability.profiling import tracked_jit
 
+        # One device, no reduction between the backward and the update:
+        # the update is compiled apart from the weight-gradient products
+        # (`_step_body`), and the step's compile event says so.
         return tracked_jit(
-            self._step_body, name="train_step", key_argnums=(3, 4),
+            functools.partial(self._step_body, update_apart=True),
+            name="train_step", key_argnums=(3, 4),
+            event_fields={"update_apart": True},
             donate_argnums=(0, 1),
         )
 

@@ -1,6 +1,7 @@
 """Test environment: force an 8-device virtual CPU platform BEFORE jax import
 so sharding/collective tests run without TPU hardware."""
 
+import contextlib
 import os
 import sys
 
@@ -34,16 +35,32 @@ ensure_compile_cache()
 import pytest  # noqa: E402
 
 
-@pytest.fixture()
-def no_persistent_compile_cache():
-    """Every compile in the test is a real one: for tests that assert
-    cold `compile` events, and for AOT compiles for a described chip
-    (whose entries cannot be read back without one)."""
+@contextlib.contextmanager
+def _persistent_compile_cache_off():
     from jax.experimental.compilation_cache import compilation_cache
 
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield
-    jax.config.update("jax_enable_compilation_cache", was)
-    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
+@pytest.fixture()
+def no_persistent_compile_cache():
+    """Every compile in the test is a real one: for tests that assert
+    cold `compile` events, and for AOT compiles for a described chip
+    (whose entries cannot be read back without one)."""
+    with _persistent_compile_cache_off():
+        yield
+
+
+@pytest.fixture(scope="module")
+def no_persistent_compile_cache_in_module():
+    """The same for a module-scoped fixture that compiles (pytest sets
+    those up before any function-scoped fixture)."""
+    with _persistent_compile_cache_off():
+        yield

@@ -670,7 +670,11 @@ def test_live_build_and_planner_hand_the_jit_the_same_arguments(
         log.close()
     assert planned is not live
     assert planned == live
-    assert live["event_fields"] == {"dp_overlap": overlap}
+    # A world of eight devices: the update is not held apart (that is
+    # for one device, where nothing else stands before it).
+    assert live["event_fields"] == {
+        "dp_overlap": overlap, "update_apart": False,
+    }
     if overlap:
         assert (
             live["compiler_options"] == step_plan.DP_OVERLAP_COMPILER_OPTIONS
@@ -685,3 +689,4 @@ def test_live_build_and_planner_hand_the_jit_the_same_arguments(
     ]
     assert step_events
     assert all(e["dp_overlap"] is overlap for e in step_events)
+    assert all(e["update_apart"] is False for e in step_events)

@@ -113,6 +113,110 @@ def test_remat_changes_no_value():
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-6)
 
 
+def _tiny(which):
+    """(model, loss, optimizer) of the tiny dense LM or the tiny hybrid."""
+    if which == "hybrid":
+        return nh.custom_model(TINY), nh.loss, nh.optimizer()
+    from elasticdl_tpu.models.transformer import transformer_lm as tlm
+
+    return tlm.custom_model(), tlm.loss, tlm.optimizer()
+
+
+@pytest.mark.parametrize("which", ["lm", "hybrid"])
+def test_the_update_apart_changes_no_value(which):
+    """A one-device step keeps the optimizer's update out of the
+    weight-gradient products' fusions with a barrier a gradient leaf
+    (`_step_body`'s `update_apart`): the same values reach Adam, so four
+    steps with and without it give the same losses and parameters."""
+    import functools
+
+    x = tokens(2, 17, 1)
+
+    def four_steps(update_apart):
+        model, loss_fn, optimizer = _tiny(which)
+        trainer = trainer_mod.LocalTrainer(model, loss_fn, optimizer, seed=3)
+        trainer.init_variables_if_needed(x[:, :-1])
+        if update_apart:
+            # What LocalTrainer always builds: nothing reduces its
+            # gradients over devices.
+            lowered = trainer._train_step.lower(
+                trainer._variables, trainer._opt_state,
+                jax.random.PRNGKey(0), jnp.asarray(x[:, :-1]),
+                jnp.asarray(x[:, 1:])).as_text()
+            assert lowered.count("optimization_barrier") == len(
+                jax.tree_util.tree_leaves(trainer._variables["params"]))
+        else:
+            trainer._train_step = jax.jit(
+                functools.partial(trainer._step_body, update_apart=False),
+                donate_argnums=(0, 1))
+        losses = []
+        for step in range(4):
+            batch = tokens(2, 17, step)
+            _, _, loss = trainer.train_minibatch(batch[:, :-1], batch[:, 1:])
+            losses.append(float(loss))
+        return losses, trainer.export_variables()["variables"]["params"]
+
+    apart_losses, apart = four_steps(True)
+    fused_losses, fused = four_steps(False)
+    assert apart_losses[-1] != apart_losses[0]
+    np.testing.assert_allclose(apart_losses, fused_losses, rtol=0, atol=1e-6)
+    for a, b in zip(jax.tree_util.tree_leaves(apart),
+                    jax.tree_util.tree_leaves(fused)):
+        np.testing.assert_allclose(a, b, rtol=0, atol=1e-6)
+
+
+@pytest.mark.parametrize("n_devices, apart", [(1, True), (4, False)])
+def test_the_step_event_says_whether_the_update_is_apart(
+        tmp_path, n_devices, apart):
+    """`update_apart` rides on the step's compile event beside
+    `dp_overlap`: true where the gradients are not reduced over devices
+    (`LocalTrainer`'s `train_step` always, the sharded step on a mesh of
+    one device), false on a data mesh of four, which is lowered without a
+    barrier as before."""
+    from jax.sharding import Mesh
+
+    from elasticdl_tpu.observability import events as obs_events
+    from elasticdl_tpu.parallel import step_plan
+    from elasticdl_tpu.parallel.mesh import DATA_AXIS
+
+    model, loss_fn, optimizer = _tiny("hybrid")
+    trainer = trainer_mod.LocalTrainer(model, loss_fn, optimizer, seed=3)
+    x = tokens(4, 17, 1)
+    log = obs_events.EventLog(
+        str(tmp_path / "events.jsonl"), job="t", role="test")
+    prev = obs_events.get_event_log()
+    obs_events.set_event_log(log)
+    try:
+        trainer.train_minibatch(x[:, :-1], x[:, 1:])
+        mesh = Mesh(np.array(jax.devices()[:n_devices]), (DATA_AXIS,))
+        assert step_plan.update_apart_for(mesh) is apart
+        _, step = step_plan.build_step(
+            step_plan.StepModel(
+                step_body=trainer._step_body,
+                apply_train=trainer._apply_train,
+                loss_fn=loss_fn, optax=trainer._optax),
+            mesh, 1, 4, trainer._variables, trainer._opt_state)
+        args = (trainer._variables, trainer._opt_state,
+                jax.random.PRNGKey(0), x[:, :-1], x[:, 1:])
+        n_leaves = len(
+            jax.tree_util.tree_leaves(trainer._variables["params"]))
+        assert step.lower(*args).as_text().count(
+            "optimization_barrier") == (n_leaves if apart else 0)
+        loss = step(*args)[2]["loss"]
+        assert np.isfinite(float(loss))
+    finally:
+        obs_events.set_event_log(prev)
+        log.close()
+    said = {
+        e["fn"]: e for e in obs_events.read_events(
+            str(tmp_path / "events.jsonl"))
+        if e["kind"] in ("compile", "compile_cache_hit")
+    }
+    assert said["train_step"]["update_apart"] is True
+    assert said["allreduce_step"]["update_apart"] is apart
+    assert said["allreduce_step"]["dp_overlap"] is False
+
+
 def test_a_model_without_an_attention_layer_is_causal():
     """The Mamba layers carry order: a later token changes no earlier
     logit, with or without the attention layer."""

@@ -12,6 +12,7 @@ off around them (an AOT entry cannot be read back without a chip).
 
 import os
 import re
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -54,13 +55,17 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.fixture()
-def kernel_on(monkeypatch):
+def _steer_to_the_kernel(monkeypatch):
     """jax.default_backend() is the CPU here, so the dispatch would take
     its CPU branch; steer it as the chip would (in the test, not through
     an option of the program)."""
     monkeypatch.delenv("EDL_FORCE_PALLAS_INTERPRET", raising=False)
     monkeypatch.setattr(fa, "_use_pallas", lambda: True)
+
+
+@pytest.fixture()
+def kernel_on(monkeypatch):
+    _steer_to_the_kernel(monkeypatch)
 
 
 def _qkv(shape, sharding):
@@ -214,24 +219,133 @@ def _resident_bytes(compiled):
     )
 
 
-def test_flagship_step_compiles_and_fits_one_v5e(topo, kernel_on,
-                                                 monkeypatch):
+class _CompiledStep(NamedTuple):
+    """A planned step, lowered and compiled once a module (30 to 60 s a
+    compile): what the tests below read of it."""
+
+    mesh: Any
+    weights: Any  # {"f32[rows,columns]"}: the shapes of the parameters
+    n_grad_leaves: int
+    out_tree: Any
+    lowered: str  # step.lower(...).as_text(): what the program asked for
+    text: str  # compiled.as_text(): what the TPU compiler made of it
+    resident: int
+    argument_bytes: int
+
+
+def _compile_planned(plan):
+    """`plan(monkeypatch)` -> (trainer, step, abstract, mesh), under a
+    patch of its own that ends with the compile."""
+    with pytest.MonkeyPatch.context() as mp:
+        _steer_to_the_kernel(mp)
+        trainer, step, abstract, mesh = plan(mp)
+        try:
+            lowered = step.lower(*abstract)
+            compiled = lowered.compile()
+            params = jax.tree_util.tree_leaves(
+                trainer._variables["params"]
+            )
+        finally:
+            trainer.close()
+    return _CompiledStep(
+        mesh=mesh,
+        weights={
+            f"f32[{','.join(str(d) for d in p.shape)}]" for p in params
+        },
+        n_grad_leaves=len(params),
+        out_tree=jax.tree_util.tree_structure(lowered.out_info),
+        lowered=lowered.as_text(),
+        text=compiled.as_text(),
+        resident=_resident_bytes(compiled),
+        argument_bytes=compiled.memory_analysis().argument_size_in_bytes,
+    )
+
+
+@pytest.fixture(scope="module")
+def flagship_one_chip(topo, no_persistent_compile_cache_in_module):
+    return _compile_planned(lambda mp: _plan_flagship_step(topo, mp, 1))
+
+
+@pytest.fixture(scope="module")
+def flagship_dp4(topo, no_persistent_compile_cache_in_module):
+    return _compile_planned(lambda mp: _plan_flagship_step(topo, mp, 4))
+
+
+@pytest.fixture(scope="module")
+def nemotron_h_cut_one_chip(topo, no_persistent_compile_cache_in_module):
+    from elasticdl_tpu.models.nemotron_h import nemotron_h_twotower_cut as m
+
+    return _compile_planned(lambda mp: _plan_step(m, 2, 8192, topo, mp))
+
+
+def test_flagship_step_compiles_and_fits_one_v5e(flagship_one_chip):
     """The WHOLE flagship training step of AllReduceTrainer — the program
     `edl train` runs at `flagship_config()` widths, minibatch 4 — for one
     described chip: it contains the Pallas calls and fits 16 GB."""
-    trainer, step, abstract, _ = _plan_flagship_step(topo, monkeypatch, 1)
-    try:
-        compiled = step.lower(*abstract).compile()
-    finally:
-        trainer.close()
     # 12 layers x (flash_fwd, flash_bwd)
-    assert compiled.as_text().count("tpu_custom_call") == 24
-    resident = _resident_bytes(compiled)
+    assert flagship_one_chip.text.count("tpu_custom_call") == 24
+    resident = flagship_one_chip.resident
     assert resident < HBM_BYTES, f"{resident / 2**30:.2f} GiB"
 
 
 _HLO_OP = re.compile(r"\s*(?:ROOT )?%\S+ = (.*?) ([a-z][a-z0-9\-]*)\(")
 _HLO_ARRAY = re.compile(r"(bf16|f32)\[([0-9,]*)\]")
+_THREE_OF_ONE_F32 = re.compile(r"\((f32\[[0-9,]+\]), \1, \1\)")
+
+
+def _without_layouts(hlo_shape):
+    return re.sub(r"\{[^{}]*\}", "", hlo_shape)
+
+
+def _update_fusions(step):
+    """{fusion kind: [weight shapes]} of the entry computation's fusions
+    whose result is a tuple of three float32 arrays of one weight's
+    shape: the new parameter and both Adam moments of that weight.
+    `kOutput` is a fusion that ends in a product (the weight's gradient,
+    with the update pulled in behind it), `kLoop` the elementwise update
+    alone."""
+    found = {}
+    entry = step.text[step.text.index("\nENTRY"):]
+    for line in entry.split("\n"):
+        m = _HLO_OP.match(line)
+        if m is None or m.group(2) != "fusion":
+            continue
+        shape = _THREE_OF_ONE_F32.fullmatch(_without_layouts(m.group(1)))
+        if shape is not None and shape.group(1) in step.weights:
+            kind = re.search(r"kind=(\w+)", line).group(1)
+            found.setdefault(kind, []).append(shape.group(1))
+    return found
+
+
+def test_flagship_one_chip_step_compiles_its_update_apart(
+    flagship_one_chip,
+):
+    """On one device nothing stands between the backward and the
+    optimizer, and the TPU compiler then pulls Adam into the output
+    fusion of each weight-gradient product (49 of them at the parent:
+    four matrices a layer and the LM head), where the product runs at 53
+    to 84% of its roof (PERF.md section 6, PR 31). With one
+    `optimization_barrier` a gradient leaf the products compile alone and
+    the update as loop fusions."""
+    step = flagship_one_chip
+    assert step_plan.update_apart_for(step.mesh)
+    assert step.lowered.count("optimization_barrier") == step.n_grad_leaves
+    fusions = _update_fusions(step)
+    assert "kOutput" not in fusions, fusions["kOutput"]
+    # Every matrix's update is still there, as an elementwise loop.
+    assert len(fusions["kLoop"]) >= 49
+    assert "f32[1024,32768]" in fusions["kLoop"]
+
+
+def test_dp4_step_is_lowered_without_a_barrier(flagship_dp4):
+    """Over several devices GSPMD's all-reduce already stands between
+    each product and the update, so the products compile alone; a barrier
+    there only perturbs the schedule (the compiler's own estimate of the
+    dp4 step rises 3% with one). The lowered text is read: the compiled
+    text keeps no trace of a barrier."""
+    assert not step_plan.update_apart_for(flagship_dp4.mesh)
+    assert "optimization_barrier" not in flagship_dp4.lowered
+    assert "kOutput" not in _update_fusions(flagship_dp4)
 
 
 def _all_reduces(hlo_text):
@@ -251,7 +365,7 @@ def _all_reduces(hlo_text):
         m = _HLO_OP.match(line)
         if m is None or m.group(2) != "all-reduce":
             continue
-        shape = re.sub(r"\{[^{}]*\}", "", m.group(1))
+        shape = _without_layouts(m.group(1))
         size = sum(
             int(np.prod([int(d) for d in dims.split(",") if d]))
             * (2 if dtype == "bf16" else 4)
@@ -264,9 +378,7 @@ def _all_reduces(hlo_text):
     return blocking, fused
 
 
-def test_flagship_dp4_step_overlaps_its_gradient_all_reduces(
-    topo, kernel_on, monkeypatch
-):
+def test_flagship_dp4_step_overlaps_its_gradient_all_reduces(flagship_dp4):
     """The flagship step over the described v5e:2x2 (global minibatch 16,
     `lm_flagship.dp4`): it compiles, fits one chip's 16 GB, keeps its 24
     kernels, and the all-reduces the TPU compiler can overlap run inside
@@ -278,17 +390,10 @@ def test_flagship_dp4_step_overlaps_its_gradient_all_reduces(
     operations are, not on that attribute. The compiler fuses an
     all-reduce of one array only: what stays blocking is the combiner's
     tuples (the layers' bf16 matrices, the biases, the norm vectors)."""
-    trainer, step, abstract, mesh = _plan_flagship_step(
-        topo, monkeypatch, 4
-    )
-    try:
-        assert step_plan.dp_overlap_for(mesh, zero1=False)
-        compiled = step.lower(*abstract).compile()
-    finally:
-        trainer.close()
-    text = compiled.as_text()
+    assert step_plan.dp_overlap_for(flagship_dp4.mesh, zero1=False)
+    text = flagship_dp4.text
     assert text.count("tpu_custom_call") == 24
-    resident = _resident_bytes(compiled)
+    resident = flagship_dp4.resident
     assert resident < HBM_BYTES, f"{resident / 2**30:.2f} GiB"
     blocking, fused = _all_reduces(text)
     # The LM head's float32 weight gradient (134 MB, the largest single
@@ -306,8 +411,9 @@ def test_flagship_one_chip_step_takes_no_option(topo, kernel_on,
                                                 monkeypatch):
     """A world of one device has no all-reduce: `dp_overlap_for` says no,
     the step's jit gets no compiler option, and what it lowers is text for
-    text the plain `jax.jit` of the same step (the parent's program), so
-    the one-chip cells' cache entries and numerics cannot move."""
+    text the plain `jax.jit` of the same step body (the one with its
+    update apart, which is all that parts it from the step of a world of
+    several devices)."""
     from elasticdl_tpu.observability import profiling
 
     seen = []
@@ -323,13 +429,16 @@ def test_flagship_one_chip_step_takes_no_option(topo, kernel_on,
         assert not step_plan.dp_overlap_for(mesh, zero1=False)
         (kwargs,) = seen
         assert "compiler_options" not in kwargs
-        assert kwargs["event_fields"] == {"dp_overlap": False}
+        assert kwargs["event_fields"] == {
+            "dp_overlap": False, "update_apart": True,
+        }
         planned = step.lower(*abstract).as_text()
         repl = NamedSharding(mesh, P())
         data = NamedSharding(mesh, P("data"))
         plain = jax.jit(
             step_plan.dp_step_fn(
-                trainer._step_model(), mesh, abstract[3].shape[0]
+                trainer._step_model(), mesh, abstract[3].shape[0],
+                update_apart=True,
             ),
             in_shardings=(repl, repl, repl, data, data),
             out_shardings=(repl, repl, repl),
@@ -345,29 +454,40 @@ def test_flagship_one_chip_step_takes_no_option(topo, kernel_on,
     assert payload.sub("KERNEL", planned) == payload.sub("KERNEL", plain)
 
 
-def test_nemotron_h_cut_step_compiles_and_fits_one_v5e(topo, kernel_on,
-                                                       monkeypatch):
+def test_nemotron_h_cut_step_compiles_and_fits_one_v5e(
+    nemotron_h_cut_one_chip
+):
     """The WHOLE training step of the Nemotron-H cut (667 M parameters at
     16 bytes each, minibatch 2 x S 8192, as `edl train` runs
     `nemotron_h_twotower_cut`) for one described chip: the flash kernels
     at S 8192 under 32 broadcast heads, the chunked scan, the dynamic
     loop of the grouped expert product; it fits 16 GB with the remat the
     model-def states, and hands its statistics back beside the loss."""
-    from elasticdl_tpu.models.nemotron_h import nemotron_h_twotower_cut as m
-
-    trainer, step, abstract, _ = _plan_step(m, 2, 8192, topo, monkeypatch)
-    try:
-        lowered = step.lower(*abstract)
-        out = jax.tree_util.tree_structure(lowered.out_info)
-        compiled = lowered.compile()
-    finally:
-        trainer.close()
+    step = nemotron_h_cut_one_chip
     # The third output is {"loss", "stats"}: 1 + 4 scalars.
-    assert out.children()[2].num_leaves == 5
+    assert step.out_tree.children()[2].num_leaves == 5
     # One attention layer: flash_fwd (and its rematerialised twin) and
     # flash_bwd.
-    assert 2 <= compiled.as_text().count("tpu_custom_call") <= 3
-    resident = _resident_bytes(compiled)
-    assert resident < HBM_BYTES, f"{resident / 2**30:.2f} GiB"
+    assert 2 <= step.text.count("tpu_custom_call") <= 3
+    assert step.resident < HBM_BYTES, f"{step.resident / 2**30:.2f} GiB"
     # params + Adam m and v
-    assert compiled.memory_analysis().argument_size_in_bytes > 7.9e9
+    assert step.argument_bytes > 7.9e9
+
+
+def test_nemotron_h_cut_step_compiles_its_update_apart(
+    nemotron_h_cut_one_chip
+):
+    """The same family is the hybrid cell's first too (the mixers'
+    `in_proj` `f32[2688,10304] x3`, the head's `f32[2688,16384] x3`): with
+    a barrier a gradient leaf no weight-gradient product is compiled with
+    Adam inside it. Per-leaf barriers do not make the gradients live
+    together: the step still fits (the test above)."""
+    step = nemotron_h_cut_one_chip
+    # One a gradient leaf, beside the one `jax.checkpoint` gives each of
+    # the nine rematerialised blocks.
+    assert (
+        step.lowered.count("optimization_barrier") == step.n_grad_leaves + 9
+    )
+    fusions = _update_fusions(step)
+    assert "kOutput" not in fusions, fusions["kOutput"]
+    assert {"f32[2688,10304]", "f32[2688,16384]"} <= set(fusions["kLoop"])
