@@ -121,13 +121,18 @@ class MultiHeadAttention(nn.Module):
         q = jnp.swapaxes(q, 1, 2)
         k = jnp.swapaxes(k, 1, 2)
         v = jnp.swapaxes(v, 1, 2)
-        attend = cfg.attention or _default_attention
-        # Softmax path in float32 for stability; back to compute dtype.
-        out = attend(
-            q.astype(jnp.float32),
-            k.astype(jnp.float32),
-            v.astype(jnp.float32),
-        ).astype(dtype)
+        if cfg.attention is None:
+            # q, k, v cross the kernels' boundary in the activation dtype
+            # and o, dq, dk, dv come back in it: flash_attention runs its
+            # scores and softmax in float32 itself, tile by tile.
+            out = _default_attention(q, k, v)
+        else:
+            # The context-parallel callables are handed float32.
+            out = cfg.attention(
+                q.astype(jnp.float32),
+                k.astype(jnp.float32),
+                v.astype(jnp.float32),
+            ).astype(dtype)
         out = jnp.swapaxes(out, 1, 2).reshape(*x.shape[:2], cfg.d_model)
         return nn.Dense(cfg.d_model, dtype=dtype, name="proj")(out)
 

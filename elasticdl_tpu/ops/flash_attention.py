@@ -471,7 +471,7 @@ def flash_attention(
                 q, k, v, causal, bq, bk, emit_lse=False
             )[0]
         )(q, k, v)
-    return reference_attention(q, k, v, causal)
+    return _fallback_attention(q, k, v, causal)
 
 
 def _fit_block(s, requested):
@@ -514,7 +514,7 @@ def _fwd(q, k, v, causal, block_q, block_k):
             )
         )(q, k, v)
         return out, (q, k, v, out, lse)
-    out = reference_attention(q, k, v, causal)
+    out = _fallback_attention(q, k, v, causal)
     return out, (q, k, v, out, None)
 
 
@@ -528,10 +528,25 @@ def _bwd(causal, block_q, block_k, residuals, g):
     return _bwd_xla(q, k, v, out, g, causal)
 
 
+def _fallback_attention(q, k, v, causal):
+    """`reference_attention` as the kernel keeps its promise: whatever
+    dtype crosses the boundary, scores and softmax run in float32 and the
+    result is rounded to the operands' dtype once. (Kept below the kernels:
+    jax 0.9.0 keys a compiled Mosaic call on the line numbers of its call
+    stack.)"""
+    out = reference_attention(
+        *(x.astype(jnp.float32) for x in (q, k, v)), causal
+    )
+    return out.astype(q.dtype)
+
+
 def _bwd_xla(q, k, v, out, g, causal):
     """Full-matrix XLA backward (backends without the kernel): scores recomputed,
     then dV = P^T g;  dP = g V^T;  dS = P * (dP - rowsum(g * out));
-    dQ = dS K * scale;  dK = dS^T Q * scale."""
+    dQ = dS K * scale;  dK = dS^T Q * scale. In float32 whatever the
+    operands' dtype, each gradient rounded to its operand's once."""
+    dtypes = q.dtype, k.dtype, v.dtype
+    q, k, v, out, g = (x.astype(jnp.float32) for x in (q, k, v, out, g))
     scale = q.shape[-1] ** -0.5
     scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
     if causal:
@@ -546,7 +561,7 @@ def _bwd_xla(q, k, v, out, g, causal):
     ds = p * (dp - delta)
     dq = jnp.einsum("bhqk,bhkd->bhqd", ds, k) * scale
     dk = jnp.einsum("bhqk,bhqd->bhkd", ds, q) * scale
-    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+    return tuple(d.astype(t) for d, t in zip((dq, dk, dv), dtypes))
 
 
 flash_attention.defvjp(_fwd, _bwd)

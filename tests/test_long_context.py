@@ -129,6 +129,10 @@ def test_flash_attention_kernel_interpret(qkv, monkeypatch):
         )
 
 
+BF16_EPS = float(jnp.finfo(jnp.bfloat16).eps)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
 @pytest.mark.parametrize(
     "s,block_q,block_k",
@@ -140,30 +144,97 @@ def test_flash_attention_kernel_interpret(qkv, monkeypatch):
         (512, 256, 128),
     ],
 )
-def test_flash_attention_gradients(monkeypatch, causal, s, block_q, block_k):
+def test_flash_attention_gradients(
+    monkeypatch, causal, s, block_q, block_k, dtype
+):
     """The one backward kernel (interpret mode) against the gradients of
     full attention: dq is summed across the outer grid axis and, causal,
-    across the first_i clamp; float32 sums earn a float32 tolerance."""
+    across the first_i clamp; float32 sums earn a float32 tolerance. With
+    bfloat16 operands (what the flagship hands the kernels) the reference
+    is float32 on the same bfloat16 values: the kernel's tiles are float32
+    in VMEM either way, so what parts the two is o, dO and each gradient
+    rounded to bfloat16 once, and `delta` read from the rounded o."""
     monkeypatch.setenv("EDL_FORCE_PALLAS_INTERPRET", "1")
     rng = np.random.default_rng(s + block_q)
     q, k, v = (
-        jnp.asarray(rng.normal(size=(1, 2, s, 32)), jnp.float32)
+        jnp.asarray(rng.normal(size=(1, 2, s, 32)), dtype)
         for _ in range(3)
     )
 
     def loss_flash(q, k, v):
         out = flash_attention(q, k, v, causal, block_q, block_k)
-        return jnp.sum(out ** 2)
+        assert out.dtype == q.dtype
+        return jnp.sum(out.astype(jnp.float32) ** 2)
 
     def loss_ref(q, k, v):
         return jnp.sum(reference_attention(q, k, v, causal=causal) ** 2)
 
     gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-    gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    gr = jax.grad(loss_ref, argnums=(0, 1, 2))(
+        *(x.astype(jnp.float32) for x in (q, k, v))
+    )
     for a, b in zip(gf, gr):
-        np.testing.assert_allclose(
-            np.asarray(a), np.asarray(b), atol=1e-4, rtol=1e-4
-        )
+        assert a.dtype == jnp.dtype(dtype)
+        a, b = np.asarray(a.astype(jnp.float32)), np.asarray(b)
+        if dtype == "float32":
+            np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)
+        else:
+            # The float32 tolerance times the epsilons' ratio (6.5) would
+            # bound nothing. The roundings are half a bfloat16 epsilon
+            # each, so a whole gradient stays within one epsilon of its
+            # largest element (0.2 to 0.45 of that measured).
+            assert np.abs(a - b).max() <= BF16_EPS * np.abs(b).max()
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+def test_flash_kernel_bfloat16_output_is_one_rounding(qkv, monkeypatch, causal):
+    """bfloat16 across the kernel's boundary (interpret mode): the output
+    is the float32 reference on the same bfloat16 values, rounded to
+    bfloat16 once by the kernel's own write: within one unit in the last
+    place of the reference so rounded."""
+    monkeypatch.setenv("EDL_FORCE_PALLAS_INTERPRET", "1")
+    q, k, v = (x.astype(jnp.bfloat16) for x in qkv)
+    out = flash_attention(q, k, v, causal, 128, 128)
+    assert out.dtype == jnp.bfloat16
+    ref = reference_attention(
+        *(x.astype(jnp.float32) for x in (q, k, v)), causal=causal
+    )
+    out, ref = np.asarray(out.astype(jnp.float32)), np.asarray(ref)
+    assert (np.abs(out - ref) <= BF16_EPS * np.abs(ref) + 1e-6).all()
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+def test_fallback_runs_its_softmax_in_float32(qkv, causal):
+    """Off the chip `flash_attention` is full attention in XLA. Scores and
+    softmax in float32 is the op's promise on every backend, not the
+    caller's: with bfloat16 operands the fallback is the float32 fallback
+    on the same values rounded once, forward bit for bit, and the
+    gradients those of float32 given the same rounded o and dO."""
+    q, k, v = (x.astype(jnp.bfloat16) for x in qkv)
+    q32, k32, v32 = (x.astype(jnp.float32) for x in (q, k, v))
+    out, vjp = jax.vjp(lambda *a: flash_attention(*a, causal), q, k, v)
+    out32, vjp32 = jax.vjp(
+        lambda *a: flash_attention(*a, causal), q32, k32, v32
+    )
+    assert out.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(
+        np.asarray(out.astype(jnp.float32)),
+        np.asarray(out32.astype(jnp.bfloat16).astype(jnp.float32)),
+    )
+    # Scores in bfloat16 would not have come this close.
+    plain = reference_attention(q, k, v, causal=causal)
+    assert plain.dtype == jnp.bfloat16
+    assert not np.array_equal(
+        np.asarray(plain.astype(jnp.float32)),
+        np.asarray(out.astype(jnp.float32)),
+    )
+    g = jnp.asarray(
+        np.random.default_rng(1).normal(size=out.shape), jnp.bfloat16
+    )
+    for a, b in zip(vjp(g), vjp32(g.astype(jnp.float32))):
+        assert a.dtype == jnp.bfloat16
+        a, b = np.asarray(a.astype(jnp.float32)), np.asarray(b)
+        assert np.abs(a - b).max() <= BF16_EPS * np.abs(b).max()
 
 
 def test_block_fitting_keeps_pallas_for_512_multiples():

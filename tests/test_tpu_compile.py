@@ -37,6 +37,15 @@ FLAGSHIP_SHAPES = [
 ]
 
 
+_HLO_OP = re.compile(r"\s*(?:ROOT )?%\S+ = (.*?) ([a-z][a-z0-9\-]*)\(")
+_HLO_ARRAY = re.compile(r"(bf16|f32)\[([0-9,]*)\]")
+_THREE_OF_ONE_F32 = re.compile(r"\((f32\[[0-9,]+\]), \1, \1\)")
+
+
+def _without_layouts(hlo_shape):
+    return re.sub(r"\{[^{}]*\}", "", hlo_shape)
+
+
 @pytest.fixture(scope="module")
 def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -68,37 +77,68 @@ def kernel_on(monkeypatch):
     _steer_to_the_kernel(monkeypatch)
 
 
-def _qkv(shape, sharding):
-    return [
-        jax.ShapeDtypeStruct(shape, jnp.float32, sharding=sharding)
-    ] * 3
+def _qkv(shape, sharding, dtype=jnp.float32):
+    return [jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)] * 3
 
 
+# What crosses the kernels' boundary: float32 (the hybrid's call site and
+# the context-parallel wrappers) or the activation dtype (the flagship).
+OPERAND_DTYPES = ["float32", "bfloat16"]
+
+
+_OPERAND_LAYOUTS = re.compile(
+    r"operand_layout_constraints=\{((?:[^{}]|\{[^{}]*\})*)\}"
+)
+
+
+def _kernel_calls(hlo_text):
+    """[(result shapes, [operand shapes])] of the Mosaic calls in a
+    compiled text, without layouts."""
+    calls = []
+    for line in hlo_text.split("\n"):
+        m = _HLO_OP.match(line)
+        if m and m.group(2) == "custom-call" and "tpu_custom_call" in line:
+            operands = _OPERAND_LAYOUTS.search(line).group(1)
+            calls.append((
+                _without_layouts(m.group(1)),
+                [f"{t}[{dims}]" for t, dims in _HLO_ARRAY.findall(operands)],
+            ))
+    return calls
+
+
+@pytest.mark.parametrize("dtype", OPERAND_DTYPES)
 @pytest.mark.parametrize("shape", FLAGSHIP_SHAPES, ids=str)
-def test_flash_forward_compiles_for_v5e(one_chip, kernel_on, shape):
+def test_flash_forward_compiles_for_v5e(one_chip, kernel_on, shape, dtype):
     compiled = (
         jax.jit(lambda q, k, v: fa.flash_attention(q, k, v, True))
-        .lower(*_qkv(shape, one_chip))
+        .lower(*_qkv(shape, one_chip, dtype))
         .compile()
     )
     assert compiled.as_text().count("tpu_custom_call") == 1
 
 
+@pytest.mark.parametrize("dtype", OPERAND_DTYPES)
 @pytest.mark.parametrize("shape", FLAGSHIP_SHAPES, ids=str)
 def test_flash_forward_backward_compiles_for_v5e(
-    one_chip, kernel_on, shape
+    one_chip, kernel_on, shape, dtype
 ):
     def loss(q, k, v):
         return jnp.sum(fa.flash_attention(q, k, v, True))
 
     compiled = (
         jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
-        .lower(*_qkv(shape, one_chip))
+        .lower(*_qkv(shape, one_chip, dtype))
         .compile()
     )
     # forward (with lse) and the one backward kernel; S 8192 is the case
     # that asks the most VMEM (dq's float32 row is 4 MiB there).
-    assert compiled.as_text().count("tpu_custom_call") == 2
+    b, h, s, d = shape
+    hlo = {"float32": "f32", "bfloat16": "bf16"}[dtype]
+    block = f"{hlo}[{b * h},{s},{d}]"
+    lse = f"f32[{b * h},{s},{fa.LANES}]"
+    assert sorted(r for r, _ in _kernel_calls(compiled.as_text())) == sorted(
+        [f"({block}, {lse})", f"({block}, {block}, {block})"]
+    )
 
 
 def test_flash_kernel_partitions_over_a_data_mesh(topo, kernel_on):
@@ -288,13 +328,50 @@ def test_flagship_step_compiles_and_fits_one_v5e(flagship_one_chip):
     assert resident < HBM_BYTES, f"{resident / 2**30:.2f} GiB"
 
 
-_HLO_OP = re.compile(r"\s*(?:ROOT )?%\S+ = (.*?) ([a-z][a-z0-9\-]*)\(")
-_HLO_ARRAY = re.compile(r"(bf16|f32)\[([0-9,]*)\]")
-_THREE_OF_ONE_F32 = re.compile(r"\((f32\[[0-9,]+\]), \1, \1\)")
+@pytest.mark.parametrize(
+    "fixture,resident_with_f32_operands",
+    # Resident bytes a chip at the parent of PR 36, which handed the
+    # kernels float32 copies of q, k, v and kept them and o as residuals.
+    [("flagship_one_chip", 12_017_048_576), ("flagship_dp4", 11_908_217_344)],
+)
+def test_flagship_step_hands_its_kernels_the_activation_dtype(
+    request, fixture, resident_with_f32_operands
+):
+    """q, k, v cross the flash kernels' boundary as the bfloat16 the
+    configuration states and o, dq, dk, dv come back as it, on one chip
+    and inside dp4's per-shard `shard_map` alike: the float32 the softmax
+    needs is made tile by tile in VMEM. What that takes off the step: the
+    float32 residuals of 12 layers (1.6 GB) and, between the kernels,
+    every `convert` that rounded a kernel's result."""
+    step = request.getfixturevalue(fixture)
+    block, lse = "bf16[32,4096,128]", "f32[32,4096,128]"
+    # flash_fwd: q, k, v -> o, lse; flash_bwd: q, k, v, dO and the
+    # lane-replicated lse and delta -> dq, dk, dv.
+    assert sorted(_kernel_calls(step.text)) == sorted(
+        12 * [(f"({block}, {lse})", 3 * [block])]
+        + 12 * [(f"({block}, {block}, {block})", 4 * [block] + 2 * [lse])]
+    )
+    entry = step.text[step.text.index("\nENTRY"):]
+    rounded = [
+        line for line in entry.split("\n")
+        if (m := _HLO_OP.match(line)) and m.group(2) == "convert"
+        and _without_layouts(m.group(1)) == block
+    ]
+    assert not rounded, rounded[:3]
+    assert step.resident <= resident_with_f32_operands - 1.4e9, step.resident
 
 
-def _without_layouts(hlo_shape):
-    return re.sub(r"\{[^{}]*\}", "", hlo_shape)
+def test_nemotron_h_cut_step_still_hands_its_kernels_float32(
+    nemotron_h_cut_one_chip,
+):
+    """The hybrid's call site (`models/nemotron_h/nemotron_h.py`) keeps
+    its upcasts: that program is the parent's, so its cell's thin loss
+    limits are not played by a changed rounding."""
+    calls = _kernel_calls(nemotron_h_cut_one_chip.text)
+    assert calls
+    for results, operands in calls:
+        assert results.startswith("(f32[64,8192,128], "), results
+        assert set(operands) == {"f32[64,8192,128]"}, operands
 
 
 def _update_fusions(step):
