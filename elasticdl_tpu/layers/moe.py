@@ -133,16 +133,17 @@ GROUPED_SCOPE = "moe_grouped"
 SHARED_SCOPE = "moe_shared"
 
 
-def route_top_k(scores, correction_bias, k, norm_topk_prob, scaling_factor):
+def route_top_k(scores, correction_bias, k, norm_topk_prob, scaling_factor,
+                eps=1e-20):
     """scores [T, E] float32 (after the sigmoid) -> (experts [T, k] int32,
     weights [T, k] float32). The k experts are the largest of scores +
     correction_bias; the weights are the scores themselves at the chosen
-    (without the bias), divided by their sum if `norm_topk_prob`, times
-    `scaling_factor`."""
+    (without the bias), divided by their sum (plus `eps`: HF `lfm2_moe`
+    has 1e-6 there) if `norm_topk_prob`, times `scaling_factor`."""
     _, experts = jax.lax.top_k(scores + correction_bias, k)
     weights = jnp.take_along_axis(scores, experts, axis=-1)
     if norm_topk_prob:
-        weights = weights / (jnp.sum(weights, -1, keepdims=True) + 1e-20)
+        weights = weights / (jnp.sum(weights, -1, keepdims=True) + eps)
     return experts.astype(jnp.int32), weights * scaling_factor
 
 
@@ -279,11 +280,113 @@ def _grouped_bwd(block, k, residuals, dy):
 grouped_relu2_experts.defvjp(_grouped_fwd, _grouped_bwd)
 
 
+# ---------- the gated (SwiGLU) expert form, beside the relu^2 one ----------
+#
+# Its own loops: the relu^2 path above is compiled as it was. What the two
+# share is what decides no arithmetic: the plan and `_block_rows`.
+
+
+def _swiglu_expert(rows, w_gate_up, w_down):
+    """down(silu(gate(rows)) * up(rows)); `w_gate_up` [D, 2F] holds the
+    gate's columns first, so both take one product."""
+    f32 = jnp.float32
+    gu = jnp.dot(rows, w_gate_up, preferred_element_type=f32)
+    g, u = jnp.split(gu, 2, axis=-1)
+    h = (jax.nn.silu(g) * u).astype(rows.dtype)
+    return g, u, h, jnp.dot(h, w_down, preferred_element_type=f32)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def grouped_swiglu_experts(x, weights, w_gate_up, w_down, plan, block, k):
+    """sum over the held assignments (t, e) of weights[t, e] *
+    down_e(silu(gate_e(x_t)) * up_e(x_t)), as [T, D] float32.
+
+    As `grouped_relu2_experts`, with w_gate_up [count, D, 2F] (gate
+    columns, then up) and w_down [count, F, D]: two products a block
+    forward, six backward."""
+    return _swiglu_forward(x, weights, w_gate_up, w_down, plan, block, k)
+
+
+def _swiglu_forward(x, weights, w_gate_up, w_down, plan, block, k):
+    dtype = x.dtype
+    gate_up, down = w_gate_up.astype(dtype), w_down.astype(dtype)
+    flat_w = weights.reshape(-1)
+
+    def body(i, y):
+        e, _, ids, tokens, real = _block_rows(plan, i, block, k)
+        gate = jnp.where(real, flat_w[ids], 0.0)
+        *_, out = _swiglu_expert(x[tokens], gate_up[e], down[e])
+        return y.at[tokens].add(gate[:, None] * out)
+
+    with jax.named_scope(GROUPED_SCOPE):
+        return jax.lax.fori_loop(
+            0, plan["n_blocks"], body, jnp.zeros(x.shape, jnp.float32))
+
+
+def _swiglu_fwd(x, weights, w_gate_up, w_down, plan, block, k):
+    y = _swiglu_forward(x, weights, w_gate_up, w_down, plan, block, k)
+    return y, (x, weights, w_gate_up, w_down, plan)
+
+
+def _swiglu_bwd(block, k, residuals, dy):
+    x, weights, w_gate_up, w_down, plan = residuals
+    dtype, f32 = x.dtype, jnp.float32
+    gate_up, down = w_gate_up.astype(dtype), w_down.astype(dtype)
+    flat_w = weights.reshape(-1)
+    dy = dy.astype(dtype)
+
+    def body(i, carry):
+        dx, d_gate_up, d_down, d_sorted = carry
+        e, start, ids, tokens, real = _block_rows(plan, i, block, k)
+        gate = jnp.where(real, flat_w[ids], 0.0)
+        rows, g_out = x[tokens], dy[tokens].astype(f32)
+        g, u, h, out = _swiglu_expert(rows, gate_up[e], down[e])
+        d_weight = jnp.sum(out * g_out, axis=-1)
+        d_out = (gate[:, None] * g_out).astype(dtype)
+        d_h = jnp.dot(d_out, down[e].T, preferred_element_type=f32)
+        sig = jax.nn.sigmoid(g)
+        silu = g * sig
+        d_gu = jnp.concatenate(
+            [d_h * u * (sig + silu * (1.0 - sig)), d_h * silu],
+            axis=-1).astype(dtype)
+        d_rows = jnp.dot(d_gu, gate_up[e].T, preferred_element_type=f32)
+        d_down = d_down.at[e].add(
+            jnp.dot(h.T, d_out, preferred_element_type=f32))
+        d_gate_up = d_gate_up.at[e].add(
+            jnp.dot(rows.T, d_gu, preferred_element_type=f32))
+        # A block's tail belongs to the next expert's run: keep what is
+        # there.
+        was = jax.lax.dynamic_slice(d_sorted, (start,), (block,))
+        d_sorted = jax.lax.dynamic_update_slice(
+            d_sorted, jnp.where(real, d_weight, was), (start,))
+        return dx.at[tokens].add(d_rows), d_gate_up, d_down, d_sorted
+
+    with jax.named_scope(GROUPED_SCOPE):
+        dx, d_gate_up, d_down, d_sorted = jax.lax.fori_loop(
+            0, plan["n_blocks"], body,
+            (jnp.zeros(x.shape, f32), jnp.zeros(w_gate_up.shape, f32),
+             jnp.zeros(w_down.shape, f32),
+             jnp.zeros(plan["order"].shape, f32)))
+        # Back from sorted order to [T, k].
+        n = flat_w.shape[0]
+        _, d_flat = jax.lax.sort(
+            (plan["order"][:n], d_sorted[:n]), num_keys=1)
+    return (dx.astype(dtype), d_flat.reshape(weights.shape),
+            d_gate_up.astype(w_gate_up.dtype), d_down.astype(w_down.dtype),
+            None)
+
+
+grouped_swiglu_experts.defvjp(_swiglu_fwd, _swiglu_bwd)
+
+
 class RoutedExperts(nn.Module):
     """Top-k of `num_experts` by sigmoid scores with a correction bias,
     experts `down(relu(up(x))^2)` without a gate, and a shared expert of the
     same form for every token (DeepSeek-V3's routing as the HF `nemotron_h`
     model uses it). No auxiliary loss, no capacity: nothing is dropped.
+    `gated` makes the experts `down(silu(gate(x)) * up(x))` (HF `lfm2_moe`;
+    parameters `w_gate_up`, `w_down`; no shared expert in that form), and
+    the layer then also counts the rows its loops multiplied.
 
     The layer is told which experts it holds, `held = (first, count)`: it
     routes over all `num_experts`, computes its own experts' part of the
@@ -301,6 +404,9 @@ class RoutedExperts(nn.Module):
     norm_topk_prob: bool = True
     routed_scaling_factor: float = 1.0
     block_rows: int = 1024
+    gated: bool = False
+    # Under the sum that normalises the chosen scores.
+    topk_eps: float = 1e-20
     # Not None: the router's logits are replaced, in the forward pass, by
     # uniform noise drawn from this seed for each (row, position, expert),
     # the same at every step; the gradient goes straight through to the
@@ -341,14 +447,19 @@ class RoutedExperts(nn.Module):
             scores = jax.nn.sigmoid(logits)
             experts, weights = route_top_k(
                 scores, jax.lax.stop_gradient(bias.value), k,
-                self.norm_topk_prob, self.routed_scaling_factor)
+                self.norm_topk_prob, self.routed_scaling_factor,
+                self.topk_eps)
             plan = plan_held_blocks(experts, first, count, self.block_rows)
-        w_up = self.param(
-            "w_up", self.kernel_init, (count, d, self.d_hidden), f32)
+        if self.gated and self.d_shared:
+            raise ValueError("the gated form has no shared expert")
+        name, grouped, width = (
+            ("w_gate_up", grouped_swiglu_experts, 2 * self.d_hidden)
+            if self.gated else
+            ("w_up", grouped_relu2_experts, self.d_hidden))
+        w_up = self.param(name, self.kernel_init, (count, d, width), f32)
         w_down = self.param(
             "w_down", self.kernel_init, (count, self.d_hidden, d), f32)
-        y = grouped_relu2_experts(
-            tokens, weights, w_up, w_down, plan, self.block_rows, k)
+        y = grouped(tokens, weights, w_up, w_down, plan, self.block_rows, k)
         if self.d_shared:
             with jax.named_scope(SHARED_SCOPE):
                 h = nn.Dense(
@@ -366,4 +477,10 @@ class RoutedExperts(nn.Module):
             "moe_held_load_max": jnp.max(counts),
             "moe_held_load_mean": jnp.mean(counts),
         }
+        if self.gated:
+            # Rows the grouped loops multiplied, and those of them that
+            # were assignments: the blocks' fill.
+            stats["moe_block_rows_run"] = (
+                plan["n_blocks"] * self.block_rows).astype(f32)
+            stats["moe_block_rows_real"] = stats["moe_assignments_held"]
         return y.reshape(b, s, d).astype(x.dtype), stats

@@ -318,6 +318,13 @@ def nemotron_h_cut_one_chip(topo, no_persistent_compile_cache_in_module):
     return _compile_planned(lambda mp: _plan_step(m, 2, 8192, topo, mp))
 
 
+@pytest.fixture(scope="module")
+def lfm2_cut_one_chip(topo, no_persistent_compile_cache_in_module):
+    from elasticdl_tpu.models.lfm2 import lfm2_24b_a2b_cut as m
+
+    return _compile_planned(lambda mp: _plan_step(m, 2, 8192, topo, mp))
+
+
 def test_flagship_step_compiles_and_fits_one_v5e(flagship_one_chip):
     """The WHOLE flagship training step of AllReduceTrainer — the program
     `edl train` runs at `flagship_config()` widths, minibatch 4 — for one
@@ -568,3 +575,26 @@ def test_nemotron_h_cut_step_compiles_its_update_apart(
     fusions = _update_fusions(step)
     assert "kOutput" not in fusions, fusions["kOutput"]
     assert {"f32[2688,10304]", "f32[2688,16384]"} <= set(fusions["kLoop"])
+
+
+
+def test_lfm2_cut_step_compiles_and_fits_one_v5e(lfm2_cut_one_chip):
+    """The WHOLE training step of the LFM2-24B-A2B cut (648 M parameters at
+    16 bytes each, minibatch 2 x S 8192, as `edl train` runs
+    `lfm2_24b_a2b_cut`) for one described chip: the flash kernels at head
+    64, half a lane row, handed the activation dtype; the dynamic loops of
+    the gated grouped product; it fits 16 GB with the remat the model-def
+    states, and hands six counters back beside the loss."""
+    step = lfm2_cut_one_chip
+    assert step.out_tree.children()[2].num_leaves == 7
+    # Two attention layers: flash_fwd, its rematerialised twin, flash_bwd.
+    calls = _kernel_calls(step.text)
+    assert 4 <= len(calls) <= 6
+    for results, operands in calls:
+        assert results.startswith("(bf16[64,8192,64], "), results
+        assert set(operands) <= {"bf16[64,8192,64]", "f32[64,8192,128]"}
+    assert step.resident < HBM_BYTES, f"{step.resident / 2**30:.2f} GiB"
+    # params + Adam m and v
+    assert step.argument_bytes > 7.7e9
+    assert {"f32[8,2048,3072]", "f32[8,1536,2048]", "f32[2048,6144]",
+            "f32[8192,2048]"} <= step.weights
