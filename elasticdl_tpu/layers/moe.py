@@ -284,6 +284,32 @@ grouped_relu2_experts.defvjp(_grouped_fwd, _grouped_bwd)
 #
 # Its own loops: the relu^2 path above is compiled as it was. What the two
 # share is what decides no arithmetic: the plan and `_block_rows`.
+#
+# A block's rows go back to token order by a scatter-add into the loop's
+# carry. On the chip a row of a [T, D] float32 array is one sublane of D /
+# 128 tiles of (8, 128), each shared with seven other tokens, and a scatter
+# of rows moves an eighth of what it touches. The gated loops carry y and dx
+# as [T, D / 128, 128] instead: a token's row is whole tiles of its own
+# (0.094 ms a block of 1152 rows of 2048 against 0.283). The same rows, the
+# same float32 sums in the same order; the padded rows still add their
+# zeros to token 0, and the scatter keeps no attribute: told that a block's
+# rows are sorted and unique, the compiler scatters into [T, D] three times
+# slower and into slabs no faster.
+
+_LANES = 128
+
+
+def _row_slabs(a):
+    """[n, D] as [n, D / 128, 128], where D is whole lanes."""
+    n, d = a.shape
+    return a.reshape(n, d // _LANES, _LANES) if d % _LANES == 0 else a
+
+
+def _token_rows(a):
+    """The forward's carry back as [T, D], an array of its own: left to
+    fuse the change of layout into what reads y, the compiler keeps one
+    [T, D] float32 more at the step's peak."""
+    return jax.lax.optimization_barrier(a.reshape(a.shape[0], -1))
 
 
 def _swiglu_expert(rows, w_gate_up, w_down):
@@ -316,11 +342,12 @@ def _swiglu_forward(x, weights, w_gate_up, w_down, plan, block, k):
         e, _, ids, tokens, real = _block_rows(plan, i, block, k)
         gate = jnp.where(real, flat_w[ids], 0.0)
         *_, out = _swiglu_expert(x[tokens], gate_up[e], down[e])
-        return y.at[tokens].add(gate[:, None] * out)
+        return y.at[tokens].add(_row_slabs(gate[:, None] * out))
 
     with jax.named_scope(GROUPED_SCOPE):
-        return jax.lax.fori_loop(
-            0, plan["n_blocks"], body, jnp.zeros(x.shape, jnp.float32))
+        return _token_rows(jax.lax.fori_loop(
+            0, plan["n_blocks"], body,
+            _row_slabs(jnp.zeros(x.shape, jnp.float32))))
 
 
 def _swiglu_fwd(x, weights, w_gate_up, w_down, plan, block, k):
@@ -359,19 +386,21 @@ def _swiglu_bwd(block, k, residuals, dy):
         was = jax.lax.dynamic_slice(d_sorted, (start,), (block,))
         d_sorted = jax.lax.dynamic_update_slice(
             d_sorted, jnp.where(real, d_weight, was), (start,))
-        return dx.at[tokens].add(d_rows), d_gate_up, d_down, d_sorted
+        return (dx.at[tokens].add(_row_slabs(d_rows)), d_gate_up, d_down,
+                d_sorted)
 
     with jax.named_scope(GROUPED_SCOPE):
         dx, d_gate_up, d_down, d_sorted = jax.lax.fori_loop(
             0, plan["n_blocks"], body,
-            (jnp.zeros(x.shape, f32), jnp.zeros(w_gate_up.shape, f32),
-             jnp.zeros(w_down.shape, f32),
+            (_row_slabs(jnp.zeros(x.shape, f32)),
+             jnp.zeros(w_gate_up.shape, f32), jnp.zeros(w_down.shape, f32),
              jnp.zeros(plan["order"].shape, f32)))
         # Back from sorted order to [T, k].
         n = flat_w.shape[0]
         _, d_flat = jax.lax.sort(
             (plan["order"][:n], d_sorted[:n]), num_keys=1)
-    return (dx.astype(dtype), d_flat.reshape(weights.shape),
+    return (dx.reshape(x.shape).astype(dtype),
+            d_flat.reshape(weights.shape),
             d_gate_up.astype(w_gate_up.dtype), d_down.astype(w_down.dtype),
             None)
 

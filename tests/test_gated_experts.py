@@ -2,7 +2,9 @@
 tests/test_routed_experts.py's manner: the grouped product against a plain
 loop, its hand-written backward against autodiff of a dense formulation
 under uneven load, an expert with no assignment, a block that straddles two
-experts, the shares test, and the epsilon under the normalising sum."""
+experts, the shares test, and the epsilon under the normalising sum; and
+the same at a width of whole lanes, where the loops carry y and dx as
+[T, D / 128, 128]."""
 
 import jax
 import jax.numpy as jnp
@@ -38,7 +40,7 @@ def share_of(variables, first, count):
 def plain_layer(params, x, first, count, logits=None):
     """The equations, token by token and expert by expert, w1 and w3 apart;
     `logits` [T, E] stand in for the router's where given."""
-    tokens = np.asarray(x, np.float64).reshape(-1, D)
+    tokens = np.asarray(x, np.float64).reshape(-1, x.shape[-1])
     router = np.asarray(params["router"], np.float64)
     out = np.zeros_like(tokens)
     for t, row in enumerate(tokens):
@@ -82,6 +84,26 @@ def test_gated_layer_matches_the_plain_loop(held, block):
     assert run % block == 0 and real <= run < real + count * block
 
 
+def dense_experts(params, tokens, scores, first, count):
+    """The held experts' part of the layer as dense products over every
+    token, for autodiff to take apart."""
+    chosen, w = moe.route_top_k(scores, jnp.zeros(E), K, True, 1.0, EPS)
+    gates = jnp.sum(jax.nn.one_hot(chosen, E) * w[..., None], axis=1)
+    out = jnp.zeros_like(tokens)
+    for e in range(count):
+        w1, w3 = jnp.split(params["w_gate_up"][e], 2, axis=-1)
+        h = jax.nn.silu(tokens @ w1) * (tokens @ w3)
+        out = out + gates[:, first + e, None] * (h @ params["w_down"][e])
+    return out
+
+
+def assert_trees_close(got, want):
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(
+            g, w, rtol=2e-4, atol=2e-5 * float(jnp.max(jnp.abs(w)) + 1))
+
+
 def uneven_logits():
     """[T, E] that send 18 tokens' first choice to expert 5, their second
     to 6 or 7 by turns, and leave expert 8 without an assignment: with
@@ -117,16 +139,9 @@ def test_gated_gradients_match_autodiff_of_a_dense_formulation():
     assert int(start) + 4 > int(plan["group_start"][2])
 
     def dense(params, x, logits):
-        tokens = x.reshape(-1, D)
-        chosen, w = moe.route_top_k(
-            jax.nn.sigmoid(logits), jnp.zeros(E), K, True, 1.0, EPS)
-        gates = jnp.sum(jax.nn.one_hot(chosen, E) * w[..., None], axis=1)
-        out = jnp.zeros_like(tokens)
-        for e in range(6):
-            w1, w3 = jnp.split(params["w_gate_up"][e], 2, axis=-1)
-            h = jax.nn.silu(tokens @ w1) * (tokens @ w3)
-            out = out + gates[:, 4 + e, None] * (h @ params["w_down"][e])
-        return out.reshape(x.shape)
+        return dense_experts(
+            params, x.reshape(-1, D), jax.nn.sigmoid(logits), 4, 6
+        ).reshape(x.shape)
 
     def grouped(params, x, logits):
         tokens = x.reshape(-1, D)
@@ -146,14 +161,108 @@ def test_gated_gradients_match_autodiff_of_a_dense_formulation():
                         argnums=(0, 1, 2))(params, x, logits)
         got = jax.grad(lambda *a: jnp.sum(grouped(*a) * weight),
                        argnums=(0, 1, 2))(params, x, logits)
-    flat_w, _ = jax.tree_util.tree_flatten(want)
-    flat_g, _ = jax.tree_util.tree_flatten(got)
-    for g, w in zip(flat_g, flat_w):
-        np.testing.assert_allclose(
-            g, w, rtol=2e-4, atol=2e-5 * float(jnp.max(jnp.abs(w)) + 1))
+    assert_trees_close(got, want)
     # The expert without an assignment takes no gradient.
     assert not np.asarray(got[0]["w_gate_up"][4]).any()
     assert np.asarray(got[0]["w_gate_up"][1]).any()
+
+
+# ---------- at a width of whole lanes: the carry in slabs of 128 ----------
+
+WIDE, T, HELD, BLOCK = 256, 18, (4, 6), 4
+# Which tokens each held expert (4 to 9) takes; a token's other choices
+# fall on experts 0 to 2, which this share does not hold. A padded row of a
+# block points at token 0.
+LOADS = {
+    # 5, 18, 7, 6, 3 and 1 rows: every last block has padding. Token 0 is
+    # held by experts 4, 5 and 8 and not by 6, 7 and 9.
+    "padding_in_every_last_block": {
+        4: range(0, 5), 5: range(0, 18), 6: range(5, 12),
+        7: range(12, 18), 8: range(0, 3), 9: [17]},
+    # 8 and 4 rows end on a block's end (no padded row); 6 ends inside one.
+    "runs_that_end_on_a_block_end": {
+        4: range(0, 8), 5: range(8, 12), 6: range(2, 8), 7: range(12, 16)},
+    # Experts 5 and 8 take nothing, between experts that do.
+    "experts_without_an_assignment": {
+        4: range(0, 7), 6: range(0, 18), 7: range(3, 9), 9: range(0, 2)},
+    # No held expert takes token 0: what the padded rows add there is all
+    # its row ever gets.
+    "token_0_held_by_no_expert": {
+        4: range(1, 6), 5: range(1, 18), 6: range(6, 9), 9: range(9, 12)},
+}
+
+
+def wide_case(load):
+    """(variables of the share, x [2, 9, WIDE]) in which the router reads
+    its logits off x's first E features, set so that each held expert takes
+    the tokens `load` gives it."""
+    chosen = [[e for e, ts in load.items() if t in ts] for t in range(T)]
+    assert max(len(c) for c in chosen) <= K
+    logits = np.full((T, E), -6.0) - 0.01 * np.arange(E)
+    for t, held in enumerate(chosen):
+        for rank, e in enumerate(held + [0, 1, 2][:K - len(held)]):
+            logits[t, e] = 6.0 - 2.0 * rank
+    x = np.random.default_rng(7).normal(size=(T, WIDE)).astype(np.float32)
+    x[:, :E] = logits
+    variables = layer().init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 4, WIDE)))
+    variables = share_of(
+        jax.tree_util.tree_map(lambda a: a * 20.0, variables), *HELD)
+    variables["params"]["router"] = jnp.eye(E, WIDE)
+    return variables, jnp.asarray(x.reshape(2, 9, WIDE))
+
+
+@pytest.mark.parametrize("name", sorted(LOADS))
+def test_gated_layer_in_slabs_matches_the_plain_loop(name):
+    load = LOADS[name]
+    variables, x = wide_case(load)
+    experts, _ = moe.route_top_k(
+        jax.nn.sigmoid(x.reshape(T, WIDE)[:, :E]), jnp.zeros(E), K, True,
+        1.0, EPS)
+    plan = moe.plan_held_blocks(experts, *HELD, BLOCK)
+    counts = [len(load.get(HELD[0] + e, ())) for e in range(HELD[1])]
+    assert np.asarray(plan["counts"]).tolist() == counts
+    assert int(plan["n_blocks"]) == sum(-(-c // BLOCK) for c in counts)
+    # The carry really is in slabs at this width.
+    assert moe._row_slabs(jnp.zeros((T, WIDE))).shape == (T, 2, 128)
+    with jax.default_matmul_precision("highest"):
+        y, stats = layer(HELD, BLOCK).apply(variables, x)
+    want = plain_layer(variables["params"], x, *HELD)
+    # Sums of 256 terms that cancel: the tolerance follows the largest.
+    np.testing.assert_allclose(
+        y, want, rtol=2e-4, atol=2e-5 * (np.abs(want).max() + 1))
+    assert float(stats["moe_block_rows_real"]) == sum(counts)
+    assert float(stats["moe_block_rows_run"]) == BLOCK * int(plan["n_blocks"])
+    if 0 not in {t for ts in load.values() for t in ts}:
+        assert not np.asarray(y)[0, 0].any()
+
+
+@pytest.mark.parametrize("name", sorted(LOADS))
+def test_gated_gradients_in_slabs_match_the_dense_formulation(name):
+    variables, x = wide_case(LOADS[name])
+    weight = some_tokens(3, x.shape)
+
+    def dense(params, x):
+        tokens = x.reshape(T, WIDE)
+        scores = jax.nn.sigmoid(jnp.einsum(
+            "td,ed->te", tokens, params["router"],
+            precision=jax.lax.Precision.HIGHEST))
+        return dense_experts(params, tokens, scores, *HELD).reshape(x.shape)
+
+    def via_layer(params, x):
+        return layer(HELD, BLOCK).apply(
+            {"params": params, "buffers": variables["buffers"]}, x)[0]
+
+    with jax.default_matmul_precision("highest"):
+        want = jax.grad(lambda p, x: jnp.sum(dense(p, x) * weight),
+                        argnums=(0, 1))(variables["params"], x)
+        got = jax.grad(lambda p, x: jnp.sum(via_layer(p, x) * weight),
+                       argnums=(0, 1))(variables["params"], x)
+    assert_trees_close(got, want)
+    # An expert without an assignment takes no gradient; the others do.
+    for e in range(HELD[1]):
+        assert np.asarray(got[0]["w_gate_up"][e]).any() == bool(
+            LOADS[name].get(HELD[0] + e))
 
 
 def test_gated_layer_gradients_reach_the_router():

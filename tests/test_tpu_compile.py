@@ -91,6 +91,16 @@ _OPERAND_LAYOUTS = re.compile(
 )
 
 
+def _scatter_results(hlo_text):
+    """The result shapes of the scatters in a compiled text, without
+    layouts."""
+    return [
+        _without_layouts(m.group(1))
+        for m in map(_HLO_OP.match, hlo_text.split("\n"))
+        if m and m.group(2) == "scatter"
+    ]
+
+
 def _kernel_calls(hlo_text):
     """[(result shapes, [operand shapes])] of the Mosaic calls in a
     compiled text, without layouts."""
@@ -583,8 +593,11 @@ def test_lfm2_cut_step_compiles_and_fits_one_v5e(lfm2_cut_one_chip):
     16 bytes each, minibatch 2 x S 8192, as `edl train` runs
     `lfm2_24b_a2b_cut`) for one described chip: the flash kernels at head
     64, half a lane row, handed the activation dtype; the dynamic loops of
-    the gated grouped product; it fits 16 GB with the remat the model-def
-    states, and hands six counters back beside the loss."""
+    the gated grouped product, which put a block's rows back into a carry
+    of whole tiles a token, `[16384, 16, 128]`, and never into `[16384,
+    2048]` (a row there is one sublane of 16 tiles it shares with seven
+    other tokens); it fits 16 GB with the remat the model-def states, and
+    hands six counters back beside the loss."""
     step = lfm2_cut_one_chip
     assert step.out_tree.children()[2].num_leaves == 7
     # Two attention layers: flash_fwd, its rematerialised twin, flash_bwd.
@@ -598,3 +611,7 @@ def test_lfm2_cut_step_compiles_and_fits_one_v5e(lfm2_cut_one_chip):
     assert step.argument_bytes > 7.7e9
     assert {"f32[8,2048,3072]", "f32[8,1536,2048]", "f32[2048,6144]",
             "f32[8192,2048]"} <= step.weights
+    # Six routed layers, a loop forward and a loop backward each.
+    scatters = _scatter_results(step.text)
+    assert scatters.count("f32[16384,16,128]") == 12, scatters
+    assert "f32[16384,2048]" not in scatters
