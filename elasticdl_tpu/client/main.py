@@ -23,9 +23,14 @@ import argparse
 import os
 import shutil
 import sys
+import time
 
-from elasticdl_tpu.common import args as args_mod
-from elasticdl_tpu.common.log_utils import get_logger
+# The set-up phase `setup.client` begins here; the master this process
+# becomes closes it once its observability plane is up.
+_T_CLIENT = time.time()
+
+from elasticdl_tpu.common import args as args_mod  # noqa: E402
+from elasticdl_tpu.common.log_utils import get_logger  # noqa: E402
 
 logger = get_logger("client.main")
 
@@ -49,7 +54,7 @@ def _job_parser(name):
 def _run_master_in_process(argv):
     from elasticdl_tpu.master.main import main as master_main
 
-    return master_main(argv)
+    return master_main(argv, client_started=_T_CLIENT)
 
 
 def _submit(job_args, raw_argv):

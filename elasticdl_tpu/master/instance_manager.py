@@ -24,7 +24,7 @@ import time
 
 from elasticdl_tpu.common.constants import PodStatus
 from elasticdl_tpu.common.log_utils import get_logger
-from elasticdl_tpu.observability import emit_event
+from elasticdl_tpu.observability import emit_event, tracing
 from elasticdl_tpu.observability.metrics import default_registry
 
 logger = get_logger("master.instance_manager")
@@ -108,9 +108,15 @@ class LocalProcessInstanceManager:
         # chaos run logs with a correlatable identity.
         env = dict(os.environ)
         env["ELASTICDL_ROLE"] = f"{kind}-{instance_id}"
-        popen = subprocess.Popen(
-            argv, stdout=sys.stdout, stderr=sys.stderr, env=env
-        )
+        # The fork; with the child's first stamp (`setup.imports`) it
+        # bounds the interpreter's start.
+        with tracing.span(
+            "setup.spawn", cat=tracing.SETUP,
+            instance=f"{kind}-{instance_id}",
+        ):
+            popen = subprocess.Popen(
+                argv, stdout=sys.stdout, stderr=sys.stderr, env=env
+            )
         with self._lock:
             prev = self._instances.get((kind, instance_id))
             inst = _Instance(kind, instance_id, popen)

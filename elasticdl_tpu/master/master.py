@@ -28,6 +28,7 @@ from elasticdl_tpu.master.instance_manager import (
 from elasticdl_tpu.master.membership import MembershipManager
 from elasticdl_tpu.master.servicer import MasterServicer
 from elasticdl_tpu.master.task_dispatcher import TaskDispatcher
+from elasticdl_tpu.observability import tracing
 
 logger = get_logger("master.master")
 
@@ -74,6 +75,9 @@ _PS_RELAY_ARGS = [
 class Master:
     def __init__(self, args):
         self.args = args
+        # The set-up phase `setup.master` runs from here to the port
+        # bound in prepare().
+        self.setup_started = time.time()
         # The observability plane comes up FIRST so task creation, instance
         # launches, and every later lifecycle transition land in the event
         # log/registry. Spawned worker/PS processes find the same obs dir
@@ -90,11 +94,16 @@ class Master:
         # A fixed metrics port is the master's alone; local children must
         # bind ephemeral ports or they'd all collide on this host.
         os.environ.pop(observability.METRICS_PORT_ENV, None)
-        if args.model_zoo:
-            sys.path.insert(0, args.model_zoo)
-        self.spec = get_model_spec(args.model_def)
+        # Children of `setup.master`: the model module's imports (jax and
+        # flax among them, no backend), then the shards read off the
+        # record files and cut into tasks.
+        with tracing.span("setup.model_spec", cat=tracing.SETUP):
+            if args.model_zoo:
+                sys.path.insert(0, args.model_zoo)
+            self.spec = get_model_spec(args.model_def)
 
         # --- data shards -> task dispatcher (reference master.py:61-94) ---
+        tasks_started = time.time()
         reader_factory = self.spec.create_data_reader or create_data_reader
         training_shards = (
             reader_factory(args.training_data).create_shards()
@@ -119,6 +128,10 @@ class Master:
             num_epochs=args.num_epochs,
             shuffle=args.shuffle_shards,
             seed=args.seed,
+        )
+        tracing.record_span(
+            "setup.task_create", tasks_started,
+            time.time() - tasks_started, cat=tracing.SETUP,
         )
 
         if args.checkpoint_dir_for_init and training_shards:
@@ -520,6 +533,10 @@ class Master:
             self.servicer, rpc.MASTER_SERVICE, port=self.args.master_port
         )
         logger.info("Master serving on port %d", self.port)
+        tracing.record_span(
+            "setup.master", self.setup_started,
+            time.time() - self.setup_started, cat=tracing.SETUP,
+        )
         if self.instance_manager is not None:
             if self.args.num_ps:
                 self.instance_manager.start_parameter_servers()
@@ -637,8 +654,8 @@ class Master:
         # watchdog recovery here would yank tasks out from under a live
         # world mid-lease.
         slow = {
-            wid
-            for wid in self.task_d.doing_tasks_over_timeout()
+            wid: seen
+            for wid, seen in self.task_d.doing_tasks_over_timeout().items()
             if not is_lease_owner(wid)
         }
         deadline = (
@@ -649,15 +666,19 @@ class Master:
             for wid, ts in self.servicer.snapshot_liveness().items()
             if ts < deadline
         }
-        for worker_id in slow | silent:
+        for worker_id in set(slow) | silent:
             why = "slow" if worker_id in slow else "silent"
+            # What the slow rule saw (task, age, mean, threshold): a
+            # firing can be told from a host pause by its record alone.
+            seen = slow.get(worker_id, {})
             logger.warning(
-                "Watchdog: recovering tasks of %s worker %d",
+                "Watchdog: recovering tasks of %s worker %d%s",
                 why,
                 worker_id,
+                "".join(f" {k}={v}" for k, v in seen.items()),
             )
             observability.emit_event(
-                "task_timeout", worker=worker_id, reason=why
+                "task_timeout", worker=worker_id, reason=why, **seen
             )
             self.task_d.recover_tasks(worker_id)
             self.servicer.forget_worker(worker_id)

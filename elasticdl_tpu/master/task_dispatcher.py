@@ -1081,18 +1081,34 @@ class TaskDispatcher:
             })
 
     def doing_tasks_over_timeout(self, factor=3.0, min_samples=5):
-        """Worker ids whose in-flight task has run > factor x the rolling mean
-        completion time for its type (reference master/master.py:487-509)."""
+        """{worker id: what the rule saw} for the workers whose in-flight
+        task has run > factor x the rolling mean completion time for its
+        type (reference master/master.py:487-509). The record names the
+        task (`task_id`, `task_type`), its age, the mean with the number
+        of samples behind it and the threshold crossed, all in seconds:
+        what the watchdog logs and puts into its `task_timeout` event.
+        A worker with several such tasks is recorded with the oldest."""
         now = time.time()
         with self._lock:
-            slow_workers = set()
+            slow_workers = {}
             for tid, (wid, task, start) in self._doing.items():
                 durations = self._task_durations.get(task.type, [])
                 if len(durations) < min_samples:
                     continue
                 mean = sum(durations) / len(durations)
-                if now - start > factor * max(mean, 1e-3):
-                    slow_workers.add(wid)
+                threshold = factor * max(mean, 1e-3)
+                age = now - start
+                if age > threshold and age > slow_workers.get(
+                    wid, {"age_s": 0.0}
+                )["age_s"]:
+                    slow_workers[wid] = {
+                        "task_id": tid,
+                        "task_type": pb.TaskType.Name(task.type),
+                        "age_s": round(age, 6),
+                        "mean_s": round(mean, 6),
+                        "samples": len(durations),
+                        "threshold_s": round(threshold, 6),
+                    }
             return slow_workers
 
     def add_evaluation_complete_callback(self, cb):

@@ -134,7 +134,13 @@ def lib():
             return None
         so = _so_path()
         if not os.path.exists(so):
-            build()
+            # A set-up phase of whichever role gets here first, in a
+            # job that may never call a PS kernel (the record reader
+            # lives in the same library).
+            from elasticdl_tpu.observability import tracing
+
+            with tracing.span("setup.native_build", cat=tracing.SETUP):
+                build()
         _lib = _declare(ctypes.CDLL(so))
         logger.info("Loaded native kernels from %s", so)
     return _lib

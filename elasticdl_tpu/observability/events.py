@@ -17,6 +17,7 @@ Event kinds (docs/OBSERVABILITY.md#event-schema):
   task_create / task_timeout / task_reassign / task_failed / job_failed
   worker_removed / membership_epoch
   compile / mem_high_watermark / profile_start / profile_done / rotated
+  setup_phase / steps_done / step_stall
 """
 
 import json

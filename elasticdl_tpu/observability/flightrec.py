@@ -122,6 +122,12 @@ class FlightRecorder:
                     self._open.pop(ident, None)
             self.on_span(entry[0], entry[1], closed, "phase", None)
 
+    def recent(self, n):
+        """The newest `n` closed spans, oldest first (the step-done
+        clock reads them when the device runs dry)."""
+        with self._lock:
+            return list(self._events)[-n:]
+
     # ---------- dumping ----------
 
     def snapshot(self, reason):

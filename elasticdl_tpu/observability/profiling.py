@@ -433,10 +433,19 @@ class TrackedFunction:
         _install_listener()
         start = time.time()
         t0 = time.perf_counter()
-        with _MonitoringCapture() as cap:
+        with _MonitoringCapture() as cap, open_compile(self._name):
             out = self._jitted(*args, **kwargs)
         wall = time.perf_counter() - t0
         self._seen.add(key)
+        first_call = getattr(self, "_first_call", None)
+        if first_call:
+            # The set-up phase round a newly built program's first call:
+            # tracing, the compile or the cache load (the `compile` /
+            # `compile_cache_hit` event below is its child), the enqueue.
+            tracing.record_span(
+                first_call, start, wall, cat=tracing.SETUP,
+                args={"fn": self._name},
+            )
         size = self._observed_cache_size()
         if size is not None:
             if size == self._expected_cache:
@@ -472,23 +481,63 @@ class TrackedFunction:
         return out
 
 
+class open_compile:
+    """Context manager: lists `name` (a tracked function's, or
+    `speculative_compile`) among the compiles in flight in this process
+    meanwhile. A compile is known as a span only once it returns; the
+    step-done clock reads `open_compiles()` when the device runs dry, to
+    say which one the host was inside. Defined down here, and entered on
+    the line that was there: `TrackedFunction`'s line numbers are in
+    every traced step's Mosaic payload (the kernels' debug locations
+    hold the whole call stack), and so in the compile cache's key."""
+
+    _open = {}  # {the manager: (name, start)}
+
+    def __init__(self, name):
+        self._name = name
+
+    def __enter__(self):
+        open_compile._open[self] = (self._name, time.time())
+        return self
+
+    def __exit__(self, *exc):
+        open_compile._open.pop(self, None)
+        return False
+
+
+def open_compiles():
+    """[{"name", "age_s"}] of the compiles still running, oldest first."""
+    now = time.time()
+    return [
+        {"name": name, "age_s": round(now - start, 3)}
+        for name, start in sorted(
+            list(open_compile._open.values()), key=lambda e: e[1]
+        )
+    ]
+
+
 def tracked_jit(fn, *, name, key_argnums=None, event_fields=None,
-                **jit_kwargs):
+                first_call=None, **jit_kwargs):
     """`jax.jit` with compile accounting. `name` is the logical step
     name the metrics/events carry (stable across rebuilds); `key_argnums`
     restricts the per-call shape signature to the argument positions
     that actually vary (trainers pass the batch so the hot path never
     flattens the parameter tree); `event_fields` is a dict the `compile`
     / `compile_cache_hit` events of this function carry beside their own
-    fields (which form the builder took), at no cost to a warm call."""
+    fields (which form the builder took), at no cost to a warm call;
+    `first_call` names the set-up phase (`setup.*`) recorded round the
+    first call of each new signature or mesh."""
     import jax
 
     jitted = jax.jit(fn, **jit_kwargs)
     if not tracker_enabled():
         return jitted
-    return TrackedFunction(
+    tracked = TrackedFunction(
         jitted, name, key_argnums=key_argnums, event_fields=event_fields
     )
+    # Set from here, not through the constructor: see `open_compile`.
+    tracked._first_call = first_call
+    return tracked
 
 
 # ---------------------------------------------------------------------------

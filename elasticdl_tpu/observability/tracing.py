@@ -26,6 +26,9 @@ import zlib
 
 import grpc
 
+from elasticdl_tpu.observability import events as _events
+from elasticdl_tpu.observability.metrics import default_registry
+
 # Metadata keys must be lowercase in gRPC.
 _MD_TRACE = "edl-trace-id"
 _MD_PARENT = "edl-parent-span"
@@ -59,6 +62,33 @@ def _feed_sinks(name, start_s, dur_s, cat, args):
             sink(name, start_s, dur_s, cat, args)
         except Exception:
             pass
+
+
+# The category of a set-up phase: a span opened with `cat=SETUP` (named
+# `setup.<phase>`, docs/OBSERVABILITY.md) is also written to the event
+# log when it closes, as one `setup_phase` event, because the event log
+# is what the benchmark, `edl top` and a kill drill read. A span of any
+# other category pays one comparison for this. Some thirty a process
+# life, none on the step path.
+SETUP = "setup"
+
+_SETUP_SECONDS = default_registry().gauge(
+    "edl_setup_phase_seconds",
+    "Seconds the newest run of each set-up phase of this process took",
+    labelnames=("phase",),
+)
+
+
+def _setup_phase(name, start_s, dur_s, args):
+    _SETUP_SECONDS.labels(phase=name).set(dur_s)
+    fields = {
+        k: v for k, v in (args or {}).items()
+        if isinstance(v, (str, int, float, bool))
+    }
+    _events.emit(
+        "setup_phase", name=name, start=round(start_s, 6),
+        seconds=round(dur_s, 6), **fields,
+    )
 
 
 class TraceContext:
@@ -298,17 +328,22 @@ class span:
             )
         if _sinks:
             _feed_sinks(self.name, self.start, self.dur, self.cat, self.args)
+        if self.cat == SETUP:
+            _setup_phase(self.name, self.start, self.dur, self.args)
         return False
 
 
 def record_span(name, start_s, dur_s, cat="edl", args=None):
     """Record an already-measured span (recorder + sinks). For callers
     that time the interval themselves — e.g. the compile tracker, which
-    only knows a call was a compile once it returns."""
+    only knows a call was a compile once it returns, or a set-up phase
+    that began before this module was imported."""
     rec = _recorder
     if rec is not None:
         rec.record(name, start_s, dur_s, cat=cat, args=args)
     _feed_sinks(name, start_s, dur_s, cat, args)
+    if cat == SETUP:
+        _setup_phase(name, start_s, dur_s, args)
 
 
 def instant(name, cat="edl", **args):

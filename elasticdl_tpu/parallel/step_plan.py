@@ -440,7 +440,7 @@ def jit_step(step_fn, mesh, var_sh, opt_sh, donate, dp_overlap,
     )
     return tracked_jit(
         step_fn,
-        name="allreduce_step",
+        name="allreduce_step", first_call="setup.first_dispatch",
         key_argnums=(3, 4),
         event_fields={
             "dp_overlap": dp_overlap, "update_apart": update_apart,

@@ -432,6 +432,17 @@ class AllReduceTrainer(JaxTrainer):
             raise RuntimeError("master did not admit this worker to the group")
         if resp.rendezvous_id == self._group_id and not force:
             return
+        # A set-up phase: the first world of this process, and every
+        # regroup after it, with the epoch it joins.
+        with tracing.span(
+            "setup.world_init", cat=tracing.SETUP,
+            epoch=resp.rendezvous_id,
+        ):
+            self._join_world(resp, force)
+
+    def _join_world(self, resp, force):
+        """Join the world `resp` describes: gate, mesh, state from rank 0
+        or from this process's own snapshot, placed on the new mesh."""
         logger.info(
             "World change: epoch %d -> %d (rank %d of %d)",
             self._group_id,
@@ -453,7 +464,8 @@ class AllReduceTrainer(JaxTrainer):
             return
         # Snapshot to host BEFORE any distributed teardown: device arrays of
         # the old world are unusable once jax.distributed re-initializes.
-        host_state = self._state_provider()
+        with tracing.span("setup.snapshot_state", cat=tracing.SETUP):
+            host_state = self._state_provider()
         if self._multi_host:
             # Quiesce the speculator BEFORE the backend teardown: an XLA
             # compile still executing on the old PJRT client when
@@ -829,12 +841,13 @@ class AllReduceTrainer(JaxTrainer):
     def _place_variables(self, variables):
         """`variables` on the current mesh, laid out as the step takes
         them (the model spec's param_specs under TP, else replicated)."""
-        return jax.device_put(
-            variables,
-            step_plan.variables_sharding(
-                self._step_model(), self._mesh, variables
-            ),
-        )
+        with tracing.span("setup.place_variables", cat=tracing.SETUP):
+            return jax.device_put(
+                variables,
+                step_plan.variables_sharding(
+                    self._step_model(), self._mesh, variables
+                ),
+            )
 
     def _place_opt_state(self, opt_state):
         """`opt_state` on the current mesh (ZeRO-1 shards or replicated).
@@ -842,13 +855,14 @@ class AllReduceTrainer(JaxTrainer):
         of one tree dropped before the next is made, a model that fills
         the chip has room (placing both and then publishing both read a
         peak of 16.0 GB for the hybrid cell's 13.3)."""
-        return jax.device_put(
-            opt_state,
-            step_plan.opt_placement(
-                self._step_model(), self._mesh, self._n_processes(),
+        with tracing.span("setup.place_opt_state", cat=tracing.SETUP):
+            return jax.device_put(
                 opt_state,
-            ),
-        )
+                step_plan.opt_placement(
+                    self._step_model(), self._mesh, self._n_processes(),
+                    opt_state,
+                ),
+            )
 
     def _tp_active(self):
         return step_plan.tp_active(self._step_model(), self._mesh)
@@ -1175,7 +1189,8 @@ class AllReduceTrainer(JaxTrainer):
                     self._forward = self._build_forward()
             if self._pipeline_build is not None:
                 if self._variables is None:
-                    self._init_pipeline_variables(features)
+                    with tracing.span("setup.model_init", cat=tracing.SETUP):
+                        self._init_pipeline_variables(features)
                 return
             # The hook rejected the config during world init: fall through
             # to the monolithic path below (stages was reset to 1).
@@ -1187,7 +1202,8 @@ class AllReduceTrainer(JaxTrainer):
             # world (16 x 4096 tokens of flagship logits) does not fit
             # one chip — found on the first four-chip run.
             features = jax.tree_util.tree_map(lambda a: a[:1], features)
-        super().init_variables_if_needed(features)
+            with tracing.span("setup.model_init", cat=tracing.SETUP):
+                super().init_variables_if_needed(features)
         if self._mesh is None:
             self.init_world_if_needed(force=True)
         elif first_init:

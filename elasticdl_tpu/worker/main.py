@@ -3,16 +3,27 @@
 
 import os
 import sys
+import time
 
-from elasticdl_tpu import observability
-from elasticdl_tpu.common.args import validate_args, worker_parser
-from elasticdl_tpu.common.constants import DistributionStrategy, JobType
-from elasticdl_tpu.common.log_utils import get_logger
-from elasticdl_tpu.common.model_utils import get_model_spec
-from elasticdl_tpu.data.reader import create_data_reader
-from elasticdl_tpu.observability import memory
-from elasticdl_tpu.worker.master_client import MasterClient
-from elasticdl_tpu.worker.worker import Worker
+# The set-up phase `setup.imports` begins here, before the package's
+# imports; main() closes it once the observability plane is up.
+_T_IMPORTS = time.time()
+
+from elasticdl_tpu import observability  # noqa: E402
+from elasticdl_tpu.common.args import (  # noqa: E402
+    validate_args,
+    worker_parser,
+)
+from elasticdl_tpu.common.constants import (  # noqa: E402
+    DistributionStrategy,
+    JobType,
+)
+from elasticdl_tpu.common.log_utils import get_logger  # noqa: E402
+from elasticdl_tpu.common.model_utils import get_model_spec  # noqa: E402
+from elasticdl_tpu.data.reader import create_data_reader  # noqa: E402
+from elasticdl_tpu.observability import memory, tracing  # noqa: E402
+from elasticdl_tpu.worker.master_client import MasterClient  # noqa: E402
+from elasticdl_tpu.worker.worker import Worker  # noqa: E402
 
 logger = get_logger("worker.main")
 
@@ -91,18 +102,20 @@ def open_devices():
     steps ran on. A backend that cannot be opened — typically another
     process holding the chip — ends the worker here, at once, with the
     cause named; the master's relaunch budget is the retry."""
-    import jax
+    with tracing.span("setup.open_devices", cat=tracing.SETUP):
+        import jax
 
-    try:
-        devices = jax.devices()
-    except RuntimeError as e:
-        raise RuntimeError(
-            "worker could not open its accelerator (requested platforms "
-            f"JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS', '')!r}). If "
-            "the error below names the libtpu lockfile or says the TPU "
-            "is in use, another process on this host holds the chip: one "
-            f"process per chip. Backend error: {e}"
-        ) from e
+        try:
+            devices = jax.devices()
+        except RuntimeError as e:
+            raise RuntimeError(
+                "worker could not open its accelerator (requested "
+                "platforms JAX_PLATFORMS="
+                f"{os.environ.get('JAX_PLATFORMS', '')!r}). If the error "
+                "below names the libtpu lockfile or says the TPU is in "
+                "use, another process on this host holds the chip: one "
+                f"process per chip. Backend error: {e}"
+            ) from e
     first = devices[0]
     logger.info(
         "Worker devices: %d x %s (platform %s)",
@@ -122,14 +135,19 @@ def main(argv=None):
     obs = observability.setup(
         role=f"worker-{args.worker_id}", job=args.job_name
     )
+    tracing.record_span(
+        "setup.imports", _T_IMPORTS, time.time() - _T_IMPORTS,
+        cat=tracing.SETUP,
+    )
     if not args.multi_host:
         # A multi-host world initialises jax.distributed first (the
         # trainer's regroup owns that order); every other worker opens
         # its devices up front so a busy chip fails before any work.
         open_devices()
-    if args.model_zoo:
-        sys.path.insert(0, args.model_zoo)
-    spec = get_model_spec(args.model_def)
+    with tracing.span("setup.model_spec", cat=tracing.SETUP):
+        if args.model_zoo:
+            sys.path.insert(0, args.model_zoo)
+        spec = get_model_spec(args.model_def)
     job_type = _JOB_TYPES[args.job_type]
     reader_factory = spec.create_data_reader or create_data_reader
     if job_type == JobType.PREDICTION_ONLY:
@@ -153,7 +171,8 @@ def main(argv=None):
     mc = MasterClient(
         args.master_addr, args.worker_id, worker_host=args.worker_host
     )
-    trainer = build_trainer(args, spec, mc)
+    with tracing.span("setup.build_trainer", cat=tracing.SETUP):
+        trainer = build_trainer(args, spec, mc)
     extra_callbacks = []
     if args.output:
         from elasticdl_tpu.common.save_utils import ExportModelCallback

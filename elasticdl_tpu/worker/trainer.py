@@ -256,7 +256,7 @@ class JaxTrainer(Trainer):
             functools.partial(self._step_body, update_apart=True),
             name="train_step", key_argnums=(3, 4),
             event_fields={"update_apart": True},
-            donate_argnums=(0, 1),
+            donate_argnums=(0, 1), first_call="setup.first_dispatch",
         )
 
     def _build_forward(self):
@@ -341,3 +341,13 @@ class JaxTrainer(Trainer):
 class LocalTrainer(JaxTrainer):
     """Single-chip training: the minimum end-to-end strategy (reference
     DistributionStrategy.LOCAL)."""
+
+    def init_variables_if_needed(self, features):
+        if self._variables is not None:
+            return
+        # Down here, below the step's own functions: jax keeps the line
+        # numbers of a traced call stack in the compile cache's key.
+        from elasticdl_tpu.observability import tracing
+
+        with tracing.span("setup.model_init", cat=tracing.SETUP):
+            super().init_variables_if_needed(features)

@@ -108,6 +108,9 @@ class Worker:
         self._profiling = False
         self._profile_first_step = 0
         self._profile_started = 0.0
+        # When run() began, until the first batch is in hand (the set-up
+        # phase `setup.first_task`); None before and after.
+        self._run_started = None
         self._callbacks = (
             model_spec.callbacks() if model_spec.callbacks else []
         ) + list(extra_callbacks)
@@ -115,6 +118,7 @@ class Worker:
     # ---------- public ----------
 
     def run(self):
+        self._run_started = time.time()
         try:
             if self._profile_dir and self._job_type in (
                 JobType.EVALUATION_ONLY,
@@ -231,6 +235,8 @@ class Worker:
             first = self._spec.feed(
                 records[:B], Modes.TRAINING, self._metadata
             )
+            if self._run_started is not None:
+                self._first_batch_in_hand()
             self._trainer.init_variables_if_needed(first[0])
             self._trainer.init_world_if_needed()
             if (
@@ -436,6 +442,8 @@ class Worker:
                 features, labels = self._spec.feed(
                     records, Modes.TRAINING, self._metadata
                 )
+            if self._run_started is not None:
+                self._first_batch_in_hand()
             accepted, version, loss = self._trainer.train_minibatch(
                 features, labels
             )
@@ -456,6 +464,15 @@ class Worker:
                     loss,
                     getattr(self._trainer, "last_step_stats", None),
                 )
+
+    def _first_batch_in_hand(self):
+        """Close the set-up phase that began with run(): the first
+        `get_task`, the reader's open, the first decode."""
+        started, self._run_started = self._run_started, None
+        tracing.record_span(
+            "setup.first_task", started, time.time() - started,
+            cat=tracing.SETUP,
+        )
 
     def _log_unread_loss(self):
         """Read and log the loss of the last logging step. Called once
@@ -511,7 +528,8 @@ class Worker:
             self._profiling = True
             self._profile_first_step = next_step
             self._profile_started = time.time()
-            jax.profiler.start_trace(self._profile_dir)
+            with self._step_clock.profile_call():
+                jax.profiler.start_trace(self._profile_dir)
             # From here tracing.span() also writes into this trace.
             tracing.set_profiler_session(True)
             logger.info(
@@ -532,7 +550,8 @@ class Worker:
         tracing.set_profiler_session(False)
         stopped = time.time()
         try:
-            jax.profiler.stop_trace()
+            with self._step_clock.profile_call():
+                jax.profiler.stop_trace()
             logger.info(
                 "Profile written to %s (view: tensorboard --logdir %s)",
                 self._profile_dir,

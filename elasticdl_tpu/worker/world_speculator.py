@@ -42,7 +42,7 @@ import time
 
 from elasticdl_tpu.common import knobs
 from elasticdl_tpu.common.log_utils import get_logger
-from elasticdl_tpu.observability import emit_event
+from elasticdl_tpu.observability import emit_event, profiling
 from elasticdl_tpu.observability.metrics import default_registry
 
 logger = get_logger("worker.world_speculator")
@@ -234,7 +234,8 @@ class SpeculativeWorldCompiler:
                 outcome = "skipped"
                 return
             shape_key, step, abstract_args = plan
-            executable = step.lower(*abstract_args).compile()
+            with profiling.open_compile("speculative_compile"):
+                executable = step.lower(*abstract_args).compile()
             with self._lock:
                 stale = job.generation != self._generation
                 if self._stopped or (
