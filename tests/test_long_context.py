@@ -9,7 +9,9 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from elasticdl_tpu.ops import flash_attention as fa
 from elasticdl_tpu.ops.flash_attention import (
+    causal_tile_kinds,
     flash_attention,
     reference_attention,
 )
@@ -235,6 +237,232 @@ def test_fallback_runs_its_softmax_in_float32(qkv, causal):
         assert a.dtype == jnp.bfloat16
         a, b = np.asarray(a.astype(jnp.float32)), np.asarray(b)
         assert np.abs(a - b).max() <= BF16_EPS * np.abs(b).max()
+
+
+# ---------- the oracle of the tile kinds: every run tile masked whole ----------
+# The two kernels as they stood before a tile's work followed its place to
+# the diagonal (one accumulate body each, the mask on every tile that
+# runs), kept here to be run through the module's own `pallas_call`s.
+
+
+def _masked_whole(scores, i, j, block_q, block_k):
+    q_pos = i * block_q + jax.lax.broadcasted_iota(
+        jnp.int32, (block_q, block_k), 0
+    )
+    k_pos = j * block_k + jax.lax.broadcasted_iota(
+        jnp.int32, (block_q, block_k), 1
+    )
+    return jnp.where(q_pos >= k_pos, scores, fa.NEG_INF)
+
+
+def _oracle_fwd_kernel(
+    q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
+    *, block_q, block_k, num_k_blocks, causal, scale, emit_lse,
+):
+    from jax.experimental import pallas as pl
+
+    assert causal and emit_lse
+    i = pl.program_id(1)
+    j = pl.program_id(2)
+    last_j = fa._last_kj(i, block_q, block_k, num_k_blocks, causal)
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[:] = jnp.full(m_scr.shape, fa.NEG_INF, jnp.float32)
+        l_scr[:] = jnp.zeros(l_scr.shape, jnp.float32)
+        acc_scr[:] = jnp.zeros(acc_scr.shape, jnp.float32)
+
+    @pl.when(j <= last_j)
+    def _accumulate():
+        q = q_ref[:].astype(jnp.float32) * scale
+        k = k_ref[:].astype(jnp.float32)
+        v = v_ref[:].astype(jnp.float32)
+        scores = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
+        scores = _masked_whole(scores, i, j, block_q, block_k)
+        m_prev = m_scr[:, :1]
+        l_prev = l_scr[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(scores - m_new)
+        l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_scr[:] = acc_scr[:] * alpha + jnp.dot(
+            p, v, preferred_element_type=jnp.float32
+        )
+        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+
+    @pl.when(j == last_j)
+    def _finalize():
+        m = m_scr[:, :1]
+        l = l_scr[:, :1]
+        l_safe = jnp.where(l == 0.0, 1.0, l)
+        o_ref[:] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
+        lse_ref[:] = jnp.broadcast_to(m + jnp.log(l_safe), lse_ref.shape)
+
+
+def _oracle_bwd_kernel(
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
+    dq_scr, dk_scr, dv_scr,
+    *, block_q, block_k, num_q_blocks, num_k_blocks, causal, scale,
+):
+    from jax.experimental import pallas as pl
+
+    assert causal
+    j = pl.program_id(1)
+    i = pl.program_id(2)
+    first_i = fa._first_qi(j, block_q, block_k, causal)
+
+    @pl.when((j == 0) & (i == 0))
+    def _init_row():
+        dq_scr[:] = jnp.zeros(dq_scr.shape, jnp.float32)
+
+    @pl.when(i == 0)
+    def _init():
+        dk_scr[:] = jnp.zeros(dk_scr.shape, jnp.float32)
+        dv_scr[:] = jnp.zeros(dv_scr.shape, jnp.float32)
+
+    @pl.when(i >= first_i)
+    def _accumulate():
+        q = q_ref[:].astype(jnp.float32)
+        k = k_ref[:].astype(jnp.float32)
+        v = v_ref[:].astype(jnp.float32)
+        do = do_ref[:].astype(jnp.float32)
+        lse = lse_ref[:, :1]
+        delta = delta_ref[:, :1]
+        scores = scale * jnp.dot(q, k.T, preferred_element_type=jnp.float32)
+        scores = _masked_whole(scores, i, j, block_q, block_k)
+        p = jnp.exp(scores - lse)
+        dv_scr[:] = dv_scr[:] + jnp.dot(
+            p.T, do, preferred_element_type=jnp.float32
+        )
+        dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
+        ds = p * (dp - delta)
+        dk_scr[:] = dk_scr[:] + scale * jnp.dot(
+            ds.T, q, preferred_element_type=jnp.float32
+        )
+        rows = pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
+        dq_scr[rows, :] = dq_scr[rows, :] + scale * jnp.dot(
+            ds, k, preferred_element_type=jnp.float32
+        )
+
+    @pl.when(i == num_q_blocks - 1)
+    def _finalize():
+        dk_ref[:] = dk_scr[:].astype(dk_ref.dtype)
+        dv_ref[:] = dv_scr[:].astype(dv_ref.dtype)
+
+    @pl.when((j == num_k_blocks - 1) & (i == num_q_blocks - 1))
+    def _finalize_row():
+        dq_ref[:] = dq_scr[:].astype(dq_ref.dtype)
+
+
+def _both_passes(q, k, v, g, block_q, block_k):
+    out, lse = fa._flash_forward(q, k, v, True, block_q, block_k, True)
+    return (out, lse) + fa._flash_backward(
+        q, k, v, out, lse, g, True, block_q, block_k
+    )
+
+
+def _bits(x):
+    x = np.asarray(x)
+    return x.view(np.uint16 if x.dtype == jnp.bfloat16 else np.uint32)
+
+
+@pytest.mark.parametrize("tiles", [4, 8])
+@pytest.mark.parametrize(
+    "block_q,block_k",
+    [(128, 128), (256, 256), (128, 256), (256, 128)],
+    ids=["equal128", "equal256", "unequal_k", "unequal_q"],
+)
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("head", [64, 128])
+def test_tile_kinds_give_the_bits_of_masking_every_tile(
+    monkeypatch, head, dtype, block_q, block_k, tiles
+):
+    """A tile below the diagonal runs with no mask, a crossed one masked:
+    the same products on the same values in the same order, less the
+    selects that return their input. So o and lse are the oracle's bit for
+    bit in every case, and dq, dk, dv at head 64. At head 128 the CPU
+    backend (not Mosaic: on the chip all five are bit-equal at the cells'
+    three shapes, PERF.md section 6, PR 42) contracts `scale * s - lse`
+    into a fused multiply-add once the select between them is gone: the
+    scale is 2^-3.5 there and the product rounds (at head 64 it is 2^-3
+    and exact), so a gradient may differ by one float32 ulp, which a
+    bfloat16 result shows as one of its own ulps in a few elements."""
+    monkeypatch.setenv("EDL_FORCE_PALLAS_INTERPRET", "1")
+    s = tiles * max(block_q, block_k)
+    rng = np.random.default_rng(head + s + block_q)
+    q, k, v, g = (
+        jnp.asarray(rng.normal(size=(1, 1, s, head)), dtype)
+        for _ in range(4)
+    )
+    got = _both_passes(q, k, v, g, block_q, block_k)
+    monkeypatch.setattr(fa, "_fwd_kernel", _oracle_fwd_kernel)
+    monkeypatch.setattr(fa, "_bwd_kernel", _oracle_bwd_kernel)
+    want = _both_passes(q, k, v, g, block_q, block_k)
+    for name, a, b in zip(("o", "lse", "dq", "dk", "dv"), got, want):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        if head == 64 or name in ("o", "lse"):
+            np.testing.assert_array_equal(_bits(a), _bits(b), err_msg=name)
+            continue
+        a, b = (np.asarray(x.astype(jnp.float32)) for x in (a, b))
+        if dtype == "float32":
+            np.testing.assert_allclose(a, b, rtol=0, atol=1e-6, err_msg=name)
+        else:
+            off = np.abs(a - b) > 1e-6
+            assert off.mean() < 1e-3, name
+            assert (np.abs(a - b) <= BF16_EPS * np.abs(b))[off].all(), name
+
+
+@pytest.mark.parametrize(
+    "block_q,block_k",
+    [(128, 128), (256, 256), (512, 512), (1024, 1024), (128, 256),
+     (256, 128), (128, 1024), (1024, 256), (512, 1024), (1024, 512)],
+)
+@pytest.mark.parametrize("s", [1024, 2048, 4096, 8192])
+def test_causal_tile_kinds_against_the_mask_itself(s, block_q, block_k):
+    """The helper's counts against a brute-force count over the [S, S]
+    mask: a tile runs if any of its scores is seen, lies below the
+    diagonal if all are, is crossed otherwise; and with the skipped tiles
+    the three kinds partition the grid."""
+    num_q, num_k = s // block_q, s // block_k
+    seen = np.tril(np.ones((s, s), bool)).reshape(
+        num_q, block_q, num_k, block_k
+    )
+    some, every = seen.any(axis=(1, 3)), seen.all(axis=(1, 3))
+    run, below, crossed = causal_tile_kinds(s, block_q, block_k)
+    assert (run, below, crossed) == (
+        some.sum(), every.sum(), (some & ~every).sum()
+    )
+    assert (~some).sum() + below + crossed == num_q * num_k
+    # The kernels' own predicates, tile by tile.
+    for i in range(num_q):
+        last_j = int(fa._last_kj(i, block_q, block_k, num_k, True))
+        for j in range(num_k):
+            assert some[i, j] == (j <= last_j)
+            assert every[i, j] == fa._below_diagonal(i, j, block_q, block_k)
+
+
+@pytest.mark.parametrize("block_k,constant", [(128, True), (256, False)])
+def test_the_mask_of_equal_blocks_is_a_constant_of_the_trace(
+    block_k, constant
+):
+    """With equal blocks a crossed tile lies on the diagonal, so its mask
+    reads neither grid index: that is what lets the compiler drop the
+    score blocks above the diagonal (PERF.md section 6, PR 42)."""
+    jaxpr = jax.make_jaxpr(
+        lambda s, i, j: fa._causal_mask_scores(s, i, j, 128, block_k)
+    )(jnp.zeros((128, block_k), jnp.float32), 1, 1).jaxpr
+    indices = jaxpr.invars[1:]
+    read = any(
+        v is index
+        for eqn in jaxpr.eqns for v in eqn.invars for index in indices
+    )
+    assert read != constant
+
+
+def test_causal_tile_kinds_of_the_cells():
+    assert causal_tile_kinds(4096, 1024, 1024) == (10, 6, 4)
+    assert causal_tile_kinds(8192, 1024, 1024) == (36, 28, 8)
 
 
 def test_block_fitting_keeps_pallas_for_512_multiples():

@@ -29,11 +29,13 @@ pytestmark = pytest.mark.usefixtures("no_persistent_compile_cache")
 HBM_BYTES = 16 * 2**30
 
 # [B, H, S, D]: the flagship (8 x 128 heads, S=4096, minibatch 4), its
-# S=8192 sibling, and the 64-wide-head variant.
+# S=8192 sibling, the 64-wide-head variant, and the LFM2 cut's own call
+# (32 heads of 64, minibatch 2 x S 8192).
 FLAGSHIP_SHAPES = [
     (4, 8, 4096, 128),
     (2, 8, 8192, 128),
     (4, 16, 4096, 64),
+    (2, 32, 8192, 64),
 ]
 
 
