@@ -128,6 +128,10 @@ def moe_param_specs(params, expert_axis=MODEL_AXIS):
 
 # ---------- top-k routed experts, this chip's share ----------
 
+SCORES = {
+    "sigmoid": jax.nn.sigmoid,
+    "softmax": functools.partial(jax.nn.softmax, axis=-1),
+}
 ROUTING_SCOPE = "moe_routing"
 GROUPED_SCOPE = "moe_grouped"
 SHARED_SCOPE = "moe_shared"
@@ -135,7 +139,8 @@ SHARED_SCOPE = "moe_shared"
 
 def route_top_k(scores, correction_bias, k, norm_topk_prob, scaling_factor,
                 eps=1e-20):
-    """scores [T, E] float32 (after the sigmoid) -> (experts [T, k] int32,
+    """scores [T, E] float32 (after the sigmoid, or the softmax over all E)
+    -> (experts [T, k] int32,
     weights [T, k] float32). The k experts are the largest of scores +
     correction_bias; the weights are the scores themselves at the chosen
     (without the bias), divided by their sum (plus `eps`: HF `lfm2_moe`
@@ -409,10 +414,13 @@ grouped_swiglu_experts.defvjp(_swiglu_fwd, _swiglu_bwd)
 
 
 class RoutedExperts(nn.Module):
-    """Top-k of `num_experts` by sigmoid scores with a correction bias,
-    experts `down(relu(up(x))^2)` without a gate, and a shared expert of the
-    same form for every token (DeepSeek-V3's routing as the HF `nemotron_h`
-    model uses it). No auxiliary loss, no capacity: nothing is dropped.
+    """Top-k of `num_experts` by their scores plus a correction bias:
+    `score` "sigmoid" scores each expert by the sigmoid of its logit
+    (DeepSeek-V3's routing as the HF `nemotron_h` and `lfm2_moe` models use
+    it), "softmax" by the softmax over all `num_experts` logits, in float32,
+    before the choice (HF `qwen3_moe` / `sdar_moe`). Experts
+    `down(relu(up(x))^2)` without a gate, and a shared expert of the same
+    form for every token. No auxiliary loss, no capacity: nothing is dropped.
     `gated` makes the experts `down(silu(gate(x)) * up(x))` (HF `lfm2_moe`;
     parameters `w_gate_up`, `w_down`; no shared expert in that form), and
     the layer then also counts the rows its loops multiplied.
@@ -434,6 +442,7 @@ class RoutedExperts(nn.Module):
     routed_scaling_factor: float = 1.0
     block_rows: int = 1024
     gated: bool = False
+    score: str = "sigmoid"
     # Under the sum that normalises the chosen scores.
     topk_eps: float = 1e-20
     # Not None: the router's logits are replaced, in the forward pass, by
@@ -455,6 +464,9 @@ class RoutedExperts(nn.Module):
             raise ValueError(
                 f"held experts [{first}, {first + count}) are not among "
                 f"the {self.num_experts}")
+        if self.score not in SCORES:
+            raise ValueError(
+                f"score {self.score!r}: the layer scores by {sorted(SCORES)}")
         tokens = x.reshape(-1, d).astype(dtype)
         k = self.num_experts_per_tok
         with jax.named_scope(ROUTING_SCOPE):
@@ -473,7 +485,7 @@ class RoutedExperts(nn.Module):
                     jax.random.PRNGKey(self.force_balance_seed),
                     (b, s, self.num_experts), f32).reshape(logits.shape)
                 logits = logits + jax.lax.stop_gradient(noise - logits)
-            scores = jax.nn.sigmoid(logits)
+            scores = SCORES[self.score](logits)
             experts, weights = route_top_k(
                 scores, jax.lax.stop_gradient(bias.value), k,
                 self.norm_topk_prob, self.routed_scaling_factor,

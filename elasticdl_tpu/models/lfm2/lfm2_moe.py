@@ -121,15 +121,18 @@ class Lfm2MoeConfig:
         return nn.initializers.normal(self.initializer_range)
 
 
-def rotary(x, theta):
+def rotary(x, theta, positions=None):
     """x [B, S, H, Dh] -> x turned by its position, over the whole head:
     x * cos + rotate_half(x) * sin with angles position * theta^(-2i / Dh)
     for i < Dh / 2, repeated over the two halves (the HF `default` rope).
-    In float32."""
+    `positions` [S] are the rows' positions where they are not 0 .. S - 1
+    (a sequence that holds two copies of a record). In float32."""
     s, dh = x.shape[1], x.shape[-1]
     f32 = jnp.float32
     inv_freq = theta ** (-jnp.arange(0, dh, 2, dtype=f32) / dh)
-    angles = jnp.arange(s, dtype=f32)[:, None] * inv_freq[None]  # [S, Dh/2]
+    if positions is None:
+        positions = jnp.arange(s, dtype=f32)
+    angles = positions.astype(f32)[:, None] * inv_freq[None]  # [S, Dh/2]
     angles = jnp.concatenate([angles, angles], axis=-1)[None, :, None, :]
     x = x.astype(f32)
     x1, x2 = jnp.split(x, 2, axis=-1)

@@ -29,22 +29,34 @@ matrix never materializes — in EITHER pass:
 - delta = rowsum(dout * out) is precomputed in one cheap fused XLA
   elementwise pass and streamed like lse.
 
-A causal tile is one of three kinds, told at its grid step from where it
-lies to the diagonal (`causal_tile_kinds` counts them from the shapes):
-above it, skipped (pl.when; its index maps re-address a resident tile, so
-no FLOPs and no DMA); below it, accumulated by a body with no mask at all
-(every position is seen, and a select whose predicate is all true returns
-its input); crossed by it, masked. With equal blocks a crossed tile lies
-on the diagonal itself and its mask is a constant of the trace, which lets
-the compiler drop the score blocks above the diagonal from the q k^T
-product. Every kind gives the bits of masking every tile whole, forward
-and backward. Under ring/Ulysses sequence parallelism
-(parallel/ring_attention.py) the per-device S is the block, so VMEM bounds
-the per-shard sequence, not the global one.
+The mask is a hashable description handed in where the scores would be
+masked: `False` (every position sees every other), `True` (causal) or
+`BlockDiffusion(block, half)` (a clean and a noised copy of a record as
+one sequence of 2 * half rows, see the class). One function,
+`_tile_kinds`, tells a tile's kind at its grid step from the description
+and `(i, j, block_q, block_k)`: skipped (pl.when; its index maps
+re-address a resident tile, so no FLOPs and no DMA); whole, accumulated by
+a body with no mask at all (every position is seen, and a select whose
+predicate is all true returns its input); or crossed, masked. Under the
+causal mask a crossed tile is crossed by the diagonal
+(`causal_tile_kinds` counts the kinds from the shapes); with equal blocks
+it lies on the diagonal itself and its mask is a constant of the trace,
+which lets the compiler drop the score blocks above the diagonal from the
+q k^T product. Under block diffusion a tile is crossed in one of three
+ways (clean rows on their own tile of clean columns, noised rows on it,
+noised rows on their own tile of noised columns:
+`block_diffusion_tile_kinds`), each with equal blocks a constant too, and
+a noised row's run set is not contiguous (`{0..i}` and `n + i`): the
+index maps and the loops' ends follow the run set's segments
+(`_k_segments`, `_q_segments`). Every causal kind gives the bits of
+masking every tile whole, forward and backward. Under ring/Ulysses
+sequence parallelism (parallel/ring_attention.py) the per-device S is the
+block, so VMEM bounds the per-shard sequence, not the global one.
 """
 
 import functools
 import os
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -108,17 +120,74 @@ def _per_batch_shard(fn):
     )
 
 
+# ---------- the mask's description ----------
+
+
+class BlockDiffusion(NamedTuple):
+    """The block-diffusion training mask (Arriola et al. 2025, BD3-LM) over
+    one sequence of 2 * `half` rows: rows [0, half) a clean copy of a
+    record, rows [half, 2 half) its noised copy, both at positions 0 ..
+    half - 1, in blocks of `block` positions (beta(p) = p // block). Row r
+    at position p sees column c at position s iff
+      r clean,  c clean:   beta(s) <= beta(p)   (block-causal)
+      r noised, c clean:   beta(s) <  beta(p)   (the clean past)
+      r noised, c noised:  beta(s) == beta(p)   (its own block, both ways)
+      r clean,  c noised:  never.
+    Every row sees its own block, so no softmax row is empty."""
+
+    block: int
+    half: int
+
+
+def _unmasked(mask):
+    return not isinstance(mask, BlockDiffusion) and not mask
+
+
+def _check_mask(mask, s, block_q=None, block_k=None):
+    """A description the sequence (and, where the kernel runs, its tiles)
+    cannot carry raises."""
+    if not isinstance(mask, BlockDiffusion):
+        return
+    if s != 2 * mask.half or mask.half % mask.block:
+        raise ValueError(
+            f"flash_attention: {mask} describes {2 * mask.half} rows in "
+            f"whole blocks of {mask.block}, the sequence has {s}")
+    for tile in (block_q, block_k):
+        if tile is not None and (mask.half % tile or tile % mask.block):
+            raise ValueError(
+                f"flash_attention: a tile of {tile} rows is not whole "
+                f"blocks of {mask.block} inside a half of {mask.half}")
+
+
+def dense_mask(mask, s_q, s_k):
+    """The [s_q, s_k] boolean mask a description stands for (None: no
+    mask): what the XLA path applies and what the tests hold the tile
+    kinds to."""
+    if isinstance(mask, BlockDiffusion):
+        _check_mask(mask, s_q)
+        _check_mask(mask, s_k)
+        at = jnp.arange(s_q)
+        noised = at >= mask.half
+        beta = (at - noised * mask.half) // mask.block
+        (rn, cn), (rb, cb) = (
+            (x[:, None], x[None, :]) for x in (noised, beta))
+        return jnp.where(
+            rn, jnp.where(cn, cb == rb, cb < rb), ~cn & (cb <= rb))
+    if mask:
+        return jnp.tril(jnp.ones((s_q, s_k), bool), k=s_k - s_q)
+    return None
+
+
 # ---------- reference path (also the correctness oracle in tests) ----------
 
 
-def reference_attention(q, k, v, causal=False):
+def reference_attention(q, k, v, mask=False):
     """[B, H, S, D] full attention in plain XLA."""
     scale = q.shape[-1] ** -0.5
     scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
-    if causal:
-        s_q, s_k = scores.shape[-2], scores.shape[-1]
-        mask = jnp.tril(jnp.ones((s_q, s_k), bool), k=s_k - s_q)
-        scores = jnp.where(mask, scores, NEG_INF)
+    seen = dense_mask(mask, scores.shape[-2], scores.shape[-1])
+    if seen is not None:
+        scores = jnp.where(seen, scores, NEG_INF)
     weights = jax.nn.softmax(scores, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", weights, v)
 
@@ -153,16 +222,41 @@ def causal_tile_kinds(s, block_q, block_k):
     many are not skipped, how many of those take the unmasked body and how
     many the masked one. A function of the shapes alone (10 / 6 / 4 at
     S 4096 and 36 / 28 / 8 at S 8192 over 1024 x 1024 tiles)."""
-    num_q, num_k = s // block_q, s // block_k
-    run = below = 0
-    for i in range(num_q):
-        last_j = min(((i + 1) * block_q - 1) // block_k, num_k - 1)
-        run += last_j + 1
-        below += sum(
-            _below_diagonal(i, j, block_q, block_k)
-            for j in range(last_j + 1)
-        )
-    return run, below, run - below
+    run, whole, crossed = _count_tile_kinds(True, s, block_q, block_k)
+    return run, whole, sum(crossed)
+
+
+def block_diffusion_tile_kinds(half, block, block_q, block_k):
+    """(run, whole, crossed) tiles of one batch*head's grid under
+    `BlockDiffusion(block, half)`, `crossed` by kind: (clean rows on
+    clean columns, noised rows on clean columns, noised rows on noised
+    columns). 80 / 56 / (8, 8, 8) at half 8192, block 4 over 1024 x 1024
+    tiles, of 256."""
+    return _count_tile_kinds(
+        BlockDiffusion(block, half), 2 * half, block_q, block_k)
+
+
+def block_diffusion_scores(half, block, block_q=DEFAULT_BLOCK_Q,
+                           block_k=DEFAULT_BLOCK_K):
+    """(needed, run) scores of one batch*head under `BlockDiffusion(block,
+    half)`: what the mask lets through, half * (half + block), and what
+    the tiles that run hold, at the tiles the kernel takes for these
+    blocks."""
+    bq, bk = _clamp_blocks(
+        2 * half, block_q, block_k, BlockDiffusion(block, half))
+    run, _, _ = block_diffusion_tile_kinds(half, block, bq, bk)
+    return half * (half + block), run * bq * bk
+
+
+def _count_tile_kinds(mask, s, block_q, block_k):
+    kinds = [
+        _tile_kinds(mask, i, j, block_q, block_k)
+        for i in range(s // block_q) for j in range(s // block_k)]
+    whole = sum(bool(is_whole) for is_whole, _ in kinds)
+    crossed = tuple(
+        sum(bool(hit) for hit, _ in way)
+        for way in zip(*(ways for _, ways in kinds)))
+    return whole + sum(crossed), whole, crossed
 
 
 def _causal_mask_scores(scores, i, j, block_q, block_k):
@@ -179,16 +273,147 @@ def _causal_mask_scores(scores, i, j, block_q, block_k):
     return jnp.where(row >= col, scores, NEG_INF)
 
 
-def _accumulate_by_kind(accumulate, causal, runs, below):
-    """Call `accumulate(masked)` as the tile's kind asks: a tile that runs
-    masked unless it lies below the diagonal (or nothing is causal)."""
+def _block_mask_scores(scores, sees, block, p0, s0, block_q, block_k):
+    """Mask a crossed score tile under block diffusion: a row sees a
+    column iff `sees(column's block, row's block)`. `p0`, `s0` are the
+    positions of the tile's first row and column; with equal tiles a
+    crossed tile has them equal (a multiple of `block`), and the mask is
+    a constant of the trace as the causal diagonal's is."""
+    row = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+    if block_q != block_k:
+        row, col = row + p0, col + s0
+    if block & (block - 1) == 0:
+        shift = block.bit_length() - 1
+        row = jax.lax.shift_right_logical(row, shift)
+        col = jax.lax.shift_right_logical(col, shift)
+    else:
+        row, col = jax.lax.div(row, block), jax.lax.div(col, block)
+    return jnp.where(sees(col, row), scores, NEG_INF)
+
+
+def _half_tile(mask, t, tile):
+    """Tile t of `tile` rows under block diffusion: (0 clean or 1 noised,
+    the positions of its first and last row, their blocks)."""
+    noised = (t * tile) // mask.half
+    first = t * tile - noised * mask.half
+    last = first + tile - 1
+    return noised, first, last, first // mask.block, last // mask.block
+
+
+def _tile_kinds(mask, i, j, block_q, block_k):
+    """The kind of tile (i, j) under the mask's description, as (whole,
+    crossed): `whole` says that the tile runs and none of its scores is
+    masked; `crossed` is a tuple of (hit, mask_scores) pairs, one a way of
+    being crossed, `hit` saying that the tile runs masked that way and
+    `mask_scores(scores)` masking it. A tile that is neither is skipped.
+    i, j are grid indices (traced) or plain ints (the counts)."""
+    if _unmasked(mask):
+        return True, ()
+    if not isinstance(mask, BlockDiffusion):
+        # Crossed: not above the diagonal, not below it.
+        hit = (j * block_k <= (i + 1) * block_q - 1) & (
+            (j + 1) * block_k - 1 > i * block_q)
+        return _below_diagonal(i, j, block_q, block_k), ((
+            hit,
+            lambda s: _causal_mask_scores(s, i, j, block_q, block_k)),)
+    r_noised, p0, _, rb0, rb1 = _half_tile(mask, i, block_q)
+    c_noised, s0, _, cb0, cb1 = _half_tile(mask, j, block_k)
+    # (which halves, some score seen, every score seen, the relation).
+    ways = (
+        ((r_noised == 0) & (c_noised == 0), cb0 <= rb1, cb1 <= rb0,
+         lambda c, r: c <= r),
+        ((r_noised == 1) & (c_noised == 0), cb0 < rb1, cb1 < rb0,
+         lambda c, r: c < r),
+        ((r_noised == 1) & (c_noised == 1),
+         (cb0 <= rb1) & (rb0 <= cb1), (cb0 >= rb1) & (cb1 <= rb0),
+         lambda c, r: c == r),
+    )
+    whole = False
+    crossed = []
+    for halves, some, every, sees in ways:
+        whole = whole | (halves & every)
+        crossed.append((
+            halves & some & (every == False),  # noqa: E712 (traced)
+            functools.partial(
+                _block_mask_scores, sees=sees, block=mask.block, p0=p0,
+                s0=s0, block_q=block_q, block_k=block_k)))
+    return whole, tuple(crossed)
+
+
+def _k_segments(mask, i, block_q, block_k, num_k):
+    """The k tiles that row tile i runs, as two segments ((first, last),
+    (first, last)) in rising order; a run set of one segment gives it
+    twice. Causal: 0 .. `_last_kj`. Block diffusion: a clean row runs the
+    clean columns up to its own tile; a noised row the clean columns
+    whose first block lies before its last one, then the noised tiles
+    that hold its own positions."""
+    if not isinstance(mask, BlockDiffusion):
+        only = (0, _last_kj(i, block_q, block_k, num_k, mask))
+        return only, only
+    noised, p0, p1, _, rb1 = _half_tile(mask, i, block_q)
+    n_half = mask.half // block_k
+    clean = (0, jnp.where(
+        noised == 1, ((rb1 - 1) * mask.block) // block_k, p1 // block_k))
+    own = (n_half + p0 // block_k, n_half + p1 // block_k)
+    return clean, tuple(
+        jnp.where(noised == 1, a, b) for a, b in zip(own, clean))
+
+
+def _q_segments(mask, j, block_q, block_k, num_q):
+    """The q tiles that column tile j is run by, as `_k_segments` gives
+    the k tiles of a row. Causal: `_first_qi` .. the last. Block
+    diffusion: a clean column is run by the clean rows from its own
+    position on and by the noised rows whose last block lies after its
+    first one; a noised column by the noised tiles that hold its own
+    positions."""
+    if not isinstance(mask, BlockDiffusion):
+        only = (_first_qi(j, block_q, block_k, mask), num_q - 1)
+        return only, only
+    noised, s0, s1, _, _ = _half_tile(mask, j, block_k)
+    n_half = mask.half // block_q
+    own = (n_half + s0 // block_q, n_half + s1 // block_q)
+    clean_rows = (s0 // block_q, n_half - 1)
+    noised_rows = (n_half + (s0 + mask.block) // block_q, num_q - 1)
+    return (
+        tuple(jnp.where(noised == 1, a, b)
+              for a, b in zip(own, clean_rows)),
+        tuple(jnp.where(noised == 1, a, b)
+              for a, b in zip(own, noised_rows)),
+    )
+
+
+def _resident_k(j, segments):
+    """The k tile that forward step j addresses: itself where it runs,
+    else the run tile before it (already resident: no DMA), or the first
+    run tile of all."""
+    (f0, l0), (f1, l1) = segments
+    return jnp.where(j >= f1, jnp.minimum(j, l1), jnp.clip(j, f0, l0))
+
+
+def _resident_q(i, segments, num_q):
+    """The q tile that backward step i addresses: itself where it runs,
+    else the next run tile (fetched while the skipped steps pass), or the
+    last run tile of all."""
+    (f0, l0), (f1, l1) = segments
+    at = jnp.where(i <= l0, jnp.maximum(i, f0), jnp.clip(i, f1, l1))
+    # A clean column that no noised row runs has an empty second segment.
+    return jnp.minimum(at, num_q - 1)
+
+
+def _accumulate_by_kind(accumulate, kinds):
+    """Call `accumulate(mask_scores)` as the tile's kind (`_tile_kinds`)
+    asks: with None where the tile is whole, with the way's own mask where
+    it is crossed, not at all where it is skipped."""
     from jax.experimental import pallas as pl
 
-    if not causal:
-        accumulate(masked=False)
+    whole, crossed = kinds
+    if whole is True:
+        accumulate(None)
         return
-    pl.when(below)(lambda: accumulate(masked=False))
-    pl.when(runs & jnp.logical_not(below))(lambda: accumulate(masked=True))
+    pl.when(whole)(lambda: accumulate(None))
+    for hit, mask_scores in crossed:
+        pl.when(hit)(functools.partial(accumulate, mask_scores))
 
 
 # ---------- forward kernel ----------
@@ -196,7 +421,7 @@ def _accumulate_by_kind(accumulate, causal, runs, below):
 
 def _fwd_kernel(
     q_ref, k_ref, v_ref, *refs,
-    block_q, block_k, num_k_blocks, causal, scale, emit_lse,
+    block_q, block_k, num_k_blocks, mask, scale, emit_lse,
 ):
     from jax.experimental import pallas as pl
 
@@ -207,7 +432,7 @@ def _fwd_kernel(
         lse_ref = None
     i = pl.program_id(1)  # q block
     j = pl.program_id(2)  # k block (minormost: iterates fastest)
-    last_j = _last_kj(i, block_q, block_k, num_k_blocks, causal)
+    last_j = _k_segments(mask, i, block_q, block_k, num_k_blocks)[1][1]
 
     @pl.when(j == 0)
     def _init():
@@ -215,13 +440,13 @@ def _fwd_kernel(
         l_scr[:] = jnp.zeros(l_scr.shape, jnp.float32)
         acc_scr[:] = jnp.zeros(acc_scr.shape, jnp.float32)
 
-    def accumulate(masked):
+    def accumulate(mask_scores):
         q = q_ref[:].astype(jnp.float32) * scale
         k = k_ref[:].astype(jnp.float32)
         v = v_ref[:].astype(jnp.float32)
         scores = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
-        if masked:
-            scores = _causal_mask_scores(scores, i, j, block_q, block_k)
+        if mask_scores is not None:
+            scores = mask_scores(scores)
         m_prev = m_scr[:, :1]  # [block_q, 1]
         l_prev = l_scr[:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(scores, axis=1, keepdims=True))
@@ -234,13 +459,11 @@ def _fwd_kernel(
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
 
-    # Tiles fully above the causal diagonal contribute nothing: skip. (The
-    # k/v index maps also clamp to last_j, so skipped steps re-address the
+    # Tiles no row of which sees a column contribute nothing: skip. (The
+    # k/v index maps follow the run set, so skipped steps re-address an
     # already-resident tile and cost no DMA either.)
     _accumulate_by_kind(
-        accumulate, causal, j <= last_j,
-        _below_diagonal(i, j, block_q, block_k),
-    )
+        accumulate, _tile_kinds(mask, i, j, block_q, block_k))
 
     @pl.when(j == last_j)
     def _finalize():
@@ -253,7 +476,7 @@ def _fwd_kernel(
             lse_ref[:] = jnp.broadcast_to(lse, lse_ref.shape)
 
 
-def _flash_forward(q, k, v, causal, block_q, block_k, emit_lse):
+def _flash_forward(q, k, v, mask, block_q, block_k, emit_lse):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -265,22 +488,18 @@ def _flash_forward(q, k, v, causal, block_q, block_k, emit_lse):
         block_q=block_q,
         block_k=block_k,
         num_k_blocks=num_k,
-        causal=causal,
+        mask=mask,
         scale=d**-0.5,
         emit_lse=emit_lse,
     )
 
     def kv_index(b_, i, j):
-        # Clamp past-diagonal steps to the last relevant tile: an unchanged
+        # Skipped steps address a run tile that is resident: an unchanged
         # block index between consecutive grid steps skips the DMA.
-        return (b_, _last_kj_clamped(i, j), 0)
-
-    def _last_kj_clamped(i, j):
-        return (
-            jnp.minimum(j, _last_kj(i, block_q, block_k, num_k, causal))
-            if causal
-            else j
-        )
+        if _unmasked(mask):
+            return (b_, j, 0)
+        at = _resident_k(j, _k_segments(mask, i, block_q, block_k, num_k))
+        return (b_, jnp.maximum(at, 0), 0)
 
     out_specs = [
         pl.BlockSpec(
@@ -322,7 +541,7 @@ def _flash_forward(q, k, v, causal, block_q, block_k, emit_lse):
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         interpret=_interpret(),
-        name="flash_fwd",
+        name=_kernel_name(mask, "flash_fwd"),
     )(
         q.reshape(bh, s, d), k.reshape(bh, s, d), v.reshape(bh, s, d)
     )
@@ -339,14 +558,12 @@ def _flash_forward(q, k, v, causal, block_q, block_k, emit_lse):
 def _bwd_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
     dq_scr, dk_scr, dv_scr,
-    *, block_q, block_k, num_q_blocks, num_k_blocks, causal, scale,
+    *, block_q, block_k, num_q_blocks, num_k_blocks, mask, scale,
 ):
     from jax.experimental import pallas as pl
 
     j = pl.program_id(1)  # k block
     i = pl.program_id(2)  # q block (fastest)
-    first_i = _first_qi(j, block_q, block_k, causal)
-
     # dq's sum runs over j, the outer axis: the whole [S, D] row of this
     # batch*head stays in VMEM from the row's first grid step to its last.
     @pl.when((j == 0) & (i == 0))
@@ -358,7 +575,7 @@ def _bwd_kernel(
         dk_scr[:] = jnp.zeros(dk_scr.shape, jnp.float32)
         dv_scr[:] = jnp.zeros(dv_scr.shape, jnp.float32)
 
-    def accumulate(masked):
+    def accumulate(mask_scores):
         q = q_ref[:].astype(jnp.float32)
         k = k_ref[:].astype(jnp.float32)
         v = v_ref[:].astype(jnp.float32)
@@ -366,8 +583,8 @@ def _bwd_kernel(
         lse = lse_ref[:, :1]  # [block_q, 1]
         delta = delta_ref[:, :1]
         scores = scale * jnp.dot(q, k.T, preferred_element_type=jnp.float32)
-        if masked:
-            scores = _causal_mask_scores(scores, i, j, block_q, block_k)
+        if mask_scores is not None:
+            scores = mask_scores(scores)
         p = jnp.exp(scores - lse)  # [block_q, block_k]
         dv_scr[:] = dv_scr[:] + jnp.dot(
             p.T, do, preferred_element_type=jnp.float32
@@ -382,12 +599,10 @@ def _bwd_kernel(
             ds, k, preferred_element_type=jnp.float32
         )
 
-    # q tiles strictly above the diagonal see none of this k tile: skip.
-    # (The q-side index maps clamp to first_i, so skipped steps cost no DMA.)
+    # q tiles that see none of this k tile: skip. (The q-side index maps
+    # follow the run set, so skipped steps cost no DMA.)
     _accumulate_by_kind(
-        accumulate, causal, i >= first_i,
-        _below_diagonal(i, j, block_q, block_k),
-    )
+        accumulate, _tile_kinds(mask, i, j, block_q, block_k))
 
     @pl.when(i == num_q_blocks - 1)
     def _finalize():
@@ -415,7 +630,7 @@ def _bwd_vmem_bytes(s, d, block_q, block_k, itemsize):
     return tiles + blocks + dq_row + scratch
 
 
-def _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k):
+def _flash_backward(q, k, v, out, lse, g, mask, block_q, block_k):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -443,12 +658,13 @@ def _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k):
     )
     delta_fat = jnp.broadcast_to(delta[:, :, None], (bh, s, LANES))
 
-    # Grid (bh, k, q): k-indexed tiles are major, q-indexed minor. Steps
-    # above the diagonal re-address the first relevant q tile.
+    # Grid (bh, k, q): k-indexed tiles are major, q-indexed minor. Skipped
+    # steps address the next q tile that runs.
     def q_index(b_, j, i):
-        return (
-            b_, jnp.maximum(i, _first_qi(j, block_q, block_k, causal)), 0
-        )
+        if _unmasked(mask):
+            return (b_, i, 0)
+        segments = _q_segments(mask, j, block_q, block_k, num_q)
+        return (b_, _resident_q(i, segments, num_q), 0)
 
     def q_spec(width):
         return pl.BlockSpec(
@@ -466,7 +682,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k):
             block_k=block_k,
             num_q_blocks=num_q,
             num_k_blocks=num_k,
-            causal=causal,
+            mask=mask,
             scale=d**-0.5,
         ),
         grid=(bh, num_k, num_q),
@@ -495,7 +711,7 @@ def _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k):
         ],
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_bytes),
         interpret=_interpret(),
-        name="flash_bwd",
+        name=_kernel_name(mask, "flash_bwd"),
     )(q3, k3, v3, g3, lse_fat, delta_fat)
 
     return (
@@ -508,20 +724,27 @@ def _flash_backward(q, k, v, out, lse, g, causal, block_q, block_k):
 # ---------- public API with custom VJP ----------
 
 
+def _kernel_name(mask, name):
+    """The causal and unmasked calls keep their names; a call under block
+    diffusion carries its own, so a trace tells them apart."""
+    return f"bd_{name}" if isinstance(mask, BlockDiffusion) else name
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def flash_attention(
-    q, k, v, causal=False, block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K
+    q, k, v, mask=False, block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K
 ):
-    """Attention over [B, H, S, D]; where the kernel runs, S must be a
-    multiple of the (clamped) block sizes (ValueError otherwise)."""
-    bq, bk = _clamp_blocks(q.shape[2], block_q, block_k)
-    if _pallas_ok(q.shape[2], bq, bk):
+    """Attention over [B, H, S, D] under `mask`: False, True (causal) or a
+    `BlockDiffusion`. Where the kernel runs, S must be a multiple of the
+    (clamped) block sizes (ValueError otherwise)."""
+    bq, bk = _clamp_blocks(q.shape[2], block_q, block_k, mask)
+    if _pallas_ok(q.shape[2], bq, bk, mask):
         return _per_batch_shard(
             lambda q, k, v: _flash_forward(
-                q, k, v, causal, bq, bk, emit_lse=False
+                q, k, v, mask, bq, bk, emit_lse=False
             )[0]
         )(q, k, v)
-    return _fallback_attention(q, k, v, causal)
+    return _fallback_attention(q, k, v, mask)
 
 
 def _fit_block(s, requested):
@@ -534,16 +757,20 @@ def _fit_block(s, requested):
     return b
 
 
-def _clamp_blocks(s, block_q, block_k):
+def _clamp_blocks(s, block_q, block_k, mask=False):
+    """Under block diffusion a tile lies inside one half."""
+    if isinstance(mask, BlockDiffusion):
+        s = mask.half
     return _fit_block(s, block_q), _fit_block(s, block_k)
 
 
-def _pallas_ok(s, block_q, block_k):
+def _pallas_ok(s, block_q, block_k, mask=False):
     """True: run the kernel. False: this backend has no kernel (see
     _use_pallas). A sequence the kernel cannot tile RAISES where the
     kernel is in use — on the chip nothing drops to the O(S^2) path in
     silence."""
     if not _use_pallas():
+        _check_mask(mask, s)
         return False
     if s % block_q or s % block_k:
         raise ValueError(
@@ -552,45 +779,46 @@ def _pallas_ok(s, block_q, block_k):
             "multiple of 128 (the kernel never falls back to full-matrix "
             "attention on the TPU)"
         )
+    _check_mask(mask, s, block_q, block_k)
     return True
 
 
-def _fwd(q, k, v, causal, block_q, block_k):
-    bq, bk = _clamp_blocks(q.shape[2], block_q, block_k)
-    if _pallas_ok(q.shape[2], bq, bk):
+def _fwd(q, k, v, mask, block_q, block_k):
+    bq, bk = _clamp_blocks(q.shape[2], block_q, block_k, mask)
+    if _pallas_ok(q.shape[2], bq, bk, mask):
         out, lse = _per_batch_shard(
             lambda q, k, v: _flash_forward(
-                q, k, v, causal, bq, bk, emit_lse=True
+                q, k, v, mask, bq, bk, emit_lse=True
             )
         )(q, k, v)
         return out, (q, k, v, out, lse)
-    out = _fallback_attention(q, k, v, causal)
+    out = _fallback_attention(q, k, v, mask)
     return out, (q, k, v, out, None)
 
 
-def _bwd(causal, block_q, block_k, residuals, g):
+def _bwd(mask, block_q, block_k, residuals, g):
     q, k, v, out, lse = residuals
-    bq, bk = _clamp_blocks(q.shape[2], block_q, block_k)
+    bq, bk = _clamp_blocks(q.shape[2], block_q, block_k, mask)
     if lse is not None:
         return _per_batch_shard(
-            lambda *a: _flash_backward(*a, causal, bq, bk)
+            lambda *a: _flash_backward(*a, mask, bq, bk)
         )(q, k, v, out, lse, g)
-    return _bwd_xla(q, k, v, out, g, causal)
+    return _bwd_xla(q, k, v, out, g, mask)
 
 
-def _fallback_attention(q, k, v, causal):
+def _fallback_attention(q, k, v, mask):
     """`reference_attention` as the kernel keeps its promise: whatever
     dtype crosses the boundary, scores and softmax run in float32 and the
     result is rounded to the operands' dtype once. (Kept below the kernels:
     jax 0.9.0 keys a compiled Mosaic call on the line numbers of its call
     stack.)"""
     out = reference_attention(
-        *(x.astype(jnp.float32) for x in (q, k, v)), causal
+        *(x.astype(jnp.float32) for x in (q, k, v)), mask
     )
     return out.astype(q.dtype)
 
 
-def _bwd_xla(q, k, v, out, g, causal):
+def _bwd_xla(q, k, v, out, g, mask):
     """Full-matrix XLA backward (backends without the kernel): scores recomputed,
     then dV = P^T g;  dP = g V^T;  dS = P * (dP - rowsum(g * out));
     dQ = dS K * scale;  dK = dS^T Q * scale. In float32 whatever the
@@ -599,10 +827,9 @@ def _bwd_xla(q, k, v, out, g, causal):
     q, k, v, out, g = (x.astype(jnp.float32) for x in (q, k, v, out, g))
     scale = q.shape[-1] ** -0.5
     scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
-    if causal:
-        s_q, s_k = scores.shape[-2], scores.shape[-1]
-        mask = jnp.tril(jnp.ones((s_q, s_k), bool), k=s_k - s_q)
-        scores = jnp.where(mask, scores, NEG_INF)
+    seen = dense_mask(mask, scores.shape[-2], scores.shape[-1])
+    if seen is not None:
+        scores = jnp.where(seen, scores, NEG_INF)
     lse = jax.nn.logsumexp(scores, axis=-1)
     p = jnp.exp(scores - lse[..., None])
     dv = jnp.einsum("bhqk,bhqd->bhkd", p, g)

@@ -24,7 +24,7 @@ def ulysses_attention(q, k, v, axis_name, attention_fn=None, causal=False):
         # Flash attention by default: the whole point of the re-shard is
         # attending over S_global, and a full score matrix there is the
         # quadratic memory this path exists to avoid.
-        attention_fn = functools.partial(flash_attention, causal=causal)
+        attention_fn = functools.partial(flash_attention, mask=causal)
     axis_size = jax.lax.psum(1, axis_name)
     h = q.shape[1]
     if h % axis_size:

@@ -43,7 +43,7 @@ def test_ring_attention_matches_full(qkv, seq_mesh, causal):
     sharding = NamedSharding(seq_mesh, P(None, None, "seq", None))
     args = [jax.device_put(x, sharding) for x in (q, k, v)]
     out = ring(*args)
-    ref = reference_attention(q, k, v, causal=causal)
+    ref = reference_attention(q, k, v, mask=causal)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=5e-3)
 
 
@@ -56,7 +56,7 @@ def test_ulysses_attention_matches_full(qkv, seq_mesh, causal):
     sharding = NamedSharding(seq_mesh, P(None, None, "seq", None))
     args = [jax.device_put(x, sharding) for x in (q, k, v)]
     out = ulysses(*args)
-    ref = reference_attention(q, k, v, causal=causal)
+    ref = reference_attention(q, k, v, mask=causal)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=5e-3)
 
 
@@ -70,7 +70,7 @@ def test_ring_attention_gradients(qkv, seq_mesh):
         return jnp.sum(ring(q, k, v) ** 2)
 
     def loss_ref(q, k, v):
-        return jnp.sum(reference_attention(q, k, v, causal=True) ** 2)
+        return jnp.sum(reference_attention(q, k, v, mask=True) ** 2)
 
     g_ring = jax.jit(jax.grad(loss_ring, argnums=(0, 1, 2)))(q, k, v)
     g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
@@ -92,7 +92,7 @@ def test_zigzag_ring_attention_matches_full(qkv, seq_mesh):
     sharding = NamedSharding(seq_mesh, P(None, None, "seq", None))
     args = [jax.device_put(x, sharding) for x in (q, k, v)]
     out = zz(*args)
-    ref = reference_attention(q, k, v, causal=True)
+    ref = reference_attention(q, k, v, mask=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=5e-3)
 
 
@@ -108,7 +108,7 @@ def test_zigzag_ring_attention_gradients(qkv, seq_mesh):
         return jnp.sum(zz(q, k, v) ** 2)
 
     def loss_ref(q, k, v):
-        return jnp.sum(reference_attention(q, k, v, causal=True) ** 2)
+        return jnp.sum(reference_attention(q, k, v, mask=True) ** 2)
 
     g_zz = jax.jit(jax.grad(loss_zz, argnums=(0, 1, 2)))(q, k, v)
     g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
@@ -125,7 +125,7 @@ def test_flash_attention_kernel_interpret(qkv, monkeypatch):
     q, k, v = qkv
     for causal in (False, True):
         out = flash_attention(q, k, v, causal, 128, 128)
-        ref = reference_attention(q, k, v, causal=causal)
+        ref = reference_attention(q, k, v, mask=causal)
         np.testing.assert_allclose(
             np.asarray(out), np.asarray(ref), atol=5e-3
         )
@@ -169,7 +169,7 @@ def test_flash_attention_gradients(
         return jnp.sum(out.astype(jnp.float32) ** 2)
 
     def loss_ref(q, k, v):
-        return jnp.sum(reference_attention(q, k, v, causal=causal) ** 2)
+        return jnp.sum(reference_attention(q, k, v, mask=causal) ** 2)
 
     gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
     gr = jax.grad(loss_ref, argnums=(0, 1, 2))(
@@ -199,7 +199,7 @@ def test_flash_kernel_bfloat16_output_is_one_rounding(qkv, monkeypatch, causal):
     out = flash_attention(q, k, v, causal, 128, 128)
     assert out.dtype == jnp.bfloat16
     ref = reference_attention(
-        *(x.astype(jnp.float32) for x in (q, k, v)), causal=causal
+        *(x.astype(jnp.float32) for x in (q, k, v)), mask=causal
     )
     out, ref = np.asarray(out.astype(jnp.float32)), np.asarray(ref)
     assert (np.abs(out - ref) <= BF16_EPS * np.abs(ref) + 1e-6).all()
@@ -224,7 +224,7 @@ def test_fallback_runs_its_softmax_in_float32(qkv, causal):
         np.asarray(out32.astype(jnp.bfloat16).astype(jnp.float32)),
     )
     # Scores in bfloat16 would not have come this close.
-    plain = reference_attention(q, k, v, causal=causal)
+    plain = reference_attention(q, k, v, mask=causal)
     assert plain.dtype == jnp.bfloat16
     assert not np.array_equal(
         np.asarray(plain.astype(jnp.float32)),
@@ -257,14 +257,14 @@ def _masked_whole(scores, i, j, block_q, block_k):
 
 def _oracle_fwd_kernel(
     q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-    *, block_q, block_k, num_k_blocks, causal, scale, emit_lse,
+    *, block_q, block_k, num_k_blocks, mask, scale, emit_lse,
 ):
     from jax.experimental import pallas as pl
 
-    assert causal and emit_lse
+    assert mask is True and emit_lse
     i = pl.program_id(1)
     j = pl.program_id(2)
-    last_j = fa._last_kj(i, block_q, block_k, num_k_blocks, causal)
+    last_j = fa._last_kj(i, block_q, block_k, num_k_blocks, mask)
 
     @pl.when(j == 0)
     def _init():
@@ -303,14 +303,14 @@ def _oracle_fwd_kernel(
 def _oracle_bwd_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
     dq_scr, dk_scr, dv_scr,
-    *, block_q, block_k, num_q_blocks, num_k_blocks, causal, scale,
+    *, block_q, block_k, num_q_blocks, num_k_blocks, mask, scale,
 ):
     from jax.experimental import pallas as pl
 
-    assert causal
+    assert mask is True
     j = pl.program_id(1)
     i = pl.program_id(2)
-    first_i = fa._first_qi(j, block_q, block_k, causal)
+    first_i = fa._first_qi(j, block_q, block_k, mask)
 
     @pl.when((j == 0) & (i == 0))
     def _init_row():

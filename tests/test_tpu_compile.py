@@ -198,10 +198,13 @@ def test_overlong_dq_row_raises_where_the_kernel_runs(kernel_on):
     assert jax.eval_shape(jax.grad(loss), q).shape == q.shape
 
 
-def _plan_step(m, rows_a_chip, seq, topo, monkeypatch, n_devices=1):
+def _plan_step(m, rows_a_chip, seq, topo, monkeypatch, n_devices=1,
+               batch_of=None):
     """The training step of AllReduceTrainer for model-def module `m` as
     the speculator plans it for the first `n_devices` described chips:
-    (trainer, step, abstract args, mesh). The caller closes the trainer."""
+    (trainer, step, abstract args, mesh). The caller closes the trainer.
+    `batch_of(batch, seq)` gives the abstract (features, labels) where
+    they are not token rows."""
     from elasticdl_tpu.parallel.mesh import WorldTopology, resolve_world_spec
     from elasticdl_tpu.worker.allreduce_trainer import AllReduceTrainer
 
@@ -214,6 +217,8 @@ def _plan_step(m, rows_a_chip, seq, topo, monkeypatch, n_devices=1):
     )
     try:
         tokens = jax.ShapeDtypeStruct((batch, seq), jnp.int32)
+        features, labels = (
+            batch_of(batch, seq) if batch_of else (tokens, tokens))
         rng = jax.random.PRNGKey(0)
         variables = jax.eval_shape(
             lambda r, f: dict(
@@ -221,14 +226,14 @@ def _plan_step(m, rows_a_chip, seq, topo, monkeypatch, n_devices=1):
                     {"params": r, "dropout": r}, f, training=False
                 )
             ),
-            rng, tokens,
+            rng, features,
         )
         trainer._variables = variables
         trainer._opt_state = jax.eval_shape(
             trainer._optax.init, variables["params"]
         )
         trainer._step_rng_base = rng
-        trainer._note_batch_abstract(tokens, tokens, batch)
+        trainer._note_batch_abstract(features, labels, batch)
         # The trainer builds its mesh from jax.devices(): hand it the
         # described chips.
         devices = list(topo.devices)[:n_devices]
@@ -335,6 +340,23 @@ def lfm2_cut_one_chip(topo, no_persistent_compile_cache_in_module):
     from elasticdl_tpu.models.lfm2 import lfm2_24b_a2b_cut as m
 
     return _compile_planned(lambda mp: _plan_step(m, 2, 8192, topo, mp))
+
+
+def _block_diffusion_batch(batch, seq):
+    """SDAR's features and labels: a record's clean and noised copies;
+    its targets and weights."""
+    ids = jax.ShapeDtypeStruct((batch, seq), jnp.int32)
+    weights = jax.ShapeDtypeStruct((batch, seq), jnp.float32)
+    return ({"tokens": ids, "noised": ids},
+            {"targets": ids, "weights": weights})
+
+
+@pytest.fixture(scope="module")
+def sdar_cut_one_chip(topo, no_persistent_compile_cache_in_module):
+    from elasticdl_tpu.models.sdar import sdar_30b_a3b_cut as m
+
+    return _compile_planned(lambda mp: _plan_step(
+        m, 1, 8192, topo, mp, batch_of=_block_diffusion_batch))
 
 
 def test_flagship_step_compiles_and_fits_one_v5e(flagship_one_chip):
@@ -617,3 +639,34 @@ def test_lfm2_cut_step_compiles_and_fits_one_v5e(lfm2_cut_one_chip):
     scatters = _scatter_results(step.text)
     assert scatters.count("f32[16384,16,128]") == 12, scatters
     assert "f32[16384,2048]" not in scatters
+
+
+def test_sdar_cut_step_compiles_and_fits_one_v5e(sdar_cut_one_chip):
+    """The WHOLE training step of the SDAR-30B-A3B cut (645.6 M parameters
+    at 16 bytes each, minibatch 1 x 8192 record tokens = 16,384 rows, as
+    `edl train` runs `sdar_30b_a3b_cut`) for one described chip: the flash
+    kernels under the block-diffusion mask at `[32, 16384, 128]`, handed
+    the activation dtype and carrying their own names; the gated grouped
+    product over 16 held experts; the untied head over the noised half
+    alone; it fits 16 GB with the remat the model-def states, and hands
+    ten counters back beside the loss."""
+    step = sdar_cut_one_chip
+    assert step.out_tree.children()[2].num_leaves == 11
+    calls = _kernel_calls(step.text)
+    # Six layers: bd_flash_fwd (and a rematerialised twin), bd_flash_bwd.
+    assert 12 <= len(calls) <= 18
+    for results, operands in calls:
+        assert results.startswith("(bf16[32,16384,128], "), results
+        assert set(operands) <= {"bf16[32,16384,128]", "f32[32,16384,128]"}
+    assert step.text.count("bd_flash_fwd") >= 6
+    assert step.text.count("bd_flash_bwd") >= 6
+    assert step.resident < HBM_BYTES, f"{step.resident / 2**30:.2f} GiB"
+    # params + Adam m and v
+    assert step.argument_bytes > 7.7e9
+    assert {"f32[16,2048,1536]", "f32[16,768,2048]", "f32[128,2048]",
+            "f32[2048,32,128]", "f32[2048,4,128]", "f32[4096,2048]",
+            "f32[18992,2048]", "f32[2048,18992]"} <= step.weights
+    # The head runs over the noised half: 8192 rows of logits, not 16,384.
+    assert "tensor<1x8192x18992xf32>" in step.lowered
+    assert "tensor<1x16384x18992xf32>" not in step.lowered
+    print(f"sdar cut: resident {step.resident / 2**30:.2f} GiB")
