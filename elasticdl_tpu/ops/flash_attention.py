@@ -8,20 +8,21 @@ attention ops; SURVEY.md §5 marks long-context as absent upstream) — this is
 a capability extension required for long-context training.
 
 Design: the standard blockwise online-softmax scheme over a
-(batch*heads, q_blocks, k_blocks) grid. K/V stream through VMEM one
-[block_k, D] tile at a time (the k index is the minormost grid axis, so
-consecutive steps revisit the same q/output block while new K/V tiles DMA
-in), running (max, sum, acc) live in VMEM scratch, and the S x S score
+(batch*heads, run tiles) grid: the second axis enumerates the
+[block_q, block_k] tiles the mask lets run and no other, by rows in the
+forward. K/V stream through VMEM one [block_k, D] tile a step (within a
+row consecutive steps revisit the same q/output block while new K/V tiles
+DMA in), running (max, sum, acc) live in VMEM scratch, and the S x S score
 matrix never materializes — in EITHER pass:
 
 - forward emits the per-row log-sum-exp as a residual, lane-replicated to
   [bh, S, 128] (the (8,128) tiling makes a plain 1-D row vector an illegal
   block; lane replication is the canonical TPU layout for row stats, cf.
   jax.experimental.pallas.ops.tpu.flash_attention's MIN_BLOCK_SIZE scratch).
-- backward is one streaming kernel over (bh, k_blocks, q_blocks): each
+- backward is one streaming kernel over the run tiles by columns: each
   [block_q, block_k] tile of P is rebuilt once from the saved lse and
   feeds dv, dk AND dq (five products a tile). dk/dv of a k tile finish
-  within its q loop; dq's sum runs over the outer k axis, so one
+  within its column; dq's sum runs over the columns, so one
   batch*head's [S, D] float32 row of dq stays in VMEM for the row's grid
   steps and is written back once. Backward memory is O(S) + tiles, not
   O(S^2); the call asks for its VMEM (`_bwd_vmem_bytes`), and a row
@@ -33,25 +34,36 @@ The mask is a hashable description handed in where the scores would be
 masked: `False` (every position sees every other), `True` (causal) or
 `BlockDiffusion(block, half)` (a clean and a noised copy of a record as
 one sequence of 2 * half rows, see the class). One function,
-`_tile_kinds`, tells a tile's kind at its grid step from the description
-and `(i, j, block_q, block_k)`: skipped (pl.when; its index maps
-re-address a resident tile, so no FLOPs and no DMA); whole, accumulated by
-a body with no mask at all (every position is seen, and a select whose
-predicate is all true returns its input); or crossed, masked. Under the
-causal mask a crossed tile is crossed by the diagonal
-(`causal_tile_kinds` counts the kinds from the shapes); with equal blocks
-it lies on the diagonal itself and its mask is a constant of the trace,
-which lets the compiler drop the score blocks above the diagonal from the
-q k^T product. Under block diffusion a tile is crossed in one of three
-ways (clean rows on their own tile of clean columns, noised rows on it,
-noised rows on their own tile of noised columns:
-`block_diffusion_tile_kinds`), each with equal blocks a constant too, and
-a noised row's run set is not contiguous (`{0..i}` and `n + i`): the
-index maps and the loops' ends follow the run set's segments
-(`_k_segments`, `_q_segments`). Every causal kind gives the bits of
-masking every tile whole, forward and backward. Under ring/Ulysses
-sequence parallelism (parallel/ring_attention.py) the per-device S is the
-block, so VMEM bounds the per-shard sequence, not the global one.
+`_tile_kinds`, tells a tile's kind from the description and the plain
+ints `(i, j, block_q, block_k)`: not run; whole, accumulated by a body
+with no mask at all (every position is seen, and a select whose predicate
+is all true returns its input); or crossed, masked. At trace time
+`_run_tiles` lists the tiles that run, in the order a pass takes them (by
+rows forward, by columns backward: the orders the sums have always been
+made in), each with its kind; the list's length is the grid's second axis
+(`grid_steps`: 10 / 36 / 136 a batch*head at S 4096 / 8192 / 16384 under
+the causal mask, 80 under `BlockDiffusion(4, 8192)`, over 1024 x 1024
+tiles), so no grid step is skipped and a row's (column's) last run tile
+is followed at once by the next one's first, its blocks fetched behind
+it. Index maps and kernel bodies read the step's `(i, j)`, its kind and
+whether it starts or ends a row off that one list, by arithmetic
+(`_step_value`: a constant plus each change the step has reached;
+`_at_any`; a division where every tile of the grid runs, as under
+`False`), not through a table operand: the calls stay q, k, v -> o, lse
+and q, k, v, dO, lse, delta -> dq, dk, dv. Under the causal mask a
+crossed tile is crossed by the diagonal (`causal_tile_kinds` counts the
+kinds from the shapes); with equal blocks it lies on the diagonal itself
+and its mask is a constant of the trace, which lets the compiler drop the
+score blocks above the diagonal from the q k^T product. Under block
+diffusion a tile is crossed in one of three ways (clean rows on their own
+tile of clean columns, noised rows on it, noised rows on their own tile
+of noised columns: `block_diffusion_tile_kinds`), each with equal blocks
+a constant too, and a noised row's run set is not contiguous (`{0..i}`
+and `n + i`): the list holds it as it is. Every causal kind gives the
+bits of masking every tile whole, forward and backward. Under
+ring/Ulysses sequence parallelism (parallel/ring_attention.py) the
+per-device S is the block, so VMEM bounds the per-shard sequence, not the
+global one.
 """
 
 import functools
@@ -60,6 +72,8 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax import lax
 
 # Block-size sweep on TPU v5e (S=4096, bf16, causal fwd+bwd, D=64):
 # 1024x1024 tiles run 5.49 ms/step vs 5.93 (512x512) and 6.76 (256x256),
@@ -195,22 +209,6 @@ def reference_attention(q, k, v, mask=False):
 # ---------- shared tile helpers ----------
 
 
-def _last_kj(i, block_q, block_k, num_k_blocks, causal):
-    """Index of the last k tile the i-th q tile attends to."""
-    if not causal:
-        return num_k_blocks - 1
-    return jnp.minimum(
-        (((i + 1) * block_q - 1) // block_k), num_k_blocks - 1
-    )
-
-
-def _first_qi(j, block_q, block_k, causal):
-    """Index of the first q tile that sees the j-th k tile."""
-    if not causal:
-        return 0
-    return (j * block_k) // block_q
-
-
 def _below_diagonal(i, j, block_q, block_k):
     """Tile (i, j) lies wholly below the causal diagonal: its last k
     position is seen by its first q row, so no score of it is masked."""
@@ -218,8 +216,8 @@ def _below_diagonal(i, j, block_q, block_k):
 
 
 def causal_tile_kinds(s, block_q, block_k):
-    """(run, below, crossed) tiles of one batch*head's causal grid: how
-    many are not skipped, how many of those take the unmasked body and how
+    """(run, below, crossed) tiles of one batch*head under the causal
+    mask: how many run, how many of those take the unmasked body and how
     many the masked one. A function of the shapes alone (10 / 6 / 4 at
     S 4096 and 36 / 28 / 8 at S 8192 over 1024 x 1024 tiles)."""
     run, whole, crossed = _count_tile_kinds(True, s, block_q, block_k)
@@ -248,15 +246,18 @@ def block_diffusion_scores(half, block, block_q=DEFAULT_BLOCK_Q,
     return half * (half + block), run * bq * bk
 
 
+def grid_steps(mask, s, block_q, block_k):
+    """Steps a batch*head of either pass's grid: both enumerate the tiles
+    that run under the mask's description, and nothing else (10 / 36 / 136
+    at S 4096 / 8192 / 16384 causal, 80 under `BlockDiffusion(4, 8192)`,
+    over 1024 x 1024 tiles). A function of the shapes alone."""
+    return len(_run_tiles(mask, s, block_q, block_k).major)
+
+
 def _count_tile_kinds(mask, s, block_q, block_k):
-    kinds = [
-        _tile_kinds(mask, i, j, block_q, block_k)
-        for i in range(s // block_q) for j in range(s // block_k)]
-    whole = sum(bool(is_whole) for is_whole, _ in kinds)
-    crossed = tuple(
-        sum(bool(hit) for hit, _ in way)
-        for way in zip(*(ways for _, ways in kinds)))
-    return whole + sum(crossed), whole, crossed
+    run = _run_tiles(mask, s, block_q, block_k)
+    crossed = tuple(len(steps) for steps in run.ways)
+    return len(run.major), len(run.major) - sum(crossed), crossed
 
 
 def _causal_mask_scores(scores, i, j, block_q, block_k):
@@ -273,16 +274,18 @@ def _causal_mask_scores(scores, i, j, block_q, block_k):
     return jnp.where(row >= col, scores, NEG_INF)
 
 
-def _block_mask_scores(scores, sees, block, p0, s0, block_q, block_k):
-    """Mask a crossed score tile under block diffusion: a row sees a
-    column iff `sees(column's block, row's block)`. `p0`, `s0` are the
-    positions of the tile's first row and column; with equal tiles a
-    crossed tile has them equal (a multiple of `block`), and the mask is
-    a constant of the trace as the causal diagonal's is."""
+def _block_mask_scores(scores, sees, mask, i, j, block_q, block_k):
+    """Mask the crossed score tile (i, j) under block diffusion: a row sees
+    a column iff `sees(column's block, row's block)`. With equal tiles a
+    crossed tile's first row and first column are the same position (a
+    multiple of `mask.block`), and the mask is a constant of the trace as
+    the causal diagonal's is."""
     row = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 0)
     col = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
     if block_q != block_k:
-        row, col = row + p0, col + s0
+        row = row + _half_tile(mask, i, block_q)[1]
+        col = col + _half_tile(mask, j, block_k)[1]
+    block = mask.block
     if block & (block - 1) == 0:
         shift = block.bit_length() - 1
         row = jax.lax.shift_right_logical(row, shift)
@@ -301,118 +304,159 @@ def _half_tile(mask, t, tile):
     return noised, first, last, first // mask.block, last // mask.block
 
 
+# A row's block r sees a column's block c, by the halves they lie in: clean
+# rows on clean columns, noised rows on clean, noised on noised.
+_BLOCK_SEES = (
+    lambda c, r: c <= r,
+    lambda c, r: c < r,
+    lambda c, r: c == r,
+)
+
+
+def _way_masks(mask, i, j, block_q, block_k):
+    """A `mask_scores(scores)` for each way a tile can be crossed under the
+    mask's description, for tile (i, j): traced (a grid step's tile, which
+    the masks read with unequal blocks alone) or plain ints."""
+    if _unmasked(mask):
+        return ()
+    if not isinstance(mask, BlockDiffusion):
+        return (lambda s: _causal_mask_scores(s, i, j, block_q, block_k),)
+    return tuple(
+        functools.partial(
+            _block_mask_scores, sees=sees, mask=mask, i=i, j=j,
+            block_q=block_q, block_k=block_k)
+        for sees in _BLOCK_SEES)
+
+
 def _tile_kinds(mask, i, j, block_q, block_k):
-    """The kind of tile (i, j) under the mask's description, as (whole,
-    crossed): `whole` says that the tile runs and none of its scores is
-    masked; `crossed` is a tuple of (hit, mask_scores) pairs, one a way of
-    being crossed, `hit` saying that the tile runs masked that way and
-    `mask_scores(scores)` masking it. A tile that is neither is skipped.
-    i, j are grid indices (traced) or plain ints (the counts)."""
+    """The kind of tile (i, j), plain ints, under the mask's description,
+    as (whole, crossed): `whole` says that the tile runs and none of its
+    scores is masked; `crossed` holds one truth value a way of being
+    crossed (`_way_masks`), saying that the tile runs masked that way. A
+    tile that is neither does not run."""
     if _unmasked(mask):
         return True, ()
     if not isinstance(mask, BlockDiffusion):
         # Crossed: not above the diagonal, not below it.
-        hit = (j * block_k <= (i + 1) * block_q - 1) & (
+        hit = (j * block_k <= (i + 1) * block_q - 1) and (
             (j + 1) * block_k - 1 > i * block_q)
-        return _below_diagonal(i, j, block_q, block_k), ((
-            hit,
-            lambda s: _causal_mask_scores(s, i, j, block_q, block_k)),)
-    r_noised, p0, _, rb0, rb1 = _half_tile(mask, i, block_q)
-    c_noised, s0, _, cb0, cb1 = _half_tile(mask, j, block_k)
-    # (which halves, some score seen, every score seen, the relation).
+        return _below_diagonal(i, j, block_q, block_k), (hit,)
+    r_noised, _, _, rb0, rb1 = _half_tile(mask, i, block_q)
+    c_noised, _, _, cb0, cb1 = _half_tile(mask, j, block_k)
+    # (which halves, some score seen, every score seen), a way.
     ways = (
-        ((r_noised == 0) & (c_noised == 0), cb0 <= rb1, cb1 <= rb0,
-         lambda c, r: c <= r),
-        ((r_noised == 1) & (c_noised == 0), cb0 < rb1, cb1 < rb0,
-         lambda c, r: c < r),
-        ((r_noised == 1) & (c_noised == 1),
-         (cb0 <= rb1) & (rb0 <= cb1), (cb0 >= rb1) & (cb1 <= rb0),
-         lambda c, r: c == r),
+        (r_noised == 0 and c_noised == 0, cb0 <= rb1, cb1 <= rb0),
+        (r_noised == 1 and c_noised == 0, cb0 < rb1, cb1 < rb0),
+        (r_noised == 1 and c_noised == 1,
+         cb0 <= rb1 and rb0 <= cb1, cb0 >= rb1 and cb1 <= rb0),
     )
-    whole = False
-    crossed = []
-    for halves, some, every, sees in ways:
-        whole = whole | (halves & every)
-        crossed.append((
-            halves & some & (every == False),  # noqa: E712 (traced)
-            functools.partial(
-                _block_mask_scores, sees=sees, block=mask.block, p0=p0,
-                s0=s0, block_q=block_q, block_k=block_k)))
-    return whole, tuple(crossed)
-
-
-def _k_segments(mask, i, block_q, block_k, num_k):
-    """The k tiles that row tile i runs, as two segments ((first, last),
-    (first, last)) in rising order; a run set of one segment gives it
-    twice. Causal: 0 .. `_last_kj`. Block diffusion: a clean row runs the
-    clean columns up to its own tile; a noised row the clean columns
-    whose first block lies before its last one, then the noised tiles
-    that hold its own positions."""
-    if not isinstance(mask, BlockDiffusion):
-        only = (0, _last_kj(i, block_q, block_k, num_k, mask))
-        return only, only
-    noised, p0, p1, _, rb1 = _half_tile(mask, i, block_q)
-    n_half = mask.half // block_k
-    clean = (0, jnp.where(
-        noised == 1, ((rb1 - 1) * mask.block) // block_k, p1 // block_k))
-    own = (n_half + p0 // block_k, n_half + p1 // block_k)
-    return clean, tuple(
-        jnp.where(noised == 1, a, b) for a, b in zip(own, clean))
-
-
-def _q_segments(mask, j, block_q, block_k, num_q):
-    """The q tiles that column tile j is run by, as `_k_segments` gives
-    the k tiles of a row. Causal: `_first_qi` .. the last. Block
-    diffusion: a clean column is run by the clean rows from its own
-    position on and by the noised rows whose last block lies after its
-    first one; a noised column by the noised tiles that hold its own
-    positions."""
-    if not isinstance(mask, BlockDiffusion):
-        only = (_first_qi(j, block_q, block_k, mask), num_q - 1)
-        return only, only
-    noised, s0, s1, _, _ = _half_tile(mask, j, block_k)
-    n_half = mask.half // block_q
-    own = (n_half + s0 // block_q, n_half + s1 // block_q)
-    clean_rows = (s0 // block_q, n_half - 1)
-    noised_rows = (n_half + (s0 + mask.block) // block_q, num_q - 1)
     return (
-        tuple(jnp.where(noised == 1, a, b)
-              for a, b in zip(own, clean_rows)),
-        tuple(jnp.where(noised == 1, a, b)
-              for a, b in zip(own, noised_rows)),
+        any(halves and every for halves, _, every in ways),
+        tuple(halves and some and not every for halves, some, every in ways))
+
+
+class _Run(NamedTuple):
+    """The tiles a pass runs, one entry a grid step: plain ints of the
+    trace."""
+
+    major: tuple  # the step's row (by column: its column)
+    offset: tuple  # its k tile (by column: its q tile) less the step
+    ways: tuple  # for each way of being crossed, the steps crossed so
+    width: int  # where every tile of the grid runs, a row's tiles; else 0
+
+
+@functools.lru_cache(maxsize=None)
+def _run_tiles(mask, s, block_q, block_k, by_column=False):
+    """The tiles that run under the mask's description, in the order a
+    pass's grid takes them. The forward goes by rows: (i, j), row i rising
+    and within it its k tiles rising, the order of a row's online softmax.
+    The backward goes `by_column`: (j, i), column j rising and within it
+    its q tiles rising, the order dk, dv and dq's rows are summed in."""
+    num_q, num_k = s // block_q, s // block_k
+    tiles = []
+    for i in range(num_q):
+        for j in range(num_k):
+            whole, crossed = _tile_kinds(mask, i, j, block_q, block_k)
+            if whole or any(crossed):
+                tiles.append(((j, i) if by_column else (i, j)) + (crossed,))
+    tiles.sort()
+    ways = tuple(zip(*(crossed for _, _, crossed in tiles)))
+    return _Run(
+        major=tuple(major for major, _, _ in tiles),
+        offset=tuple(minor - n for n, (_, minor, _) in enumerate(tiles)),
+        ways=tuple(
+            tuple(n for n, hit in enumerate(way) if hit) for way in ways),
+        width=(num_q if by_column else num_k)
+        if len(tiles) == num_q * num_k else 0,
     )
 
 
-def _resident_k(j, segments):
-    """The k tile that forward step j addresses: itself where it runs,
-    else the run tile before it (already resident: no DMA), or the first
-    run tile of all."""
-    (f0, l0), (f1, l1) = segments
-    return jnp.where(j >= f1, jnp.minimum(j, l1), jnp.clip(j, f0, l0))
+def _step_value(t, values):
+    """`values[t]` at the traced grid step t, `values` plain ints of the
+    trace: the first of them plus every later change that t has reached.
+    Arithmetic over constants, so that neither an index map nor a kernel
+    needs a table operand (the benchmark knows the calls by their operand
+    counts). In `lax` primitives: a `jnp` operation on a tracer is a
+    nested jit to trace, and a step's kernels hold thousands of these."""
+    at = np.int32(values[0])
+    for n in range(1, len(values)):
+        if values[n] != values[n - 1]:
+            at = lax.add(at, lax.select(
+                lax.ge(t, np.int32(n)),
+                np.int32(values[n] - values[n - 1]), np.int32(0)))
+    return at
 
 
-def _resident_q(i, segments, num_q):
-    """The q tile that backward step i addresses: itself where it runs,
-    else the next run tile (fetched while the skipped steps pass), or the
-    last run tile of all."""
-    (f0, l0), (f1, l1) = segments
-    at = jnp.where(i <= l0, jnp.maximum(i, f0), jnp.clip(i, f1, l1))
-    # A clean column that no noised row runs has an empty second segment.
-    return jnp.minimum(at, num_q - 1)
+def _major_at(t, run):
+    """The row (by column: the column) of grid step t: it changes where a
+    row's run tiles end. Where every tile runs it is a division."""
+    if run.width:
+        return lax.div(t, np.int32(run.width))
+    return _step_value(t, run.major)
 
 
-def _accumulate_by_kind(accumulate, kinds):
-    """Call `accumulate(mask_scores)` as the tile's kind (`_tile_kinds`)
-    asks: with None where the tile is whole, with the way's own mask where
-    it is crossed, not at all where it is skipped."""
+def _minor_at(t, run):
+    """The k tile (by column: the q tile) of grid step t. Inside a segment
+    of a run set it rises with t, so it is t plus a value that changes
+    only where a segment starts."""
+    if run.width:
+        return lax.rem(t, np.int32(run.width))
+    return lax.add(t, _step_value(t, run.offset))
+
+
+def _at_any(t, steps):
+    return functools.reduce(
+        lax.bitwise_or, (lax.eq(t, np.int32(n)) for n in steps))
+
+
+def _ends_at(t, run):
+    """(first, last): whether grid step t is the first, the last run tile
+    of its row (by column: its column)."""
+    if run.width:
+        minor = _minor_at(t, run)
+        return (lax.eq(minor, np.int32(0)),
+                lax.eq(minor, np.int32(run.width - 1)))
+    majors = run.major
+    firsts = [n for n, m in enumerate(majors) if n == 0 or majors[n - 1] != m]
+    lasts = [n - 1 for n in firsts[1:]] + [len(majors) - 1]
+    return _at_any(t, firsts), _at_any(t, lasts)
+
+
+def _accumulate_by_kind(accumulate, t, ways, masks):
+    """Call `accumulate(mask_scores)` as the kind of grid step t's tile
+    asks: with the way's own mask (`_way_masks`) at the steps `ways` lists
+    for it, with None at every other step: the tile is whole there."""
     from jax.experimental import pallas as pl
 
-    whole, crossed = kinds
-    if whole is True:
+    hits = [
+        (_at_any(t, steps), mask_scores)
+        for steps, mask_scores in zip(ways, masks) if steps]
+    if not hits:
         accumulate(None)
         return
-    pl.when(whole)(lambda: accumulate(None))
-    for hit, mask_scores in crossed:
+    crossed = functools.reduce(lax.bitwise_or, (hit for hit, _ in hits))
+    pl.when(lax.bitwise_not(crossed))(lambda: accumulate(None))
+    for hit, mask_scores in hits:
         pl.when(hit)(functools.partial(accumulate, mask_scores))
 
 
@@ -421,7 +465,7 @@ def _accumulate_by_kind(accumulate, kinds):
 
 def _fwd_kernel(
     q_ref, k_ref, v_ref, *refs,
-    block_q, block_k, num_k_blocks, mask, scale, emit_lse,
+    block_q, block_k, run, mask, scale, emit_lse,
 ):
     from jax.experimental import pallas as pl
 
@@ -430,11 +474,11 @@ def _fwd_kernel(
     else:
         o_ref, m_scr, l_scr, acc_scr = refs
         lse_ref = None
-    i = pl.program_id(1)  # q block
-    j = pl.program_id(2)  # k block (minormost: iterates fastest)
-    last_j = _k_segments(mask, i, block_q, block_k, num_k_blocks)[1][1]
+    t = pl.program_id(1)  # the t-th run tile, by rows
+    i, j = _major_at(t, run), _minor_at(t, run)  # q block, k block
+    first, last = _ends_at(t, run)
 
-    @pl.when(j == 0)
+    @pl.when(first)
     def _init():
         m_scr[:] = jnp.full(m_scr.shape, NEG_INF, jnp.float32)
         l_scr[:] = jnp.zeros(l_scr.shape, jnp.float32)
@@ -459,13 +503,10 @@ def _fwd_kernel(
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
 
-    # Tiles no row of which sees a column contribute nothing: skip. (The
-    # k/v index maps follow the run set, so skipped steps re-address an
-    # already-resident tile and cost no DMA either.)
     _accumulate_by_kind(
-        accumulate, _tile_kinds(mask, i, j, block_q, block_k))
+        accumulate, t, run.ways, _way_masks(mask, i, j, block_q, block_k))
 
-    @pl.when(j == last_j)
+    @pl.when(last)
     def _finalize():
         m = m_scr[:, :1]
         l = l_scr[:, :1]
@@ -482,57 +523,41 @@ def _flash_forward(q, k, v, mask, block_q, block_k, emit_lse):
 
     b, h, s, d = q.shape
     bh = b * h
-    num_q, num_k = s // block_q, s // block_k
+    # The grid's second axis runs over the run tiles alone, by rows: a
+    # row's q, o and lse blocks stay while its k/v tiles stream.
+    run = _run_tiles(mask, s, block_q, block_k)
     kernel = functools.partial(
         _fwd_kernel,
         block_q=block_q,
         block_k=block_k,
-        num_k_blocks=num_k,
+        run=run,
         mask=mask,
         scale=d**-0.5,
         emit_lse=emit_lse,
     )
 
-    def kv_index(b_, i, j):
-        # Skipped steps address a run tile that is resident: an unchanged
-        # block index between consecutive grid steps skips the DMA.
-        if _unmasked(mask):
-            return (b_, j, 0)
-        at = _resident_k(j, _k_segments(mask, i, block_q, block_k, num_k))
-        return (b_, jnp.maximum(at, 0), 0)
-
-    out_specs = [
-        pl.BlockSpec(
-            (None, block_q, d), lambda b_, i, j: (b_, i, 0),
+    def q_spec(width):
+        return pl.BlockSpec(
+            (None, block_q, width),
+            lambda b_, t: (b_, _major_at(t, run), 0),
             memory_space=pltpu.VMEM,
-        ),
-    ]
+        )
+
+    k_spec = pl.BlockSpec(
+        (None, block_k, d), lambda b_, t: (b_, _minor_at(t, run), 0),
+        memory_space=pltpu.VMEM,
+    )
+    out_specs = [q_spec(d)]
     out_shape = [jax.ShapeDtypeStruct((bh, s, d), q.dtype)]
     if emit_lse:
-        out_specs.append(
-            pl.BlockSpec(
-                (None, block_q, LANES), lambda b_, i, j: (b_, i, 0),
-                memory_space=pltpu.VMEM,
-            )
-        )
+        out_specs.append(q_spec(LANES))
         out_shape.append(
             jax.ShapeDtypeStruct((bh, s, LANES), jnp.float32)
         )
     res = pl.pallas_call(
         kernel,
-        grid=(bh, num_q, num_k),
-        in_specs=[
-            pl.BlockSpec(
-                (None, block_q, d), lambda b_, i, j: (b_, i, 0),
-                memory_space=pltpu.VMEM,
-            ),
-            pl.BlockSpec(
-                (None, block_k, d), kv_index, memory_space=pltpu.VMEM
-            ),
-            pl.BlockSpec(
-                (None, block_k, d), kv_index, memory_space=pltpu.VMEM
-            ),
-        ],
+        grid=(bh, len(run.major)),
+        in_specs=[q_spec(d), k_spec, k_spec],
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
@@ -558,19 +583,21 @@ def _flash_forward(q, k, v, mask, block_q, block_k, emit_lse):
 def _bwd_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
     dq_scr, dk_scr, dv_scr,
-    *, block_q, block_k, num_q_blocks, num_k_blocks, mask, scale,
+    *, block_q, block_k, run, mask, scale,
 ):
     from jax.experimental import pallas as pl
 
-    j = pl.program_id(1)  # k block
-    i = pl.program_id(2)  # q block (fastest)
-    # dq's sum runs over j, the outer axis: the whole [S, D] row of this
+    t = pl.program_id(1)  # the t-th run tile, by columns
+    j, i = _major_at(t, run), _minor_at(t, run)  # k block, q block
+    first, last = _ends_at(t, run)
+
+    # dq's sum runs over the columns: the whole [S, D] row of this
     # batch*head stays in VMEM from the row's first grid step to its last.
-    @pl.when((j == 0) & (i == 0))
+    @pl.when(t == 0)
     def _init_row():
         dq_scr[:] = jnp.zeros(dq_scr.shape, jnp.float32)
 
-    @pl.when(i == 0)
+    @pl.when(first)
     def _init():
         dk_scr[:] = jnp.zeros(dk_scr.shape, jnp.float32)
         dv_scr[:] = jnp.zeros(dv_scr.shape, jnp.float32)
@@ -599,17 +626,15 @@ def _bwd_kernel(
             ds, k, preferred_element_type=jnp.float32
         )
 
-    # q tiles that see none of this k tile: skip. (The q-side index maps
-    # follow the run set, so skipped steps cost no DMA.)
     _accumulate_by_kind(
-        accumulate, _tile_kinds(mask, i, j, block_q, block_k))
+        accumulate, t, run.ways, _way_masks(mask, i, j, block_q, block_k))
 
-    @pl.when(i == num_q_blocks - 1)
+    @pl.when(last)
     def _finalize():
         dk_ref[:] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[:] = dv_scr[:].astype(dv_ref.dtype)
 
-    @pl.when((j == num_k_blocks - 1) & (i == num_q_blocks - 1))
+    @pl.when(t == len(run.major) - 1)
     def _finalize_row():
         dq_ref[:] = dq_scr[:].astype(dq_ref.dtype)
 
@@ -636,7 +661,6 @@ def _flash_backward(q, k, v, out, lse, g, mask, block_q, block_k):
 
     b, h, s, d = q.shape
     bh = b * h
-    num_q, num_k = s // block_q, s // block_k
     vmem_bytes = _bwd_vmem_bytes(s, d, block_q, block_k, q.dtype.itemsize)
     if vmem_bytes > VMEM_BUDGET_BYTES:
         raise ValueError(
@@ -658,21 +682,19 @@ def _flash_backward(q, k, v, out, lse, g, mask, block_q, block_k):
     )
     delta_fat = jnp.broadcast_to(delta[:, :, None], (bh, s, LANES))
 
-    # Grid (bh, k, q): k-indexed tiles are major, q-indexed minor. Skipped
-    # steps address the next q tile that runs.
-    def q_index(b_, j, i):
-        if _unmasked(mask):
-            return (b_, i, 0)
-        segments = _q_segments(mask, j, block_q, block_k, num_q)
-        return (b_, _resident_q(i, segments, num_q), 0)
+    # The grid's second axis runs over the run tiles alone, by columns: a
+    # column's k, v, dk and dv blocks stay while its q-indexed tiles stream.
+    run = _run_tiles(mask, s, block_q, block_k, by_column=True)
 
     def q_spec(width):
         return pl.BlockSpec(
-            (None, block_q, width), q_index, memory_space=pltpu.VMEM
+            (None, block_q, width),
+            lambda b_, t: (b_, _minor_at(t, run), 0),
+            memory_space=pltpu.VMEM,
         )
 
     k_spec = pl.BlockSpec(
-        (None, block_k, d), lambda b_, j, i: (b_, j, 0),
+        (None, block_k, d), lambda b_, t: (b_, _major_at(t, run), 0),
         memory_space=pltpu.VMEM,
     )
     dq, dk, dv = pl.pallas_call(
@@ -680,12 +702,11 @@ def _flash_backward(q, k, v, out, lse, g, mask, block_q, block_k):
             _bwd_kernel,
             block_q=block_q,
             block_k=block_k,
-            num_q_blocks=num_q,
-            num_k_blocks=num_k,
+            run=run,
             mask=mask,
             scale=d**-0.5,
         ),
-        grid=(bh, num_k, num_q),
+        grid=(bh, len(run.major)),
         in_specs=[
             q_spec(d), k_spec, k_spec, q_spec(d),
             q_spec(LANES), q_spec(LANES),
@@ -693,7 +714,7 @@ def _flash_backward(q, k, v, out, lse, g, mask, block_q, block_k):
         out_specs=[
             # Indexed by the row alone: written back once a row.
             pl.BlockSpec(
-                (None, s, d), lambda b_, j, i: (b_, 0, 0),
+                (None, s, d), lambda b_, t: (b_, 0, 0),
                 memory_space=pltpu.VMEM,
             ),
             k_spec,

@@ -1,6 +1,6 @@
 """The flash kernels under the block-diffusion mask (ops/flash_attention.py
 `BlockDiffusion`): the description against the four-line definition by
-brute force, the tile kinds and the run-set segments against the mask
+brute force, the tile kinds and the grids' decode against the mask
 itself, the kernels in interpret mode against the dense oracle."""
 
 import jax
@@ -85,54 +85,58 @@ def test_tile_kinds_against_the_mask_itself(half, block, block_q, block_k):
     scores = jnp.zeros((block_q, block_k), jnp.float32)
     for i in range(num_q):
         for j in range(num_k):
-            is_whole, ways = fa._tile_kinds(mask, i, j, block_q, block_k)
-            assert bool(is_whole) == every[i, j]
-            hits = [bool(hit) for hit, _ in ways]
+            is_whole, hits = fa._tile_kinds(mask, i, j, block_q, block_k)
+            assert is_whole == every[i, j]
             assert sum(hits) == int(some[i, j] and not every[i, j])
             # The way a tile is crossed is the pair of halves it lies in.
             want_way = (i * block_q >= half) + (j * block_k >= half)
-            for way, (hit, mask_scores) in enumerate(ways):
-                if bool(hit):
+            masks = fa._way_masks(mask, i, j, block_q, block_k)
+            for way, (hit, mask_scores) in enumerate(zip(hits, masks)):
+                if hit:
                     assert way == want_way
                     kept = np.asarray(mask_scores(scores)) == 0.0
                     np.testing.assert_array_equal(kept, seen[i, j])
 
 
+def walk_the_decode(mask, s, block_q, block_k, seen):
+    """Walk both passes' grids, every step decoded as the index maps and
+    the kernel bodies decode it: the tiles of the brute-force mask `seen`
+    ([S, S]) some score of which is seen come up exactly once each and no
+    other tile does, rows (by columns: columns) rising and a row's tiles
+    rising, a row's first and last steps are told, and the steps listed
+    as crossed are those whose tile holds a score that is not seen."""
+    num_q, num_k = s // block_q, s // block_k
+    tiled = seen.reshape(num_q, block_q, num_k, block_k)
+    some, every = tiled.any(axis=(1, 3)), tiled.all(axis=(1, 3))
+    for by_column, want, whole in ((False, some, every),
+                                   (True, some.T, every.T)):
+        run = fa._run_tiles(mask, s, block_q, block_k, by_column)
+        steps = fa.grid_steps(mask, s, block_q, block_k)
+        assert len(run.major) == len(run.offset) == steps
+        major, minor, first, last = (
+            np.asarray(x) for x in jax.jit(jax.vmap(lambda t: (
+                fa._major_at(t, run), fa._minor_at(t, run),
+                *fa._ends_at(t, run))))(
+                    jnp.arange(steps, dtype=jnp.int32)))
+        crossed = sorted(n for way in run.ways for n in way)
+        assert crossed == [
+            n for n in range(steps) if not whole[major[n], minor[n]]]
+        # `argwhere` lists the run tiles once each, majors rising and
+        # within a major its minors rising.
+        np.testing.assert_array_equal(
+            np.stack([major, minor], axis=1), np.argwhere(want))
+        starts = np.r_[True, major[1:] != major[:-1]]
+        np.testing.assert_array_equal(first, starts)
+        np.testing.assert_array_equal(last, np.r_[starts[1:], True])
+        # Every row (column) has a tile to zero and to write its sums at.
+        assert set(major) == set(range(len(want)))
+
+
 @pytest.mark.parametrize("half,block,block_q,block_k", SHAPES)
-def test_index_maps_follow_the_run_set(half, block, block_q, block_k):
-    """A forward step addresses itself where it runs, else the run tile
-    before it (resident: no DMA); the row ends at its last run tile. A
-    backward step addresses itself where it runs, else the next run tile,
-    else the last."""
-    mask = BlockDiffusion(block, half)
-    num_q, num_k = 2 * half // block_q, 2 * half // block_k
-    seen = brute_force_mask(half, block).reshape(
-        num_q, block_q, num_k, block_k)
-    some = seen.any(axis=(1, 3))
-    for i in range(num_q):
-        runs = np.flatnonzero(some[i])
-        segments = fa._k_segments(mask, i, block_q, block_k, num_k)
-        assert int(segments[1][1]) == runs[-1]
-        for j in range(num_k):
-            at = max(int(fa._resident_k(j, segments)), 0)
-            before = runs[runs <= j]
-            want = before[-1] if len(before) else runs[0]
-            if len(before) or some[i, 0]:
-                assert at == want, (i, j)
-            if some[i, j]:
-                assert at == j
-    for j in range(num_k):
-        runs = np.flatnonzero(some[:, j])
-        segments = fa._q_segments(mask, j, block_q, block_k, num_q)
-        for i in range(num_q):
-            at = int(fa._resident_q(i, segments, num_q))
-            after = runs[runs >= i]
-            if some[i, j]:
-                assert at == i
-            elif len(after):
-                assert at == after[0], (i, j)
-            else:
-                assert 0 <= at < num_q
+def test_the_decode_walks_the_run_tiles_alone(half, block, block_q, block_k):
+    walk_the_decode(
+        BlockDiffusion(block, half), 2 * half, block_q, block_k,
+        brute_force_mask(half, block))
 
 
 def test_tile_kinds_of_the_cell():
@@ -152,13 +156,13 @@ def test_the_block_masks_of_equal_tiles_are_constants_of_the_trace(
     mask = BlockDiffusion(4, 512)
 
     def crossed(s, i, j):
-        _, ways = fa._tile_kinds(mask, i, j, 128, block_k)
-        return [mask_scores(s) for _, mask_scores in ways]
+        return [
+            mask_scores(s)
+            for mask_scores in fa._way_masks(mask, i, j, 128, block_k)]
 
     jaxpr = jax.make_jaxpr(crossed)(
         jnp.zeros((128, block_k), jnp.float32), 1, 1).jaxpr
-    # What the masks read of the grid indices (the hits read them, and are
-    # not in the outputs).
+    # What the masks read of the grid indices.
     needed = set()
     for eqn in reversed(jaxpr.eqns):
         if any(v in needed or v in jaxpr.outvars for v in eqn.outvars):

@@ -3,10 +3,13 @@ and Ulysses all-to-all must reproduce full attention exactly (same math,
 different schedule), including causal masking and gradients through the
 sharded computation."""
 
+import functools
+
 import numpy as np
 import pytest
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from elasticdl_tpu.ops import flash_attention as fa
@@ -18,6 +21,7 @@ from elasticdl_tpu.ops.flash_attention import (
 from elasticdl_tpu.parallel.mesh import make_mesh
 from elasticdl_tpu.parallel.ring_attention import make_ring_attention
 from elasticdl_tpu.parallel.ulysses import make_ulysses_attention
+from tests.test_block_diffusion_attention import walk_the_decode
 
 B, H, S, D = 2, 8, 256, 32
 
@@ -242,7 +246,19 @@ def test_fallback_runs_its_softmax_in_float32(qkv, causal):
 # ---------- the oracle of the tile kinds: every run tile masked whole ----------
 # The two kernels as they stood before a tile's work followed its place to
 # the diagonal (one accumulate body each, the mask on every tile that
-# runs), kept here to be run through the module's own `pallas_call`s.
+# runs) and before the grids ran over the run tiles alone: rectangular
+# grids whose steps above the diagonal are skipped, under `pallas_call`s
+# of their own.
+
+
+def _last_kj(i, block_q, block_k, num_k_blocks):
+    """Index of the last k tile the i-th q tile attends to."""
+    return jnp.minimum(((i + 1) * block_q - 1) // block_k, num_k_blocks - 1)
+
+
+def _first_qi(j, block_q, block_k):
+    """Index of the first q tile that sees the j-th k tile."""
+    return (j * block_k) // block_q
 
 
 def _masked_whole(scores, i, j, block_q, block_k):
@@ -257,14 +273,13 @@ def _masked_whole(scores, i, j, block_q, block_k):
 
 def _oracle_fwd_kernel(
     q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-    *, block_q, block_k, num_k_blocks, mask, scale, emit_lse,
+    *, block_q, block_k, num_k_blocks, scale,
 ):
     from jax.experimental import pallas as pl
 
-    assert mask is True and emit_lse
     i = pl.program_id(1)
     j = pl.program_id(2)
-    last_j = fa._last_kj(i, block_q, block_k, num_k_blocks, mask)
+    last_j = _last_kj(i, block_q, block_k, num_k_blocks)
 
     @pl.when(j == 0)
     def _init():
@@ -303,14 +318,13 @@ def _oracle_fwd_kernel(
 def _oracle_bwd_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
     dq_scr, dk_scr, dv_scr,
-    *, block_q, block_k, num_q_blocks, num_k_blocks, mask, scale,
+    *, block_q, block_k, num_q_blocks, num_k_blocks, scale,
 ):
     from jax.experimental import pallas as pl
 
-    assert mask is True
     j = pl.program_id(1)
     i = pl.program_id(2)
-    first_i = fa._first_qi(j, block_q, block_k, mask)
+    first_i = _first_qi(j, block_q, block_k)
 
     @pl.when((j == 0) & (i == 0))
     def _init_row():
@@ -355,6 +369,93 @@ def _oracle_bwd_kernel(
         dq_ref[:] = dq_scr[:].astype(dq_ref.dtype)
 
 
+def _oracle_forward(q, k, v, block_q, block_k):
+    from jax.experimental import pallas as pl
+
+    bh, s, d = q.shape
+    num_q, num_k = s // block_q, s // block_k
+
+    def q_spec(width):
+        return pl.BlockSpec(
+            (None, block_q, width), lambda b_, i, j: (b_, i, 0))
+
+    def kv_index(b_, i, j):
+        return (b_, jnp.minimum(j, _last_kj(i, block_q, block_k, num_k)), 0)
+
+    k_spec = pl.BlockSpec((None, block_k, d), kv_index)
+    return pl.pallas_call(
+        functools.partial(
+            _oracle_fwd_kernel, block_q=block_q, block_k=block_k,
+            num_k_blocks=num_k, scale=d**-0.5),
+        grid=(bh, num_q, num_k),
+        in_specs=[q_spec(d), k_spec, k_spec],
+        out_specs=[q_spec(d), q_spec(fa.LANES)],
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, s, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, s, fa.LANES), jnp.float32),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((block_q, fa.LANES), jnp.float32),
+            pltpu.VMEM((block_q, fa.LANES), jnp.float32),
+            pltpu.VMEM((block_q, d), jnp.float32),
+        ],
+        interpret=True,
+    )(q, k, v)
+
+
+def _oracle_backward(q, k, v, g, lse_fat, delta_fat, block_q, block_k):
+    from jax.experimental import pallas as pl
+
+    bh, s, d = q.shape
+    num_q, num_k = s // block_q, s // block_k
+
+    def q_spec(width):
+        return pl.BlockSpec(
+            (None, block_q, width),
+            lambda b_, j, i: (
+                b_, jnp.maximum(i, _first_qi(j, block_q, block_k)), 0))
+
+    k_spec = pl.BlockSpec((None, block_k, d), lambda b_, j, i: (b_, j, 0))
+    return pl.pallas_call(
+        functools.partial(
+            _oracle_bwd_kernel, block_q=block_q, block_k=block_k,
+            num_q_blocks=num_q, num_k_blocks=num_k, scale=d**-0.5),
+        grid=(bh, num_k, num_q),
+        in_specs=[
+            q_spec(d), k_spec, k_spec, q_spec(d),
+            q_spec(fa.LANES), q_spec(fa.LANES),
+        ],
+        out_specs=[
+            pl.BlockSpec((None, s, d), lambda b_, j, i: (b_, 0, 0)),
+            k_spec, k_spec,
+        ],
+        out_shape=[jax.ShapeDtypeStruct((bh, s, d), q.dtype)] * 3,
+        scratch_shapes=[
+            pltpu.VMEM((s, d), jnp.float32),
+            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, d), jnp.float32),
+        ],
+        interpret=True,
+    )(q, k, v, g, lse_fat, delta_fat)
+
+
+def _oracle_both_passes(q, k, v, g, block_q, block_k):
+    """The oracle kernels round the module's own kernels' boundary: one
+    lane of lse between the passes, delta from the rounded o."""
+    b, h, s, d = q.shape
+    q3, k3, v3, g3 = (x.reshape(b * h, s, d) for x in (q, k, v, g))
+    out, lse_fat = _oracle_forward(q3, k3, v3, block_q, block_k)
+    delta = jnp.sum(
+        g3.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+    lse = lse_fat[:, :, 0]
+    fat = (b * h, s, fa.LANES)
+    grads = _oracle_backward(
+        q3, k3, v3, g3, jnp.broadcast_to(lse[:, :, None], fat),
+        jnp.broadcast_to(delta[:, :, None], fat), block_q, block_k)
+    return (out.reshape(q.shape), lse.reshape(b, h, s)) + tuple(
+        x.reshape(q.shape) for x in grads)
+
+
 def _both_passes(q, k, v, g, block_q, block_k):
     out, lse = fa._flash_forward(q, k, v, True, block_q, block_k, True)
     return (out, lse) + fa._flash_backward(
@@ -378,9 +479,10 @@ def _bits(x):
 def test_tile_kinds_give_the_bits_of_masking_every_tile(
     monkeypatch, head, dtype, block_q, block_k, tiles
 ):
-    """A tile below the diagonal runs with no mask, a crossed one masked:
-    the same products on the same values in the same order, less the
-    selects that return their input. So o and lse are the oracle's bit for
+    """A tile below the diagonal runs with no mask, a crossed one masked,
+    and a grid step is spent on no other tile: the same products on the
+    same values in the same order, less the selects that return their
+    input and the steps that did nothing. So o and lse are the oracle's bit for
     bit in every case, and dq, dk, dv at head 64. At head 128 the CPU
     backend (not Mosaic: on the chip all five are bit-equal at the cells'
     three shapes, PERF.md section 6, PR 42) contracts `scale * s - lse`
@@ -396,9 +498,7 @@ def test_tile_kinds_give_the_bits_of_masking_every_tile(
         for _ in range(4)
     )
     got = _both_passes(q, k, v, g, block_q, block_k)
-    monkeypatch.setattr(fa, "_fwd_kernel", _oracle_fwd_kernel)
-    monkeypatch.setattr(fa, "_bwd_kernel", _oracle_bwd_kernel)
-    want = _both_passes(q, k, v, g, block_q, block_k)
+    want = _oracle_both_passes(q, k, v, g, block_q, block_k)
     for name, a, b in zip(("o", "lse", "dq", "dk", "dv"), got, want):
         assert a.dtype == b.dtype and a.shape == b.shape
         if head == 64 or name in ("o", "lse"):
@@ -413,11 +513,13 @@ def test_tile_kinds_give_the_bits_of_masking_every_tile(
             assert (np.abs(a - b) <= BF16_EPS * np.abs(b))[off].all(), name
 
 
-@pytest.mark.parametrize(
-    "block_q,block_k",
-    [(128, 128), (256, 256), (512, 512), (1024, 1024), (128, 256),
-     (256, 128), (128, 1024), (1024, 256), (512, 1024), (1024, 512)],
-)
+CAUSAL_BLOCKS = [
+    (128, 128), (256, 256), (512, 512), (1024, 1024), (128, 256),
+    (256, 128), (128, 1024), (1024, 256), (512, 1024), (1024, 512),
+]
+
+
+@pytest.mark.parametrize("block_q,block_k", CAUSAL_BLOCKS)
 @pytest.mark.parametrize("s", [1024, 2048, 4096, 8192])
 def test_causal_tile_kinds_against_the_mask_itself(s, block_q, block_k):
     """The helper's counts against a brute-force count over the [S, S]
@@ -436,10 +538,34 @@ def test_causal_tile_kinds_against_the_mask_itself(s, block_q, block_k):
     assert (~some).sum() + below + crossed == num_q * num_k
     # The kernels' own predicates, tile by tile.
     for i in range(num_q):
-        last_j = int(fa._last_kj(i, block_q, block_k, num_k, True))
         for j in range(num_k):
-            assert some[i, j] == (j <= last_j)
+            whole, (hit,) = fa._tile_kinds(True, i, j, block_q, block_k)
+            assert some[i, j] == (whole or hit)
+            assert every[i, j] == whole
             assert every[i, j] == fa._below_diagonal(i, j, block_q, block_k)
+
+
+@pytest.mark.parametrize("block_q,block_k", CAUSAL_BLOCKS)
+@pytest.mark.parametrize("s", [1024, 2048, 4096, 8192])
+def test_the_decode_walks_the_causal_run_tiles_alone(s, block_q, block_k):
+    """Both passes' grids under the causal mask, unequal blocks included
+    (their masks read `(i, j)`, which the decode has to give them)."""
+    walk_the_decode(
+        True, s, block_q, block_k, np.tril(np.ones((s, s), bool)))
+
+
+@pytest.mark.parametrize(
+    "s,block_q,block_k",
+    [(128, 128, 128), (1024, 128, 128), (1024, 256, 128), (1024, 128, 512)])
+def test_the_decode_of_the_unmasked_grid_is_a_division(s, block_q, block_k):
+    """Under `False` every tile of the grid runs, whole: the run list is
+    the rectangle, and the decode divides the step by a row's tiles where
+    the other descriptions walk a chain a row long."""
+    walk_the_decode(False, s, block_q, block_k, np.ones((s, s), bool))
+    run = fa._run_tiles(False, s, block_q, block_k)
+    assert run.width == s // block_k and run.ways == ()
+    jaxpr = jax.make_jaxpr(lambda t: fa._major_at(t, run))(jnp.int32(0))
+    assert [eqn.primitive.name for eqn in jaxpr.eqns] == ["div"]
 
 
 @pytest.mark.parametrize("block_k,constant", [(128, True), (256, False)])

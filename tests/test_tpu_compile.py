@@ -402,6 +402,59 @@ def test_flagship_step_hands_its_kernels_the_activation_dtype(
     assert step.resident <= resident_with_f32_operands - 1.4e9, step.resident
 
 
+@pytest.mark.parametrize(
+    "fixture", ["flagship_one_chip", "nemotron_h_cut_one_chip",
+                "lfm2_cut_one_chip"])
+def test_the_steps_kernels_keep_their_operand_and_result_counts(
+    request, fixture
+):
+    """What the benchmark's readers key on (`benchmark/metrics/
+    _pallas_attention.py:classify`, behind `flash_roofline` and
+    `flash_time_pct` in four cells): the causal forward is a
+    `tpu_custom_call` of 3 operands and 2 results, the backward of 6 and
+    3. A table operand for the grid's decode would take `flash_fwd` out of
+    both readers; the decode is arithmetic over constants instead."""
+    step = request.getfixturevalue(fixture)
+    counts = {
+        (len(operands), len(_HLO_ARRAY.findall(results)))
+        for results, operands in _kernel_calls(step.text)
+    }
+    assert counts == {(3, 2), (6, 3)}
+
+
+@pytest.mark.parametrize(
+    "mask,s,steps",
+    [(True, 4096, 10), (True, 8192, 36), (True, 16384, 136),
+     (fa.BlockDiffusion(4, 8192), 16384, 80), (False, 4096, 16)],
+    ids=["causal4096", "causal8192", "causal16384", "sdar", "unmasked"],
+)
+def test_the_kernels_grids_hold_the_run_tiles_alone(mask, s, steps):
+    """Both passes' `pallas_call`s, as traced at the cells' shapes over
+    1024 x 1024 tiles: the grid is (batch*heads, run tiles), so no grid
+    step is spent on a tile that does not run; `grid_steps` is the static
+    counter of it."""
+    assert fa.grid_steps(mask, s, 1024, 1024) == steps
+    x = jax.ShapeDtypeStruct((1, 2, s, 128), jnp.bfloat16)
+
+    def both(q, k, v, g):
+        o, lse = fa._flash_forward(q, k, v, mask, 1024, 1024, True)
+        return fa._flash_backward(q, k, v, o, lse, g, mask, 1024, 1024)
+
+    calls = [
+        eqn for eqn in jax.make_jaxpr(both)(x, x, x, x).jaxpr.eqns
+        if eqn.primitive.name == "pallas_call"
+    ]
+    prefix = "bd_" if isinstance(mask, fa.BlockDiffusion) else ""
+    assert [
+        (c.params["name"], c.params["grid_mapping"].grid,
+         len(c.invars), len(c.outvars))
+        for c in calls
+    ] == [
+        (prefix + "flash_fwd", (2, steps), 3, 2),
+        (prefix + "flash_bwd", (2, steps), 6, 3),
+    ]
+
+
 def test_nemotron_h_cut_step_still_hands_its_kernels_float32(
     nemotron_h_cut_one_chip,
 ):
