@@ -359,6 +359,15 @@ def sdar_cut_one_chip(topo, no_persistent_compile_cache_in_module):
         m, 1, 8192, topo, mp, batch_of=_block_diffusion_batch))
 
 
+@pytest.fixture(scope="module")
+def granite_cut_one_chip(topo, no_persistent_compile_cache_in_module):
+    from elasticdl_tpu.models.granite_hybrid import (
+        granite_4_0_h_micro_cut as m,
+    )
+
+    return _compile_planned(lambda mp: _plan_step(m, 1, 8192, topo, mp))
+
+
 def test_flagship_step_compiles_and_fits_one_v5e(flagship_one_chip):
     """The WHOLE flagship training step of AllReduceTrainer — the program
     `edl train` runs at `flagship_config()` widths, minibatch 4 — for one
@@ -723,3 +732,52 @@ def test_sdar_cut_step_compiles_and_fits_one_v5e(sdar_cut_one_chip):
     assert "tensor<1x8192x18992xf32>" in step.lowered
     assert "tensor<1x16384x18992xf32>" not in step.lowered
     print(f"sdar cut: resident {step.resident / 2**30:.2f} GiB")
+
+
+def test_granite_cut_step_compiles_and_fits_one_v5e(granite_cut_one_chip):
+    """The WHOLE training step of the granite-4.0-h-micro cut (772.2 M
+    parameters at 16 bytes each, the largest state any cell holds;
+    minibatch 1 x S 8192, as `edl train` runs `granite_4_0_h_micro_cut`)
+    for one described chip: nine chunked scans at one group, chunk 256 and
+    batch 1, the causal flash kernels at `[32, 8192, 64]` handed the
+    activation dtype with q already times 1/8, the tied head over 12,544
+    rows; it fits 16 GB with the remat the model-def states, compiles its
+    update apart from the weight-gradient products, and hands its
+    counter back beside the loss."""
+    from elasticdl_tpu.models.granite_hybrid import (
+        granite_4_0_h_micro_cut as m,
+    )
+
+    step = granite_cut_one_chip
+    assert step.out_tree.children()[2].num_leaves == 2
+    # One attention layer: flash_fwd (and a rematerialised twin) and
+    # flash_bwd, the kernels every causal cell runs.
+    calls = _kernel_calls(step.text)
+    assert 2 <= len(calls) <= 3
+    for results, operands in calls:
+        assert results.startswith("(bf16[32,8192,64], "), results
+        assert set(operands) <= {"bf16[32,8192,64]", "f32[32,8192,128]"}
+    assert step.text.count("flash_fwd") >= 1
+    assert step.text.count("flash_bwd") >= 1
+    assert step.resident < HBM_BYTES, f"{step.resident / 2**30:.2f} GiB"
+    # params + Adam m and v: 772,160,448 x 12 B.
+    assert step.argument_bytes > 9.2e9
+    assert {"f32[2048,8512]", "f32[4,4352]", "f32[4096,2048]",
+            "f32[2048,16384]", "f32[8192,2048]", "f32[2048,32,64]",
+            "f32[2048,8,64]", "f32[12544,2048]"} <= step.weights
+    # Tied: no head of its own.
+    assert "f32[2048,12544]" not in step.weights
+    # The scan ran at one group, chunk 256, batch 1: 32 chunks.
+    assert "tensor<1x32x1x256x256xf32>" in step.lowered
+    # The update apart: a barrier a gradient leaf, beside the one
+    # `jax.checkpoint` gives each rematerialised block.
+    assert step_plan.update_apart_for(step.mesh)
+    rematerialised = {"none": 0, "dots": 10}
+    assert step.lowered.count("optimization_barrier") == (
+        step.n_grad_leaves + rematerialised[m.REMAT])
+    fusions = _update_fusions(step)
+    assert "kOutput" not in fusions, fusions["kOutput"]
+    assert {"f32[2048,8512]", "f32[2048,16384]", "f32[12544,2048]"} <= set(
+        fusions["kLoop"])
+    print(f"granite cut ({m.REMAT}): resident {step.resident / 2**30:.2f} "
+          "GiB")
