@@ -14,13 +14,15 @@ a chunk of Q tokens it is a masked [Q, Q] product, between chunks a short
 recurrence over one state a chunk, so nearly all of it is matrix products.
 Decays (cumulative sums and their exponentials) stay float32; the products
 take operands in the activation dtype and accumulate in float32. The
-gradient is `jax.grad` of the same products. Plain `jax.numpy`: the three
+gradient is `jax.grad` of the same products. Plain `jax.numpy`: the four
 phases run under `jax.named_scope("ssd_scan")` so that a device trace finds
-them; a Pallas kernel for them is a later PR's.
+them. `Mamba2Mixer.scan` is the function the mixer scans with: `ssd_chunked`
+unless the call site hands in another with the same arguments and result
+(`ops/ssd_scan.py:ssd_scan`, the same mathematics as Pallas kernels).
 """
 
 import math
-from typing import Optional, Tuple
+from typing import Callable, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -156,6 +158,8 @@ class Mamba2Mixer(nn.Module):
     time_step_limit: Tuple[float, Optional[float]] = (0.0, None)
     dtype: str = "bfloat16"
     kernel_init: nn.initializers.Initializer = nn.initializers.normal(0.02)
+    # (x, dt, a, b, c, chunk, dtype=) -> y, as `ssd_chunked`.
+    scan: Callable = ssd_chunked
 
     @nn.compact
     def __call__(self, u):
@@ -192,8 +196,8 @@ class Mamba2Mixer(nn.Module):
         low, high = self.time_step_limit
         if low or high is not None:
             dt = jnp.clip(dt, low, high)
-        y = ssd_chunked(x, dt, -jnp.exp(a_log.astype(f32)), b, c,
-                        self.chunk_size, dtype=dtype)
+        y = self.scan(x, dt, -jnp.exp(a_log.astype(f32)), b, c,
+                      self.chunk_size, dtype=dtype)
         y = y + x.astype(f32) * skip.astype(f32)[:, None]
         weight = self.param("norm_weight", nn.initializers.ones, (d_inner,))
         y = gated_group_rms_norm(

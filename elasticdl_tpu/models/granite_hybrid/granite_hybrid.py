@@ -6,7 +6,8 @@ multipliers.
     h = embedding[ids] * embedding_multiplier           (tied with the head)
     h = h + residual_multiplier * mixer(RMSNorm(h))     by `layer_types[i]`
       mamba      Mamba-2 mixer, one group: the gated RMSNorm runs over the
-                 whole inner width; layers/mamba2.py
+                 whole inner width; layers/mamba2.py, its scan handed in
+                 as the Pallas kernels of ops/ssd_scan.py
       attention  causal GQA, no bias, NO position signal (`nope`: the
                  mixers carry order), scores times `attention_multiplier`
                  (not head_dim^-0.5); ops/flash_attention.py
@@ -28,8 +29,9 @@ so the call site hands them q * (attention_multiplier * head_dim^0.5):
 at the published 1/64 and head 64 that is q / 8, exact in bfloat16.
 
 Model contract: training=True returns {"logits", "stats"} (the tokens the
-step scanned, from the shapes; the trainer hands them back beside the
-loss); training=False returns plain logits.
+step scanned and, where `ops/ssd_scan.py` runs its kernels, those of them
+whose scan ran as the kernels, from the shapes; the trainer hands them
+back beside the loss); training=False returns plain logits.
 """
 
 import dataclasses
@@ -52,6 +54,7 @@ from elasticdl_tpu.models.nemotron_h.nemotron_h import (  # noqa: F401
     param_specs,
 )
 from elasticdl_tpu.ops.flash_attention import flash_attention
+from elasticdl_tpu.ops.ssd_scan import runs_as_kernel, ssd_scan
 
 MIXERS = ("mamba", "attention")
 MIXER_SCOPE = "granite_mixer"
@@ -244,7 +247,7 @@ class Block(nn.Module):
                     chunk_size=cfg.mamba_chunk_size,
                     use_conv_bias=cfg.mamba_conv_bias,
                     norm_eps=cfg.rms_norm_eps, dtype=cfg.activation_dtype,
-                    kernel_init=cfg.init, name="mamba")(u)
+                    kernel_init=cfg.init, scan=ssd_scan, name="mamba")(u)
         else:
             with jax.named_scope(ATTENTION_SCOPE):
                 out = Attention(cfg, name="self_attn")(u)
@@ -284,8 +287,11 @@ class GraniteHybrid(nn.Module):
             preferred_element_type=f32) / cfg.logits_scaling
         if not training:
             return logits
-        stats = {"ssd_scan_tokens": jnp.asarray(
-            tokens.size * cfg.scanning_layers, f32)}
+        scanned = jnp.asarray(tokens.size * cfg.scanning_layers, f32)
+        stats = {"ssd_scan_tokens": scanned}
+        if runs_as_kernel():
+            # Every mixer was handed the kernels, and here they run.
+            stats["ssd_kernel_tokens"] = scanned
         return {"logits": logits, "stats": stats}
 
 

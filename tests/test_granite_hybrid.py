@@ -200,11 +200,39 @@ def test_the_step_counts_what_it_scans(tiny):
     out = gh.custom_model(CONFIG).apply(
         {"params": params}, tokens, training=True)
     batch = tokens.shape[0]
+    # Off the TPU the scan's call runs `ssd_chunked`: no token by kernel,
+    # and no key for them.
     assert {k: float(v) for k, v in out["stats"].items()} == {
         "ssd_scan_tokens": batch * LENGTH * 3}
     # Evaluation hands back plain logits.
     assert gh.custom_model(CONFIG).apply(
         {"params": params}, tokens).shape == (batch, LENGTH, 256)
+
+
+def test_where_the_kernels_run_every_scanned_token_is_a_kernels(monkeypatch):
+    """`ssd_kernel_tokens == ssd_scan_tokens` where `ops/ssd_scan.py` runs
+    its kernels (the TPU; here the interpreter), at sizes they tile, and
+    the loss is the one the same model reads through `ssd_chunked`."""
+    config = dataclasses.replace(
+        CONFIG, layer_types=("mamba", "attention", "mamba"),
+        mamba_n_heads=8, mamba_d_head=64, mamba_d_state=128,
+        mamba_chunk_size=128)
+    rng = np.random.default_rng(4)
+    tokens = rng.integers(0, 256, (2, 257)).astype(np.int32)
+    model = gh.custom_model(config)
+    params = model.init({"params": jax.random.PRNGKey(2)}, tokens[:, :-1])
+
+    def run():
+        out = model.apply(params, tokens[:, :-1], training=True)
+        return ({k: float(v) for k, v in out["stats"].items()},
+                float(gh.loss(tokens[:, 1:], out)))
+
+    chunked_stats, chunked_loss = run()
+    monkeypatch.setenv("EDL_FORCE_PALLAS_INTERPRET", "1")
+    stats, loss = run()
+    assert chunked_stats == {"ssd_scan_tokens": 2 * 256 * 2}
+    assert stats["ssd_kernel_tokens"] == stats["ssd_scan_tokens"] == 1024
+    assert abs(loss - chunked_loss) < 1e-4 * abs(chunked_loss)
 
 
 def test_the_remat_policy_changes_no_loss_and_no_gradient(tiny):

@@ -22,6 +22,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from elasticdl_tpu.ops import flash_attention as fa
+from elasticdl_tpu.ops import ssd_scan as ss
 from elasticdl_tpu.parallel import step_plan
 
 pytestmark = pytest.mark.usefixtures("no_persistent_compile_cache")
@@ -173,6 +174,70 @@ def test_flash_kernel_partitions_over_a_data_mesh(topo, kernel_on):
     assert text.count("tpu_custom_call") == 2
     # Per device: 4 of the 16 rows.
     assert "f32[4,8,4096,128]" in text
+
+
+# x [B, S, H, P], state, chunk: the granite cut's scan (one group).
+GRANITE_SCAN = ((1, 8192, 64, 64), 128, 256)
+
+
+def _scan_operands(sharding, shape=GRANITE_SCAN[0], groups=1,
+                   state=GRANITE_SCAN[1]):
+    bsz, s, h, _ = shape
+
+    def of(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=sharding)
+
+    replicated = (NamedSharding(sharding.mesh, P())
+                  if isinstance(sharding, NamedSharding) else sharding)
+    return (of(shape, jnp.bfloat16), of((bsz, s, h), jnp.float32),
+            jax.ShapeDtypeStruct((h,), jnp.float32, sharding=replicated),
+            of((bsz, s, groups, state), jnp.bfloat16),
+            of((bsz, s, groups, state), jnp.bfloat16))
+
+
+def _scan_loss(*operands):
+    return jnp.sum(ss.ssd_scan(
+        *operands, GRANITE_SCAN[2], dtype=jnp.bfloat16) ** 2)
+
+
+@pytest.mark.parametrize("backward", [False, True],
+                         ids=["forward", "backward"])
+def test_ssd_scan_kernels_compile_for_v5e(one_chip, kernel_on, backward):
+    """The chunked scan's two kernels at the granite cell's shape ([1,
+    8192, 64, 64], state 128, chunk 256, bfloat16 operands): the VMEM
+    they ask, their [128, 128] mask tiles, the heads' traced indices and
+    the transposes of eight heads' rows are the chip's
+    compiler's to refuse. Forward: y. Backward: y and the entering
+    states, then dx, dB, dC, d(dt a) and dt's part through x * dt."""
+    fn = jax.grad(_scan_loss, argnums=(0, 1, 2, 3, 4)) if backward else (
+        _scan_loss)
+    text = jax.jit(fn).lower(*_scan_operands(one_chip)).compile().as_text()
+    y, x = "f32[1,1,64,64,8192]", "bf16[1,1,64,64,8192]"
+    b, dt = "bf16[1,1,128,8192]", "f32[1,1,64,8192]"
+    calls = [f"({y}, f32[1,32,1,4096,128])", f"({x}, {b}, {b}, {dt}, {dt})"]
+    assert sorted(r for r, _ in _kernel_calls(text)) == sorted(
+        calls if backward else [y])
+    assert ("ssd_scan_bwd" in text) == backward and "ssd_scan_fwd" in text
+
+
+def test_ssd_scan_partitions_over_a_data_mesh(topo, kernel_on):
+    """As the flash kernels: under the trainer's abstract mesh each batch
+    shard scans its own rows (no cell runs the scan across chips)."""
+    mesh = jax.sharding.Mesh(np.array(topo.devices), ("data",))
+    sharded = NamedSharding(mesh, P("data"))
+
+    def loss(*operands):
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return _scan_loss(*operands)
+
+    text = (
+        jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4)))
+        .lower(*_scan_operands(sharded, shape=(4, 8192, 64, 64)))
+        .compile().as_text()
+    )
+    assert text.count("tpu_custom_call") == 2
+    # Per device: 1 of the 4 rows.
+    assert "f32[1,1,64,64,8192]" in text and "f32[4,1,64,64" not in text
 
 
 def test_unservable_sequence_raises_where_the_kernel_runs(kernel_on):
@@ -649,6 +714,10 @@ def test_nemotron_h_cut_step_compiles_and_fits_one_v5e(
     # One attention layer: flash_fwd (and its rematerialised twin) and
     # flash_bwd.
     assert 2 <= step.text.count("tpu_custom_call") <= 3
+    # Its mixers say nothing of the scan: `ssd_chunked`, the program it
+    # had, decay mask and all.
+    assert "ssd_scan_fwd" not in step.text
+    assert "tensor<2x64x8x8x128x128xf32>" in step.lowered
     assert step.resident < HBM_BYTES, f"{step.resident / 2**30:.2f} GiB"
     # params + Adam m and v
     assert step.argument_bytes > 7.9e9
@@ -739,7 +808,7 @@ def test_granite_cut_step_compiles_and_fits_one_v5e(granite_cut_one_chip):
     parameters at 16 bytes each, the largest state any cell holds;
     minibatch 1 x S 8192, as `edl train` runs `granite_4_0_h_micro_cut`)
     for one described chip: nine chunked scans at one group, chunk 256 and
-    batch 1, the causal flash kernels at `[32, 8192, 64]` handed the
+    batch 1 as the kernels of `ops/ssd_scan.py`, the causal flash kernels at `[32, 8192, 64]` handed the
     activation dtype with q already times 1/8, the tied head over 12,544
     rows; it fits 16 GB with the remat the model-def states, compiles its
     update apart from the weight-gradient products, and hands its
@@ -749,16 +818,43 @@ def test_granite_cut_step_compiles_and_fits_one_v5e(granite_cut_one_chip):
     )
 
     step = granite_cut_one_chip
-    assert step.out_tree.children()[2].num_leaves == 2
+    # The loss and the two counters.
+    assert step.out_tree.children()[2].num_leaves == 3
+    calls = _kernel_calls(step.text)
     # One attention layer: flash_fwd (and a rematerialised twin) and
     # flash_bwd, the kernels every causal cell runs.
-    calls = _kernel_calls(step.text)
-    assert 2 <= len(calls) <= 3
-    for results, operands in calls:
-        assert results.startswith("(bf16[32,8192,64], "), results
+    flash = [c for c in calls if c[0].startswith("(bf16[32,8192,64], ")]
+    assert 2 <= len(flash) <= 3
+    for _, operands in flash:
         assert set(operands) <= {"bf16[32,8192,64]", "f32[32,8192,128]"}
     assert step.text.count("flash_fwd") >= 1
     assert step.text.count("flash_bwd") >= 1
+    # Nine mixers: the scan's forward kernel, its rematerialised twin and
+    # its backward kernel in each, every one known to the benchmark's
+    # readers by the chunked layout among its operands and results.
+    scans = [c for c in calls if c not in flash]
+    assert len(scans) == 27 and len(flash) + len(scans) == len(calls)
+    forward = "(f32[1,1,64,64,8192], f32[1,32,1,4096,128])"
+    assert sum(results == forward for results, _ in scans) == 18
+    for results, operands in scans:
+        assert "bf16[1,1,64,64,8192]" in (results, *operands), results
+    # The transposes round the calls move nothing: the compiler keeps
+    # these activations with the time minor already, so no copy, reshape
+    # or transpose of a tensor of x's size (or B's, under the scan's
+    # scope) is an operation of the step. (By way of a [.., chunks, chunk]
+    # shape they were: two copies each of x, y, dy and dx, 30 ms a step on
+    # the chip.)
+    moved = []
+    for line in step.text[step.text.index("ENTRY"):].split("\n"):
+        op = _HLO_OP.match(line)
+        if not op or op.group(2) not in ("copy", "reshape", "transpose"):
+            continue
+        sizes = [int(np.prod([int(d) for d in dims.split(",")]))
+                 for _, dims in _HLO_ARRAY.findall(op.group(1)) if dims]
+        at_least = 8192 * 128 if "/ssd_scan/" in line else 8192 * 4096
+        if sizes and at_least <= max(sizes) < 8192 * 12544:
+            moved.append(line.split(", metadata=")[0])
+    assert not moved, moved[:3]
     assert step.resident < HBM_BYTES, f"{step.resident / 2**30:.2f} GiB"
     # params + Adam m and v: 772,160,448 x 12 B.
     assert step.argument_bytes > 9.2e9
@@ -767,8 +863,10 @@ def test_granite_cut_step_compiles_and_fits_one_v5e(granite_cut_one_chip):
             "f32[2048,8,64]", "f32[12544,2048]"} <= step.weights
     # Tied: no head of its own.
     assert "f32[2048,12544]" not in step.weights
-    # The scan ran at one group, chunk 256, batch 1: 32 chunks.
-    assert "tensor<1x32x1x256x256xf32>" in step.lowered
+    # The scan ran at one group, chunk 256, batch 1, as the kernels: 32
+    # chunks' entering states, and no decay mask in the program.
+    assert "tensor<1x32x1x4096x128xf32>" in step.lowered
+    assert "tensor<1x32x1x256x256xf32>" not in step.lowered
     # The update apart: a barrier a gradient leaf, beside the one
     # `jax.checkpoint` gives each rematerialised block.
     assert step_plan.update_apart_for(step.mesh)
