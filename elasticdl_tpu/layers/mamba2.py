@@ -18,7 +18,9 @@ gradient is `jax.grad` of the same products. Plain `jax.numpy`: the four
 phases run under `jax.named_scope("ssd_scan")` so that a device trace finds
 them. `Mamba2Mixer.scan` is the function the mixer scans with: `ssd_chunked`
 unless the call site hands in another with the same arguments and result
-(`ops/ssd_scan.py:ssd_scan`, the same mathematics as Pallas kernels).
+(`ops/ssd_scan.py:ssd_scan`, the same mathematics as Pallas kernels);
+`Mamba2Mixer.conv` the convolution stage in the same way: `conv_silu_split`
+unless the call site hands in `ops/causal_conv.py:causal_conv_silu`.
 """
 
 import math
@@ -109,6 +111,21 @@ def causal_depthwise_conv(x, weight, bias):
     return y if bias is None else y + bias
 
 
+def conv_silu_split(proj, widths, weight, bias):
+    """The mixer's convolution stage. proj [B, S, sum(widths)] holds z, x,
+    B, C and dt side by side in `widths`; weight [K, C] and bias [C] or
+    None (the parameters, taken in proj's dtype) over C = x's, B's and
+    C's widths. Returns z, x, B, C, dt with x, B, C =
+    split(silu(causal_depthwise_conv(x | B | C, weight, bias)))."""
+    z, xbc, dt = jnp.split(
+        proj, [widths[0], sum(widths[:4])], axis=-1)
+    xbc = jax.nn.silu(causal_depthwise_conv(
+        xbc, weight.astype(proj.dtype),
+        None if bias is None else bias.astype(proj.dtype)))
+    x, b, c = jnp.split(xbc, [widths[1], widths[1] + widths[2]], axis=-1)
+    return z, x, b, c, dt
+
+
 def gated_group_rms_norm(y, z, weight, groups, eps):
     """weight * groupRMSNorm(y * silu(z)) over `groups` groups of the last
     axis, in float32."""
@@ -160,6 +177,8 @@ class Mamba2Mixer(nn.Module):
     kernel_init: nn.initializers.Initializer = nn.initializers.normal(0.02)
     # (x, dt, a, b, c, chunk, dtype=) -> y, as `ssd_chunked`.
     scan: Callable = ssd_chunked
+    # (proj, widths, weight, bias) -> z, x, b, c, dt, as `conv_silu_split`.
+    conv: Callable = conv_silu_split
 
     @nn.compact
     def __call__(self, u):
@@ -172,17 +191,14 @@ class Mamba2Mixer(nn.Module):
         proj = nn.Dense(
             d_inner + d_conv + h, use_bias=False, dtype=dtype,
             kernel_init=self.kernel_init, name="in_proj")(u)
-        z, xbc, dt = jnp.split(proj, [d_inner, d_inner + d_conv], axis=-1)
         conv_w = self.param(
             "conv_kernel", _uniform(self.conv_kernel ** -0.5),
             (self.conv_kernel, d_conv))
         conv_b = self.param(
             "conv_bias", _uniform(self.conv_kernel ** -0.5), (d_conv,)
         ) if self.use_conv_bias else None
-        xbc = jax.nn.silu(causal_depthwise_conv(
-            xbc, conv_w.astype(dtype),
-            None if conv_b is None else conv_b.astype(dtype)))
-        x, b, c = jnp.split(xbc, [d_inner, d_inner + g * n], axis=-1)
+        z, x, b, c, dt = self.conv(
+            proj, (d_inner, d_inner, g * n, g * n, h), conv_w, conv_b)
         x = x.reshape(bsz, s, h, p)
         b = b.reshape(bsz, s, g, n)
         c = c.reshape(bsz, s, g, n)

@@ -7,7 +7,8 @@ multipliers.
     h = h + residual_multiplier * mixer(RMSNorm(h))     by `layer_types[i]`
       mamba      Mamba-2 mixer, one group: the gated RMSNorm runs over the
                  whole inner width; layers/mamba2.py, its scan handed in
-                 as the Pallas kernels of ops/ssd_scan.py
+                 as the Pallas kernels of ops/ssd_scan.py and its
+                 convolution stage as those of ops/causal_conv.py
       attention  causal GQA, no bias, NO position signal (`nope`: the
                  mixers carry order), scores times `attention_multiplier`
                  (not head_dim^-0.5); ops/flash_attention.py
@@ -53,6 +54,7 @@ from elasticdl_tpu.models.nemotron_h.nemotron_h import (  # noqa: F401
     optimizer,
     param_specs,
 )
+from elasticdl_tpu.ops.causal_conv import causal_conv_silu
 from elasticdl_tpu.ops.flash_attention import flash_attention
 from elasticdl_tpu.ops.ssd_scan import runs_as_kernel, ssd_scan
 
@@ -247,7 +249,8 @@ class Block(nn.Module):
                     chunk_size=cfg.mamba_chunk_size,
                     use_conv_bias=cfg.mamba_conv_bias,
                     norm_eps=cfg.rms_norm_eps, dtype=cfg.activation_dtype,
-                    kernel_init=cfg.init, scan=ssd_scan, name="mamba")(u)
+                    kernel_init=cfg.init, scan=ssd_scan,
+                    conv=causal_conv_silu, name="mamba")(u)
         else:
             with jax.named_scope(ATTENTION_SCOPE):
                 out = Attention(cfg, name="self_attn")(u)

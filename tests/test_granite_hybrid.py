@@ -235,6 +235,43 @@ def test_where_the_kernels_run_every_scanned_token_is_a_kernels(monkeypatch):
     assert abs(loss - chunked_loss) < 1e-4 * abs(chunked_loss)
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_the_convolution_kernels_losses_are_the_default_stages(
+        monkeypatch, dtype):
+    """The tiny model's loss and gradients with the convolution stage as
+    the kernels (interpreted) against the same model with the mixer's
+    default stage, `conv_silu_split`, at the call site: float32 to its
+    rounding; bfloat16 within what the default stage's own bfloat16 sums
+    round (its d bias is a bfloat16 sum over every token)."""
+    from elasticdl_tpu.layers import mamba2
+
+    config = dataclasses.replace(
+        CONFIG, layer_types=("mamba", "attention", "mamba"),
+        mamba_n_heads=8, mamba_d_head=64, mamba_d_state=128,
+        mamba_chunk_size=128, activation_dtype=dtype)
+    rng = np.random.default_rng(5)
+    tokens = rng.integers(0, 256, (2, 257)).astype(np.int32)
+    model = gh.custom_model(config)
+    params = model.init({"params": jax.random.PRNGKey(3)}, tokens[:, :-1])
+
+    def run():
+        return jax.value_and_grad(lambda p: gh.loss(
+            tokens[:, 1:], model.apply(p, tokens[:, :-1], training=True))
+        )(params)
+
+    monkeypatch.setenv("EDL_FORCE_PALLAS_INTERPRET", "1")
+    loss, grads = run()
+    monkeypatch.setattr(gh, "causal_conv_silu", mamba2.conv_silu_split)
+    want_loss, want_grads = run()
+    tolerance = {"float32": 1e-4, "bfloat16": 6e-2}[dtype]
+    assert abs(float(loss) - float(want_loss)) < tolerance * abs(
+        float(want_loss))
+    for got, want in zip(jax.tree_util.tree_leaves(grads),
+                         jax.tree_util.tree_leaves(want_grads)):
+        scale = float(jnp.max(jnp.abs(want))) + 1e-12
+        assert float(jnp.max(jnp.abs(got - want))) <= tolerance * scale
+
+
 def test_the_remat_policy_changes_no_loss_and_no_gradient(tiny):
     tokens, labels, params = tiny
 

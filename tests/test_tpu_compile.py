@@ -21,6 +21,7 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
+from elasticdl_tpu.ops import causal_conv as cc
 from elasticdl_tpu.ops import flash_attention as fa
 from elasticdl_tpu.ops import ssd_scan as ss
 from elasticdl_tpu.parallel import step_plan
@@ -238,6 +239,72 @@ def test_ssd_scan_partitions_over_a_data_mesh(topo, kernel_on):
     assert text.count("tpu_custom_call") == 2
     # Per device: 1 of the 4 rows.
     assert "f32[1,1,64,64,8192]" in text and "f32[4,1,64,64" not in text
+
+
+# proj [B, S, W] and the widths of z, x, B, C and dt in it: the granite
+# cut's in-projection (4 taps, bias).
+GRANITE_CONV = ((1, 8192, 8512), (4096, 4096, 128, 128, 64))
+GRANITE_CONV_CALLS = {
+    "forward": "(bf16[1,4096,8192], bf16[1,128,8192], bf16[1,128,8192])",
+    "backward": "(bf16[1,4352,8192], f32[1,4,4352], f32[1,1,4352])",
+}
+
+
+def _conv_operands(sharding, shape=GRANITE_CONV[0]):
+    replicated = (NamedSharding(sharding.mesh, P())
+                  if isinstance(sharding, NamedSharding) else sharding)
+    conv = sum(GRANITE_CONV[1][1:4])
+    return (jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=sharding),
+            jax.ShapeDtypeStruct((4, conv), jnp.float32,
+                                 sharding=replicated),
+            jax.ShapeDtypeStruct((conv,), jnp.float32, sharding=replicated))
+
+
+def _conv_loss(proj, weight, bias):
+    return sum(jnp.sum(part.astype(jnp.float32) ** 2)
+               for part in cc.causal_conv_silu(
+                   proj, GRANITE_CONV[1], weight, bias))
+
+
+@pytest.mark.parametrize("backward", [False, True],
+                         ids=["forward", "backward"])
+def test_causal_conv_kernels_compile_for_v5e(one_chip, kernel_on, backward):
+    """The convolution stage's two kernels at the granite cell's shape
+    (xBC rows 4096 .. 8447 of [1, 8512, 8192], 4 taps, bfloat16): the
+    rotations along the lanes, the turns' traced rows and the [128, 128]
+    squares turned over are the chip's compiler's to refuse. Forward: x,
+    B and C apart. Backward: those again, then d xBC, d weight and d
+    bias."""
+    fn = jax.grad(_conv_loss, argnums=(0, 1, 2)) if backward else _conv_loss
+    text = jax.jit(fn).lower(*_conv_operands(one_chip)).compile().as_text()
+    calls = [GRANITE_CONV_CALLS["forward"]] + (
+        [GRANITE_CONV_CALLS["backward"]] if backward else [])
+    assert sorted(r for r, _ in _kernel_calls(text)) == sorted(calls)
+    assert ("causal_conv_bwd" in text) == backward
+    assert "causal_conv_fwd" in text
+
+
+def test_causal_conv_partitions_over_a_data_mesh(topo, kernel_on):
+    """As the scan's kernels: under the trainer's abstract mesh each batch
+    shard convolves its own rows, and d weight and d bias are summed over
+    the shards by the program (the taps cross the boundary a copy a
+    row)."""
+    mesh = jax.sharding.Mesh(np.array(topo.devices), ("data",))
+    sharded = NamedSharding(mesh, P("data"))
+
+    def loss(*operands):
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return _conv_loss(*operands)
+
+    text = (
+        jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+        .lower(*_conv_operands(sharded, shape=(4, 8192, 8512)))
+        .compile().as_text()
+    )
+    assert text.count("tpu_custom_call") == 2
+    # Per device: 1 of the 4 rows, and the taps' gradient reduced.
+    assert "bf16[1,4352,8192]" in text and "bf16[4,4352,8192]" not in text
+    assert "all-reduce" in text
 
 
 def test_unservable_sequence_raises_where_the_kernel_runs(kernel_on):
@@ -715,8 +782,16 @@ def test_nemotron_h_cut_step_compiles_and_fits_one_v5e(
     # flash_bwd.
     assert 2 <= step.text.count("tpu_custom_call") <= 3
     # Its mixers say nothing of the scan: `ssd_chunked`, the program it
-    # had, decay mask and all.
+    # had, decay mask and all; nor of the convolution stage: the module
+    # imports nothing of `ops/causal_conv.py`, and no call of it is in
+    # the step.
     assert "ssd_scan_fwd" not in step.text
+    from elasticdl_tpu.models.nemotron_h import nemotron_h
+
+    assert "causal_conv" not in step.text
+    assert not any(
+        "causal_conv" in f"{getattr(v, '__name__', '')} "
+        f"{getattr(v, '__module__', '')}" for v in vars(nemotron_h).values())
     assert "tensor<2x64x8x8x128x128xf32>" in step.lowered
     assert step.resident < HBM_BYTES, f"{step.resident / 2**30:.2f} GiB"
     # params + Adam m and v
@@ -803,6 +878,29 @@ def test_sdar_cut_step_compiles_and_fits_one_v5e(sdar_cut_one_chip):
     print(f"sdar cut: resident {step.resident / 2**30:.2f} GiB")
 
 
+# The parent's step (PR 48) by the same compile: the convolution stage's
+# kernels may not add to it.
+GRANITE_RESIDENT_BEFORE_THE_CONV_KERNELS = 14.44 * 2**30
+
+
+def _granite_reader_sizes():
+    from elasticdl_tpu.models.granite_hybrid import (
+        granite_4_0_h_micro_cut as m,
+    )
+
+    c = m.cut_config()
+    inner = c.mamba_n_heads * c.mamba_d_head
+    conv = inner + 2 * c.mamba_n_groups * c.mamba_d_state
+    return {"batch": 1, "chunks": 8192 // c.mamba_chunk_size,
+            "chunk": c.mamba_chunk_size, "heads": c.mamba_n_heads,
+            "groups": c.mamba_n_groups,
+            "per": c.mamba_n_heads // c.mamba_n_groups,
+            "head_dim": c.mamba_d_head, "state": c.mamba_d_state,
+            "inner": inner, "conv": conv,
+            "in_proj": inner + conv + c.mamba_n_heads,
+            "hidden": c.hidden_size}
+
+
 def test_granite_cut_step_compiles_and_fits_one_v5e(granite_cut_one_chip):
     """The WHOLE training step of the granite-4.0-h-micro cut (772.2 M
     parameters at 16 bytes each, the largest state any cell holds;
@@ -829,25 +927,39 @@ def test_granite_cut_step_compiles_and_fits_one_v5e(granite_cut_one_chip):
         assert set(operands) <= {"bf16[32,8192,64]", "f32[32,8192,128]"}
     assert step.text.count("flash_fwd") >= 1
     assert step.text.count("flash_bwd") >= 1
-    # Nine mixers: the scan's forward kernel, its rematerialised twin and
-    # its backward kernel in each, every one known to the benchmark's
-    # readers by the chunked layout among its operands and results.
-    scans = [c for c in calls if c not in flash]
-    assert len(scans) == 27 and len(flash) + len(scans) == len(calls)
+    # Nine mixers: the convolution stage's forward kernel twice a layer
+    # (once rematerialised under `dots`, as the scan's) and its backward
+    # once.
+    convs = [c for c in calls if c[0] in GRANITE_CONV_CALLS.values()]
+    assert sum(results == GRANITE_CONV_CALLS["forward"]
+               for results, _ in convs) == 18
+    assert sum(results == GRANITE_CONV_CALLS["backward"]
+               for results, _ in convs) == 9
+    for results, operands in convs:
+        # xBC is read where it lies in the in-projection's result.
+        assert operands[0] == "bf16[1,8512,8192]"
+    # And the scan's forward kernel, its rematerialised twin and its
+    # backward kernel in each, every one known to the benchmark's readers
+    # by the chunked layout among its operands and results.
+    scans = [c for c in calls if c not in flash and c not in convs]
+    assert len(scans) == 27
+    assert len(flash) + len(scans) + len(convs) == len(calls)
     forward = "(f32[1,1,64,64,8192], f32[1,32,1,4096,128])"
     assert sum(results == forward for results, _ in scans) == 18
     for results, operands in scans:
         assert "bf16[1,1,64,64,8192]" in (results, *operands), results
     # The transposes round the calls move nothing: the compiler keeps
-    # these activations with the time minor already, so no copy, reshape
-    # or transpose of a tensor of x's size (or B's, under the scan's
-    # scope) is an operation of the step. (By way of a [.., chunks, chunk]
+    # these activations with the time minor already, so no copy, reshape,
+    # transpose or slice of a tensor of x's size (xBC's and the
+    # in-projection's result are larger; or B's, under the scan's scope)
+    # is an operation of the step. (By way of a [.., chunks, chunk]
     # shape they were: two copies each of x, y, dy and dx, 30 ms a step on
-    # the chip.)
+    # the chip; and xBC was a slice a layer, 0.22 ms.)
     moved = []
     for line in step.text[step.text.index("ENTRY"):].split("\n"):
         op = _HLO_OP.match(line)
-        if not op or op.group(2) not in ("copy", "reshape", "transpose"):
+        if not op or op.group(2) not in (
+                "copy", "reshape", "transpose", "slice"):
             continue
         sizes = [int(np.prod([int(d) for d in dims.split(",")]))
                  for _, dims in _HLO_ARRAY.findall(op.group(1)) if dims]
@@ -855,7 +967,8 @@ def test_granite_cut_step_compiles_and_fits_one_v5e(granite_cut_one_chip):
         if sizes and at_least <= max(sizes) < 8192 * 12544:
             moved.append(line.split(", metadata=")[0])
     assert not moved, moved[:3]
-    assert step.resident < HBM_BYTES, f"{step.resident / 2**30:.2f} GiB"
+    assert step.resident <= GRANITE_RESIDENT_BEFORE_THE_CONV_KERNELS, (
+        f"{step.resident / 2**30:.2f} GiB")
     # params + Adam m and v: 772,160,448 x 12 B.
     assert step.argument_bytes > 9.2e9
     assert {"f32[2048,8512]", "f32[4,4352]", "f32[4096,2048]",
@@ -879,3 +992,40 @@ def test_granite_cut_step_compiles_and_fits_one_v5e(granite_cut_one_chip):
         fusions["kLoop"])
     print(f"granite cut ({m.REMAT}): resident {step.resident / 2**30:.2f} "
           "GiB")
+
+
+def test_the_readers_take_the_conv_calls_for_the_mixers_and_not_the_scans(
+        granite_cut_one_chip, monkeypatch):
+    """`mixer_time_pct.granite` and `ssd_time_pct.granite` know an
+    operation by the shapes in its HLO line, results and operands alike
+    (`benchmark/metrics/_granite_ops.py`). Each of the compiled step's 27
+    calls of the convolution stage holds a shape that `mixer_shape` takes
+    (the taps, `[1, 4, 4352]`) and none that `scan_shape` takes (x is
+    `[1, 4096, 8192]` there, its `[1, 1, 64, 64, 8192]` view a bitcast
+    outside the call): counted with the mixers, and never charged to the
+    scan's roofline. The scan's own calls are still the scan's."""
+    from test_ssd_scan import _load
+
+    benchmark = os.path.join(
+        os.path.dirname(os.path.abspath(__file__)), "..", "benchmark")
+    monkeypatch.syspath_prepend(benchmark)  # its `lib`
+    ops = _load(os.path.join(benchmark, "metrics", "_granite_ops.py"))
+    z = _granite_reader_sizes()
+
+    def taken(call, test):
+        results, operands = call
+        return any(
+            test(tuple(int(d) for d in dims.split(",")), z)
+            for _, dims in _HLO_ARRAY.findall(" ".join([results, *operands]))
+            if dims)
+
+    calls = _kernel_calls(granite_cut_one_chip.text)
+    convs = [c for c in calls if c[0] in GRANITE_CONV_CALLS.values()]
+    assert len(convs) == 27
+    for call in convs:
+        assert taken(call, ops.mixer_shape), call
+        assert not taken(call, ops.scan_shape), call
+    scans = [c for c in calls if "f32[1,1,64,64,8192]" in (c[0], *c[1])
+             or "bf16[1,1,64,64,8192]" in (c[0], *c[1])]
+    assert len(scans) == 27 and all(
+        taken(c, ops.scan_shape) for c in scans)
