@@ -349,33 +349,10 @@ declare("ELASTICDL_JOIN_GATE_SECONDS", "float", 0.0,
         "boxes whose ~6.5 s compiles outlast a fixed gate scale the "
         "wait instead of churning membership.")
 
-# -- bench subsystem (elasticdl_tpu/bench/) --
-declare("ELASTICDL_BENCH_WATCHDOG_S", "float", 600.0,
-        "Hard per-benchmark wall-clock bound in the full bench run; a "
-        "wedged benchmark loses its own slot, not the run. 0 disables.")
-declare("ELASTICDL_BENCH_BUDGET_S", "float", 780.0,
-        "Soft shared budget for a FULL bench run: workloads stop "
-        "opening timed windows when it runs out (degrading sample "
-        "counts instead of dying) and the runner skips benchmarks that "
-        "no longer fit (recorded, never silent). Default sits under "
-        "the bench driver's historical ~870 s wall so the JSON line "
-        "always lands before an outer timeout. 0 disables.")
-declare("ELASTICDL_BENCH_WINDOWS", "int", 5,
-        "Timed windows per benchmark in the full run; each window "
-        "yields one examples/s sample for the bootstrap CI.")
-declare("ELASTICDL_BENCH_MIN_EFFECT", "float", 0.02,
-        "Relative effect below which a statistically significant bench "
-        "difference is still reported as noise (the regression gate's "
-        "practical-significance threshold).")
-declare("ELASTICDL_BENCH_BASELINE", "str", "",
-        "Explicit baseline BENCH json path for the verdict/gate; empty "
-        "searches the repo root for the newest parseable BENCH_r*.json.")
-
 # -- flight recorder (observability/flightrec.py) --
 declare("ELASTICDL_FLIGHTREC", "str", "auto",
         "Crash-dump flight recorder: 0/false/off disables; anything "
-        "else arms it wherever observability.setup() runs (and in "
-        "bench runs).")
+        "else arms it wherever observability.setup() runs.")
 declare("ELASTICDL_FLIGHTREC_CAPACITY", "int", 256,
         "Ring capacity: how many recent spans the flight recorder "
         "keeps in memory per process.")

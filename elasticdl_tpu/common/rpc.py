@@ -195,8 +195,8 @@ class RetryPolicy:
 
 
 # Per-method deadline/retry matrix (docs/ROBUSTNESS.md keeps the prose
-# version). EVERY spec method must appear here — tools/check_rpc_deadlines.py
-# fails the lint lane otherwise.
+# version). EVERY spec method must appear here — the rpc-deadlines rule
+# of tools/edl_lint fails the lint lane otherwise.
 METHOD_POLICIES = {
     # Master service: small control messages; get_task answers WAIT rather
     # than blocking, so short deadlines are safe.

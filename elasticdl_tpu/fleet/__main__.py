@@ -2,7 +2,7 @@
 
 Runs one harness for a fixed wall-clock window and prints the stats
 dict as JSON — the quickest way to eyeball push-vs-pull master cost at
-a given scale without going through the bench runner:
+a given scale without writing a test around the harness:
 
     python -m elasticdl_tpu.fleet --pods 200 --seconds 10 --mode push
     python -m elasticdl_tpu.fleet --pods 200 --seconds 10 --mode pull

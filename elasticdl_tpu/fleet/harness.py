@@ -775,17 +775,6 @@ class FleetHarness:
             out["policy_decisions"] = list(self.policy_decisions)
         return out
 
-    def fetch_summary_http(self):
-        """GET the master's /api/summary over real HTTP (render cost
-        included) — the bench's summary-render probe."""
-        import urllib.request
-
-        url = (
-            f"http://127.0.0.1:{self.master.exporter.port}/api/summary"
-        )
-        with urllib.request.urlopen(url, timeout=5.0) as res:
-            return json.loads(res.read().decode())
-
     def stop(self):
         self._stop.set()
         for t in self._threads:

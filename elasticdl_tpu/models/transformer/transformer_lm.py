@@ -79,7 +79,7 @@ def validate_remat_policy(remat, remat_policy):
 
 def flagship_config(max_len: int = 4096) -> "LMConfig":
     """The >=100M-param long-context config validated on a real chip
-    (tools/validate_flagship.py): 151M transformer params + 34M embeddings,
+    (benchmark cell lm_flagship.steady): 151M transformer params + 34M embeddings,
     head_dim 128 (the fast Pallas flash-attention tile).
 
     remat is OFF by default: the round-4 sweep on one TPU v5e (16 GB)

@@ -1,6 +1,6 @@
 """Stage-level data-plane instrumentation for the input pipeline.
 
-The bench attribution table (BENCH_r08) puts `input_wait` at 0.24-0.40
+The trainers' Timing summaries give `input_wait` as one large share
 of every PS-mode step, but it is a single opaque bucket: nothing says
 whether the time went to waiting on the master for a task lease, to the
 record reader, to decode, or to the h2d copy. This module decomposes the
@@ -8,8 +8,8 @@ feed path into named stages and lands every stage three ways at once:
 
 - a `Timing` phase (``input_<stage>``) on the Timing object the call
   site passes (the PS and local trainers' h2d), so
-  `bench/attribution.py` can split `input_wait` into sub-fractions
-  from the same phase summaries it already reads;
+  a reader of `Timing.summary()` can split `input_wait` into
+  sub-fractions from the same phase summaries it already reads;
 - a tracing span (``datapath.<stage>``) so Perfetto shows the feed
   path interleaved with train_step/push/pull spans, and, while a
   jax.profiler session is open, on the device trace's own clock;
@@ -55,7 +55,7 @@ QUEUE_CAPACITY_ENV = "ELASTICDL_DATAPATH_QUEUE_CAPACITY"
 QUEUE_WATERMARK_ENV = "ELASTICDL_DATAPATH_QUEUE_WATERMARK"
 
 # Canonical stage names; the Timing phase is "input_<stage>" so the
-# bench attribution layer can bucket them under input_wait.
+# summary's readers can bucket them under input_wait.
 STAGES = ("task", "read", "decode", "collate", "h2d", "starve")
 
 _registry = default_registry()

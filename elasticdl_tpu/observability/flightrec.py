@@ -2,10 +2,10 @@
 
 A bounded in-memory ring of recent step-phase spans and RPC timings per
 role, written to ``<dir>/flightrec-<role>.json`` when the process
-crashes (unhandled exception), receives SIGTERM, or a bench watchdog
-gives up on it — so a dead bench or drill leaves attributable evidence
-("died 41 s into ps_matrix:ps2-overlapped-bf16, last event a
-push_gradients wire wait") instead of an rc=124 and an empty log tail.
+crashes (unhandled exception), receives SIGTERM, or a watchdog gives
+up on it — so a dead job or drill leaves attributable evidence ("died
+41 s in, last event a push_gradients wire wait") instead of an rc=124
+and an empty log tail.
 
 Design constraints:
 
@@ -46,7 +46,7 @@ _prev_excepthook = None
 _prev_handlers = {}
 
 # Signals that mean "you are being killed, leave evidence". SIGTERM is
-# what k8s, the bench driver's `timeout`, and drills send.
+# what k8s, a driver's `timeout`, and drills send.
 _SIGNALS = (signal.SIGTERM,)
 
 

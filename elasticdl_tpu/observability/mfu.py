@@ -16,7 +16,7 @@ arguments to a StepCostModel once per minibatch. The model:
 A backend without cost_analysis or an un-lowerable step leaves the
 gauges absent — never a training failure. ELASTICDL_MFU=0 disables the
 lowering entirely. The peak comes from ONE table keyed by the
-`device_kind` jax reports (the bench reads the same table); a device
+`device_kind` jax reports (the one table the package has); a device
 that is not in it has no MFU — `peak_flops` raises, so nothing prints a
 utilization against a guessed denominator.
 """

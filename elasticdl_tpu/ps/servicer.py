@@ -370,7 +370,7 @@ class PserverServicer:
         _PS_VERSION.set(version)
         self._post_apply(version, snapshot)
         # apply_seconds lets the pushing worker split its RPC wait into
-        # wire vs apply time (the microbench matrix's breakdown).
+        # wire vs apply time (the push phase's wire/apply breakdown).
         return pb.PushGradientsResponse(
             accepted=True, version=version, apply_seconds=apply_seconds
         )

@@ -101,8 +101,8 @@ class PSClient:
         self._push_seq = 0
         # Optional common.timing.Timing: when bound (the PS trainer binds
         # its own), push_gradients records its serialize/wire/apply
-        # sub-phases there — the decomposition the microbench matrix and
-        # a flagged BENCH run need to attribute the dominant phase.
+        # sub-phases there — the decomposition tools/ps_push_probe.py and
+        # tools/step_report.py need to attribute the dominant phase.
         self.timing = None
         self._addrs = list(ps_addrs)
         self._worker_id = worker_id

@@ -1,7 +1,7 @@
 """Versioned per-table embedding-row cache for the PS trainer's prefetch.
 
 ``prefetch_embeddings`` was the PS step's single biggest host cost after
-the push itself (BENCH_r06: 280-775 ms/step) — and most of those pulls
+the push itself (host time, not the device's) — and most of those pulls
 re-fetch rows this worker saw a handful of steps ago. The cache keeps
 recently pulled rows per table, stamped with the PS model version at
 fill time, and serves a hit only while the row is younger than the

@@ -103,18 +103,6 @@ def test_chip_smoke_script_fails_without_a_tpu():
     assert '"ok"' not in res.stdout
 
 
-def test_full_bench_exits_nonzero_without_a_tpu():
-    res = _run(
-        [sys.executable, "-m", "elasticdl_tpu.bench", "--no-matrix"],
-        env={"PYTHONPATH": REPO},
-    )
-    assert res.returncode != 0
-    assert "no TPU" in res.stderr
-    line = json.loads(res.stdout.strip().splitlines()[-1])
-    assert line["details"]["platform"] == "cpu"
-    assert "no TPU" in line["details"]["error"]
-
-
 _CACHE_PROBE = """
 import json, os, subprocess, sys
 import jax

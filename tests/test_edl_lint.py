@@ -323,6 +323,70 @@ def test_env_knobs_negative(tmp_path):
     assert got <= {"no-registry", "stale-docs"}, got
 
 
+_FIXTURE_REGISTRY = """
+    def declare(name, kind, default, doc):
+        pass
+
+    declare("ELASTICDL_ROLE", "str", "", "read below, through a constant")
+    declare("ELASTICDL_OBS_DIR", "str", "", "read by a tool")
+    declare("ELASTICDL_MFU", "int", 1, "its last reader went")
+    declare("ELASTICDL_CHAOS", "str", "", "spelt beside a computed key")
+"""
+
+
+def test_env_knobs_flags_a_declared_knob_nothing_reads(tmp_path):
+    project = make_project(
+        tmp_path,
+        {
+            "elasticdl_tpu/common/knobs.py": _FIXTURE_REGISTRY,
+            "elasticdl_tpu/worker/reader.py": """
+            from elasticdl_tpu.common import knobs
+
+            ROLE_ENV = "ELASTICDL_ROLE"
+            role = knobs.get_str(ROLE_ENV)
+            mfu = "ELASTICDL_MFU"  # spelt, handed to no accessor
+            """,
+            "tools/a_tool.py": """
+            from elasticdl_tpu.common import knobs
+
+            where = knobs.raw("ELASTICDL_OBS_DIR")
+            """,
+            "elasticdl_tpu/worker/looper.py": """
+            from elasticdl_tpu.common import knobs
+
+            for env in ("ELASTICDL_CHAOS",):
+                knobs.raw(env)
+            """,
+        },
+    )
+    got = {k for k in keys(run_rule(project, "env-knobs"))
+           if k.startswith("unread:")}
+    assert got == {"unread:ELASTICDL_MFU"}, got
+
+
+def test_env_knobs_a_read_knob_is_not_unread(tmp_path):
+    project = make_project(
+        tmp_path,
+        {
+            "elasticdl_tpu/common/knobs.py": _FIXTURE_REGISTRY,
+            "elasticdl_tpu/worker/reader.py": """
+            from elasticdl_tpu.common import knobs
+            from elasticdl_tpu.worker.names import CHAOS_ENV
+
+            a = knobs.get_str("ELASTICDL_ROLE")
+            b = knobs.is_set("ELASTICDL_OBS_DIR")
+            c = knobs.get_int("ELASTICDL_MFU")
+            d = knobs.raw(CHAOS_ENV)
+            """,
+            "elasticdl_tpu/worker/names.py": """
+            CHAOS_ENV = "ELASTICDL_CHAOS"
+            """,
+        },
+    )
+    got = keys(run_rule(project, "env-knobs"))
+    assert not any(k.startswith("unread:") for k in got), got
+
+
 def test_knob_registry_semantics(monkeypatch):
     with pytest.raises(ValueError):
         knobs.declare("ELASTICDL_ROLE", "int", 3, "conflicting re-decl")

@@ -132,6 +132,30 @@ def test_datapath_stage_feeds_its_counters_from_the_spans_duration(
     assert dp._acc == {"h2d": dur} and dp._acc_records == 3
 
 
+@pytest.mark.parametrize("stage", ["task", "read", "decode", "collate",
+                                   "h2d", "starve"])
+def test_a_stage_lands_on_the_call_sites_timing_as_input_phase(stage):
+    """The trainers pass `timing=` to the h2d stage: the phase a reader
+    of `Timing.summary()` buckets under input_wait is `input_<stage>`,
+    one sample a stage, and a stage given no Timing writes none."""
+    from elasticdl_tpu.common.timing import Timing
+    from elasticdl_tpu.observability import datapath
+
+    assert stage in datapath.STAGES and len(datapath.STAGES) == 6
+    dp, timing = datapath.Datapath(enabled=True), Timing()
+    with dp.stage(stage, timing=timing):
+        pass
+    with dp.stage(stage):
+        pass
+    summary = timing.summary()
+    assert list(summary) == ["input_" + stage]
+    assert summary["input_" + stage]["count"] == 1
+    off = Timing()
+    with datapath.Datapath(enabled=False).stage(stage, timing=off):
+        pass
+    assert off.summary() == {}
+
+
 # ---------- a profiled job ----------
 
 NESTED_IN_STEP = ("datapath.decode", "trainer.world_check", "datapath.h2d",
@@ -308,8 +332,7 @@ def test_step_done_clock_writes_at_most_one_event_an_interval(tmp_path):
 
 def test_the_new_counter_passes_the_metric_name_check():
     res = subprocess.run(
-        [sys.executable, os.path.join(REPO, "tools",
-                                      "check_metric_names.py")],
+        [sys.executable, "-m", "tools.edl_lint", "--rule", "metric-names"],
         capture_output=True, text=True, cwd=REPO, timeout=300,
     )
     assert res.returncode == 0, res.stdout[-2000:] + res.stderr[-2000:]

@@ -1,8 +1,8 @@
 """Native PS serving path: id->row map, bulk lazy init, dedup, wire ids.
 
 Round-4 work: the per-id Python loop in EmbeddingTable.rows_for_ids and the
-np.unique dedup were the measured hot spots of the PS strategy (BENCH_r03:
-pull 2.5 s / push 6 s per step); they now run in native/idmap.cc. These
+np.unique dedup were the hot spots of the PS strategy's pull and push
+on the host; they now run in native/idmap.cc. These
 tests pin the semantics the Python paths had.
 """
 

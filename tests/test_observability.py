@@ -121,41 +121,6 @@ def test_timing_nested_and_exception_safety():
     assert s["outer"]["count"] == 1 and s["inner"]["count"] == 1
 
 
-def test_bench_aggregate_runs_median_and_spread_flag():
-    """The bench package's PS-mode reporting (VERDICT r4 #2, now in
-    elasticdl_tpu/bench/stats.py): the headline is the MEDIAN of N>=3
-    runs (never the max), the phase breakdown comes from the run
-    closest to the median, and a blown spread is visible in the summary
-    — a 20x-collapsed outlier run must drag the spread, not silently
-    be max-ed over."""
-    from elasticdl_tpu.bench import stats as bench_stats
-
-    runs = [
-        {"examples_per_sec": 10195.7, "phase": "a"},
-        {"examples_per_sec": 504.0, "phase": "b"},  # the r4 collapse
-        {"examples_per_sec": 9800.0, "phase": "c"},
-    ]
-    rep, med = bench_stats.representative_run(runs)
-    assert med == 9800.0  # median, not max
-    assert rep["phase"] == "c"  # breakdown from the median run
-    summary = bench_stats.summarize(
-        [r["examples_per_sec"] for r in runs]
-    )
-    assert summary["spread"] > 1.25  # the outlier is loud
-
-    steady = [
-        {"examples_per_sec": 9000.0},
-        {"examples_per_sec": 9500.0},
-        {"examples_per_sec": 9200.0},
-    ]
-    rep, med = bench_stats.representative_run(steady)
-    assert med == 9200.0 and rep is steady[2]
-    summary = bench_stats.summarize(
-        [r["examples_per_sec"] for r in steady]
-    )
-    assert summary["spread"] < 1.25
-
-
 # ---------- unified observability plane ----------
 
 

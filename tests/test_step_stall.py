@@ -66,6 +66,76 @@ def _held_up_here(seconds):
     time.sleep(seconds)
 
 
+def _steps_done():
+    return default_registry().get("edl_worker_steps_done_total").value
+
+
+def _until_stamped(more, since, within=20.0):
+    """Wait until the clock has stamped `more` steps past `since` (a
+    reading of `_steps_done`). A dispatcher that sleeps only after this
+    measures its drought from the stamp, as the clock does, and not from
+    a hand-over the clock's thread may wake to late."""
+    deadline = time.monotonic() + within
+    while _steps_done() < since + more:
+        assert time.monotonic() < deadline, "the clock stamped no step"
+        time.sleep(0.001)
+
+
+class _DeviceTime:
+    """Stands in for the clock module's `time`: `time()` is where the
+    newest finished step of the test's device put it, so the intervals
+    the slow rule sees are the test's own, whatever the scheduler does to
+    the threads. Waits (`monotonic`) stay real."""
+
+    monotonic = staticmethod(time.monotonic)
+
+    def __init__(self):
+        self.now = 1000.0
+
+    def time(self):
+        return self.now
+
+
+class _TimedLoss(_Loss):
+    """Done at `done_at` on the device's time; the thread that waited
+    for it gets to run `stamp_late` after that."""
+
+    def __init__(self, device, done_at, stamp_late=0.0):
+        super().__init__()
+        self._device, self._at = device, done_at + stamp_late
+
+    def block_until_ready(self):
+        super().block_until_ready()
+        self._device.now = self._at
+
+
+def _device_steps(monkeypatch, intervals, late=()):
+    """One loss a step on a device time of the test's own, which the
+    clock module reads from here on: step i is done `intervals[i]` after
+    the step before it; the stamps of the steps in `late` come 0.06 s
+    after that."""
+    device = _DeviceTime()
+    monkeypatch.setattr(step_clock, "time", device)
+    at, losses = device.now, []
+    for i, interval in enumerate(intervals):
+        at += interval
+        losses.append(_TimedLoss(device, at, 0.06 if i in late else 0.0))
+    return losses
+
+
+def _queued_then_done(clock, losses):
+    """The whole run queued on the device before its first step is done:
+    the clock never finds the queue empty behind a stamped step."""
+    def body():
+        for step, loss in enumerate(losses, start=1):
+            clock.dispatched(step, loss)
+        for loss in losses:
+            loss.ready.set()
+
+    _as_dispatcher(body)
+    clock.close()
+
+
 def test_ordinary_gaps_write_no_stall(event_log):
     before = [_stalls(c) for c in ("dry", "slow", "late_stamp", "profile")]
     clock = StepDoneClock(emit_interval=0.0)
@@ -149,28 +219,20 @@ def test_a_drought_over_the_rule_writes_one_stall_with_the_dispatchers_stack(
 
 
 def test_a_slow_device_with_work_queued_writes_a_stall_without_stacks(
-        event_log):
+        event_log, monkeypatch):
     count, seconds = _stalls("slow")
     clock = StepDoneClock(emit_interval=0.0)
-    losses = [_Loss() for _ in range(16)]
-
-    def body():
-        for step, loss in enumerate(losses, start=1):
-            clock.dispatched(step, loss)
-        for i, loss in enumerate(losses):
-            time.sleep(0.15 if i == 12 else 0.01)
-            loss.ready.set()
-
-    _as_dispatcher(body)
-    clock.close()
+    _queued_then_done(clock, _device_steps(
+        monkeypatch, [0.15 if i == 12 else 0.01 for i in range(16)]))
     (stall,) = event_log("step_stall")
     assert stall["step"] == 13 and stall["cause"] == "slow"
     assert stall["dry_s"] == 0.0 and "samples" not in stall
     assert "wake_late_s" not in stall
-    assert stall["interval_s"] > 1.5 * stall["median_s"]
+    assert stall["interval_s"] == pytest.approx(0.15, abs=1e-5)
+    assert stall["median_s"] == pytest.approx(0.01, abs=1e-5)
     # The step after it came a step later, not sooner: the device was
     # slow, the stamp was not late.
-    assert 0.0 < stall["next_interval_s"] < 0.05
+    assert stall["next_interval_s"] == pytest.approx(0.01, abs=1e-5)
     after = _stalls("slow")
     assert after[0] == count + 1
     assert after[1] - seconds == pytest.approx(
@@ -178,40 +240,22 @@ def test_a_slow_device_with_work_queued_writes_a_stall_without_stacks(
     assert _stamped_steps(event_log) == list(range(1, 17))
 
 
-class _LateLoss(_Loss):
-    """Ready on time, but the waiting thread is kept from running for a
-    while after it (a held GIL): the stamp is late, the step was not."""
-
-    def __init__(self, held):
-        super().__init__()
-        self._held = held
-
-    def block_until_ready(self):
-        super().block_until_ready()
-        time.sleep(self._held)
-
-
 def test_a_late_stamp_is_told_from_a_slow_step_by_the_step_after_it(
-        event_log):
+        event_log, monkeypatch):
+    """Step 13 is ready on time, but the waiting thread is kept from
+    running for 60 ms after it (a held GIL): the stamp is late, the step
+    was not, and step 14's stamp comes that much sooner."""
     slow, late = _stalls("slow")[0], _stalls("late_stamp")[0]
     clock = StepDoneClock(emit_interval=0.0)
-    losses = [_Loss() for _ in range(16)]
-    losses[12] = _LateLoss(0.06)
-
-    def body():
-        for step, loss in enumerate(losses, start=1):
-            clock.dispatched(step, loss)
-        for loss in losses:  # the device: a step every 80 ms, on time
-            time.sleep(0.08)
-            loss.ready.set()
-
-    _as_dispatcher(body)
-    clock.close()
+    # The device: a step every 80 ms, on time.
+    _queued_then_done(clock, _device_steps(
+        monkeypatch, [0.08] * 16, late={12}))
     (stall,) = event_log("step_stall")
     assert stall["step"] == 13 and stall["cause"] == "late_stamp"
+    assert stall["interval_s"] == pytest.approx(0.14, abs=1e-5)
     assert stall["interval_s"] > 1.5 * stall["median_s"]
     assert stall["interval_s"] + stall["next_interval_s"] == pytest.approx(
-        2 * stall["median_s"], abs=0.03)
+        2 * stall["median_s"], abs=1e-5)
     assert _stalls("late_stamp")[0] == late + 1
     assert _stalls("slow")[0] == slow
     assert _stamped_steps(event_log) == list(range(1, 17))
@@ -238,8 +282,10 @@ def test_a_step_that_does_not_come_has_every_threads_stack_dumped(
 
     _as_dispatcher(body)
     clock.close()
-    (stall,) = event_log("step_stall")
-    assert (stall["step"], stall["cause"]) == (13, "slow")
+    # A 10 ms sleep that the scheduler stretches past the slow rule's
+    # 50 ms writes a record of its own step: this test reads step 13's.
+    (stall,) = [e for e in event_log("step_stall") if e["step"] == 13]
+    assert stall["cause"] == "slow"
     dumped = stall["frozen_stacks"]
     assert dumped[0].startswith("Timeout (0:00:00.1")
     heads = [x for x in dumped if x.startswith(("Thread", "Current"))]
@@ -251,19 +297,10 @@ def test_a_step_that_does_not_come_has_every_threads_stack_dumped(
     assert _stamped_steps(event_log) == list(range(1, 15))
 
 
-def test_a_slow_last_step_is_written_at_the_close(event_log):
+def test_a_slow_last_step_is_written_at_the_close(event_log, monkeypatch):
     clock = StepDoneClock(emit_interval=0.0)
-    losses = [_Loss() for _ in range(12)]
-
-    def body():
-        for step, loss in enumerate(losses, start=1):
-            clock.dispatched(step, loss)
-        for i, loss in enumerate(losses):
-            time.sleep(0.15 if i == 11 else 0.01)
-            loss.ready.set()
-
-    _as_dispatcher(body)
-    clock.close()
+    _queued_then_done(clock, _device_steps(
+        monkeypatch, [0.15 if i == 11 else 0.01 for i in range(12)]))
     (stall,) = event_log("step_stall")
     assert (stall["step"], stall["cause"]) == (12, "slow")
     assert stall["next_interval_s"] is None
@@ -272,9 +309,12 @@ def test_a_slow_last_step_is_written_at_the_close(event_log):
 def test_the_first_steps_and_a_broken_run_reckon_no_interval(event_log):
     clock = StepDoneClock(emit_interval=0.0)
 
+    since = _steps_done()
+
     def body():
         clock.dispatched(1, 0.25)
-        time.sleep(0.2)  # a compile: no median yet, but a drought
+        _until_stamped(1, since)
+        time.sleep(0.5)  # a compile: no median yet, but a drought
         clock.dispatched(2, 0.25)
         time.sleep(0.02)
         clock.dispatched(7, 0.25)  # not the next step: no interval
@@ -289,24 +329,37 @@ def test_the_first_steps_and_a_broken_run_reckon_no_interval(event_log):
 
 def test_stalls_past_the_cap_are_counted_and_not_written(
         event_log, monkeypatch):
+    """Every step but the first comes after a drought ten times the
+    rule, reckoned from the stamp of the step before it. Should the
+    clock's thread lose its turn for all of that between a stamp and its
+    look at the queue, that one drought goes unseen: so what is held is
+    the cap, the order and the count, with five droughts to spare, and
+    not that each step has a record."""
     monkeypatch.setattr(step_clock, "DRY_RULE_SECONDS", 0.005)
     count, _ = _stalls("dry")
     clock = StepDoneClock(emit_interval=3600.0)
-    total = step_clock.MAX_STALL_EVENTS + 5
+    cap = step_clock.MAX_STALL_EVENTS
+    total = cap + 5
+    since = _steps_done()
 
     def body():
         clock.dispatched(1, 0.25)
         for step in range(2, total + 2):
-            time.sleep(0.02)
+            _until_stamped(step - 1, since)
+            time.sleep(0.05)
             clock.dispatched(step, 0.25)
 
     _as_dispatcher(body)
     clock.close()
     written = event_log("step_stall")
-    assert len(written) == step_clock.MAX_STALL_EVENTS
-    assert [e["step"] for e in written] == list(
-        range(2, step_clock.MAX_STALL_EVENTS + 2))
-    assert _stalls("dry")[0] == count + total
+    steps = [e["step"] for e in written]
+    assert len(written) == cap and {e["cause"] for e in written} == {"dry"}
+    assert steps == sorted(set(steps)) and steps[0] >= 2
+    # The first `cap` droughts seen are the ones written, in order; the
+    # ones after them are counted and not written.
+    counted = _stalls("dry")[0] - count
+    assert cap < counted <= total
+    assert steps[-1] <= total + 1 - (counted - cap)
     assert _stamped_steps(event_log) == list(range(1, total + 2))
 
 
@@ -421,7 +474,9 @@ def test_the_watchdog_puts_the_rules_record_into_event_and_log(event_log):
     slow = by_worker[7]
     assert slow["reason"] == "slow" and slow["task_id"] == tid
     assert slow["task_type"] == "TRAINING" and slow["samples"] == 6
-    assert slow["age_s"] > slow["threshold_s"] >= 3 * slow["mean_s"] - 1e-6
+    # Both sides are rounded to 1e-6: 3 x 5e-7 on the mean, 5e-7 on the
+    # threshold.
+    assert slow["age_s"] > slow["threshold_s"] >= 3 * slow["mean_s"] - 3e-6
     # The silent rule has no task to name.
     assert by_worker[8]["reason"] == "silent" and "age_s" not in by_worker[8]
     line = next(x for x in heard.lines

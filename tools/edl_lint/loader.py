@@ -38,7 +38,7 @@ class Project:
 
     @classmethod
     def load(cls, root, roots=("elasticdl_tpu", "tools"),
-             extra_files=("bench.py", "__graft_entry__.py")):
+             extra_files=("__graft_entry__.py",)):
         files = {}
         parse_errors = []
 

@@ -5,8 +5,8 @@ Per-file pass: an import binding never used anywhere in its module
 surface; a name quoted in `__all__` counts as used).
 
 Whole-program pass: a module-level function or class in elasticdl_tpu/
-whose name is referenced NOWHERE else across the library, tools/,
-tests/, and bench.py — not as a Name, not as an attribute, not inside
+whose name is referenced NOWHERE else across the library, tools/ and
+tests/ — not as a Name, not as an attribute, not inside
 any string literal (covers getattr-by-name, model-zoo lookup strings,
 and doc references). Decorated definitions are exempt (registration
 side effects), as are dunders and `main`.
@@ -175,7 +175,7 @@ class DeadCodeRule(Rule):
                         sf.rel,
                         node.lineno,
                         f"{kind} `{name}` is referenced nowhere in the "
-                        f"repo (library, tools, tests, bench) — delete "
+                        f"repo (library, tools, tests) — delete "
                         f"it or wire it in",
                         key=f"dead:{name}",
                     )

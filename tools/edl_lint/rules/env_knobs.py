@@ -12,11 +12,15 @@ rule enforces the contract statically:
 3. duplicate `declare()` calls for one name with conflicting
    type/default are errors;
 4. docs/KNOBS.md must match the table generated from the registry
-   (`python -m tools.edl_lint --write-knob-docs` refreshes it).
+   (`python -m tools.edl_lint --write-knob-docs` refreshes it);
+5. a knob the registry declares and no module under elasticdl_tpu/ or
+   tools/ reads through an accessor is an error: its last reader went,
+   and the declaration goes with it.
 
 Key names are resolved through literals, module constants, and imported
 constants (`observability.OBS_DIR_ENV`); an unresolvable dynamic key is
-not flagged.
+not flagged, and counts for clause 5 as a read of every knob name its
+file spells.
 """
 
 import ast
@@ -51,12 +55,31 @@ def _declared_names():
     return {k.name for k in knobs.all_knobs()}
 
 
+def _knobs_call(node, minfo):
+    """`declare`, `get_int`, ... for a call on the knobs module, else
+    None."""
+    dotted = minfo.dotted(node.func) or ""
+    if dotted.startswith(("elasticdl_tpu.common.knobs.", "knobs.")):
+        return dotted.rsplit(".", 1)[-1]
+    return None
+
+
+def _accessor_calls(sf, minfo, resolver):
+    """(node, accessor, name) for every `knobs.get_*/raw/is_set` call of
+    one file; name is None where the key does not resolve to a string."""
+    for node in ast.walk(sf.tree):
+        if isinstance(node, ast.Call) and node.args:
+            tail = _knobs_call(node, minfo)
+            if tail in _ACCESSORS:
+                yield node, tail, resolver.resolve_str(node.args[0], minfo)
+
+
 class EnvKnobsRule(Rule):
     name = "env-knobs"
     doc = (
         "ELASTICDL_* environment reads must go through the "
-        "common/knobs.py registry; accessor names must be declared; "
-        "docs/KNOBS.md must match the registry."
+        "common/knobs.py registry; accessor names must be declared "
+        "and declared names read; docs/KNOBS.md must match the registry."
     )
 
     def check(self, project):
@@ -69,6 +92,33 @@ class EnvKnobsRule(Rule):
             yield from self._check_file(sf, minfo, resolver, declared)
         yield from self._check_declarations(project)
         yield from self._check_docs(project)
+
+    def read_names(self, project):
+        """Every knob name that a module under elasticdl_tpu/ or tools/
+        (the registry apart) hands to an accessor, resolved as clause 2
+        resolves it. A file that hands an accessor a key it computes (a
+        loop over names, a helper's parameter) reads every ELASTICDL_*
+        name it spells: unresolvable is not unread."""
+        resolver = project.resolver
+        read = set()
+        for root in ("elasticdl_tpu", "tools"):
+            for sf in project.iter_files(root):
+                if sf.rel == _KNOBS_REL:
+                    continue
+                keys = {
+                    key for _, _, key in _accessor_calls(
+                        sf, resolver.module(sf.rel), resolver
+                    )
+                }
+                if None in keys:
+                    keys |= {
+                        node.value for node in ast.walk(sf.tree)
+                        if isinstance(node, ast.Constant)
+                        and isinstance(node.value, str)
+                        and node.value.startswith("ELASTICDL_")
+                    }
+                read |= keys - {None}
+        return read
 
     # -- raw environ reads ----------------------------------------------
 
@@ -92,32 +142,26 @@ class EnvKnobsRule(Rule):
                         key = resolver.resolve_str(node.args[0], minfo)
                         if key and key.startswith("ELASTICDL_"):
                             yield self._raw_read(sf, node, key)
-                elif dotted.startswith(
-                    "elasticdl_tpu.common.knobs."
-                ) or dotted.startswith("knobs."):
-                    tail = dotted.rsplit(".", 1)[-1]
-                    if tail == "declare":
-                        yield Finding(
-                            self.name,
-                            sf.rel,
-                            node.lineno,
-                            "knobs.declare() outside common/knobs.py — "
-                            "declarations live centrally so defaults "
-                            "cannot diverge",
-                            key="declare-outside-registry",
-                        )
-                    elif tail in _ACCESSORS and node.args:
-                        key = resolver.resolve_str(node.args[0], minfo)
-                        if key is not None and key not in declared:
-                            yield Finding(
-                                self.name,
-                                sf.rel,
-                                node.lineno,
-                                f"knobs.{tail}({key!r}) reads an "
-                                f"UNDECLARED knob — declare it in "
-                                f"common/knobs.py",
-                                key=f"undeclared:{key}",
-                            )
+                elif _knobs_call(node, minfo) == "declare":
+                    yield Finding(
+                        self.name,
+                        sf.rel,
+                        node.lineno,
+                        "knobs.declare() outside common/knobs.py — "
+                        "declarations live centrally so defaults "
+                        "cannot diverge",
+                        key="declare-outside-registry",
+                    )
+        for node, tail, key in _accessor_calls(sf, minfo, resolver):
+            if key is not None and key not in declared:
+                yield Finding(
+                    self.name,
+                    sf.rel,
+                    node.lineno,
+                    f"knobs.{tail}({key!r}) reads an UNDECLARED knob — "
+                    f"declare it in common/knobs.py",
+                    key=f"undeclared:{key}",
+                )
 
     def _raw_read(self, sf, node, key):
         return Finding(
@@ -165,6 +209,18 @@ class EnvKnobsRule(Rule):
                     f"knob {name} declared twice with conflicting "
                     f"type/default (first at line {prior[1]})",
                     key=f"duplicate:{name}",
+                )
+        read = self.read_names(project)
+        for name, (_, lineno) in seen.items():
+            if name not in read:
+                yield Finding(
+                    self.name,
+                    sf.rel,
+                    lineno,
+                    f"knob {name} is declared and never read: no module "
+                    f"under elasticdl_tpu/ or tools/ hands it to an "
+                    f"accessor — delete the declaration",
+                    key=f"unread:{name}",
                 )
 
     # -- generated docs freshness ----------------------------------------

@@ -30,8 +30,7 @@ from tools.edl_lint.dataflow import get_engine, self_attr
 _ENTRY_NAMES = {"train_minibatch", "train_lease_minibatch"}
 _ENTRY_SCOPE = ("elasticdl_tpu/worker/",)
 # Reachability stays inside the training layers; instrumentation
-# (observability/), transport helpers (proto/), and the bench harness
-# have their own rules.
+# (observability/) and transport helpers (proto/) have their own rules.
 _WALK_SCOPE = (
     "elasticdl_tpu/worker/",
     "elasticdl_tpu/parallel/",

@@ -1,7 +1,6 @@
 """metric-names: the metric namespace stays coherent.
 
-Port of tools/check_metric_names.py into the unified framework (the
-original script remains as a thin shim). Walks every registration call
+Walks every registration call
 site (`<registry>.counter/gauge/histogram("name", ...)`) via the shared
 AST cache and enforces the scheme docs/OBSERVABILITY.md promises:
 

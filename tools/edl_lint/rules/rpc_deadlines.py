@@ -1,7 +1,6 @@
 """rpc-deadlines: no call site escapes the deadline/retry plane.
 
-Port of tools/check_rpc_deadlines.py into the unified framework (the
-original script remains as a thin shim). Two invariants:
+Two invariants:
 
 1. every method of every ServiceSpec has an explicit entry in
    rpc.METHOD_POLICIES with a positive deadline;
@@ -29,7 +28,6 @@ _FORBIDDEN = (
 
 _ALLOWED = {
     os.path.join("elasticdl_tpu", "common", "rpc.py"),
-    os.path.join("tools", "check_rpc_deadlines.py"),  # shim docstring
 }
 
 

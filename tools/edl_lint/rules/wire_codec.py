@@ -6,7 +6,7 @@ received payloads, the int8 block-scaled codec, packed span
 offsets — into common/tensor_utils.py, which owns both sides of the
 wire format. A raw `.tobytes()` / `frombuffer()` in any other module
 that touches the proto surface is how copy-per-tensor serialization
-(the 438 ms/step BENCH_r06 found) silently comes back: someone builds
+silently comes back: someone builds
 one more message by hand instead of packing a span. This rule flags
 every such call in modules that import the generated proto module;
 modules that never touch protos (binary file readers like

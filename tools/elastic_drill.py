@@ -28,8 +28,8 @@ subprocess, polls get_job_status, injects its fault once training
 provably progresses, drains to completion, scrapes rpc retry/breaker
 counters from each role's advertised /metrics endpoint, and checks for
 leftover processes at exit. Usable standalone
-(`python tools/elastic_drill.py --scenario ps-flap`), from the e2e tests,
-and from bench.py (which folds rejoin_s into the benchmark details).
+(`python tools/elastic_drill.py --scenario ps-flap`) and from the e2e
+tests.
 """
 
 import argparse

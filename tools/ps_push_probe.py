@@ -1,9 +1,9 @@
-"""Decompose the PS push phase into its limiters (VERDICT r4 #3).
+"""Decompose the PS push phase into its limiters.
 
-The driver bench's PS-mode DeepFM spends 80-95% of its step in
-`push_gradients` while the device step is ~0.1 ms. This probe measures
-every component of that phase IN ISOLATION, with the exact shapes the
-bench pushes (batch 16384 x 39 Criteo fields, wide [V,1] + deep [V,8]
+A PS-mode DeepFM job spends most of its step in `push_gradients` while
+the device step is a fraction of a millisecond. This probe measures
+every component of that phase IN ISOLATION, with the exact shapes such
+a job pushes (batch 16384 x 39 Criteo fields, wide [V,1] + deep [V,8]
 adam tables on 2 shards), so the PS cell can carry a limiter
 decomposition (PERF.md, "Where the time goes"):
 
@@ -22,7 +22,7 @@ decomposition (PERF.md, "Where the time goes"):
 
 Run: `python tools/ps_push_probe.py [--batch 16384]`. Prints one JSON
 object; no TPU needed (the probe covers the host/RPC side — the device
-step is measured by bench.py).
+step is for a PS cell of the benchmark to measure).
 """
 
 import argparse

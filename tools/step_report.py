@@ -11,9 +11,8 @@ merges what the deep-profiling plane already wrote to disk —
 — into one "where did this step go" table per worker role: the fraction
 of step time (batch_process wall) spent in compute / serialize / PS
 wire / recompile / other, plus a compile-cause summary and the memory
-watermark timeline. The same bucket semantics as the bench attribution
-table (elasticdl_tpu/bench/attribution.py), derived from spans instead
-of trainer Timing, so live jobs and benches read on one scale.
+watermark timeline. Derived from spans, not from trainer Timing, so
+any job that wrote its traces reads on the one scale.
 
 Offline span sums cannot see nesting, so compute is derived as the
 batch remainder after the known non-compute spans — a conservative

@@ -5,7 +5,7 @@ each PS, each worker) into the job's obs/metrics directory. This tool:
 
   1. merges them into a single Chrome-trace JSON (`--out merged.json`)
      loadable in Perfetto (https://ui.perfetto.dev) or chrome://tracing;
-  2. prints the per-phase summary the benches used to hand-roll: per
+  2. prints the per-phase summary: per
      process and span name, total/count/mean plus p50/p99 over complete
      ("X") events;
   3. with --task, filters to one task's cross-process chain and prints it
