@@ -121,23 +121,31 @@ class Lfm2MoeConfig:
         return nn.initializers.normal(self.initializer_range)
 
 
-def rotary(x, theta, positions=None):
+def rotary(x, theta, positions=None, inv_freq=None, scale=None):
     """x [B, S, H, Dh] -> x turned by its position, over the whole head:
     x * cos + rotate_half(x) * sin with angles position * theta^(-2i / Dh)
     for i < Dh / 2, repeated over the two halves (the HF `default` rope).
     `positions` [S] are the rows' positions where they are not 0 .. S - 1
-    (a sequence that holds two copies of a record). In float32."""
+    (a sequence that holds two copies of a record). A long-context
+    frequency scaling hands in its own table `inv_freq` [Dh / 2] in place
+    of theta's, and the `scale` its cos and sin are multiplied by (YaRN's
+    `attention_factor`). In float32."""
     s, dh = x.shape[1], x.shape[-1]
     f32 = jnp.float32
-    inv_freq = theta ** (-jnp.arange(0, dh, 2, dtype=f32) / dh)
+    if inv_freq is None:
+        inv_freq = theta ** (-jnp.arange(0, dh, 2, dtype=f32) / dh)
     if positions is None:
         positions = jnp.arange(s, dtype=f32)
     angles = positions.astype(f32)[:, None] * inv_freq[None]  # [S, Dh/2]
     angles = jnp.concatenate([angles, angles], axis=-1)[None, :, None, :]
     x = x.astype(f32)
     x1, x2 = jnp.split(x, 2, axis=-1)
-    return x * jnp.cos(angles) + jnp.concatenate(
-        [-x2, x1], axis=-1) * jnp.sin(angles)
+
+    def scaled(table):
+        return table if scale is None else table * scale
+
+    return x * scaled(jnp.cos(angles)) + jnp.concatenate(
+        [-x2, x1], axis=-1) * scaled(jnp.sin(angles))
 
 
 class Attention(nn.Module):

@@ -31,9 +31,11 @@ matrix never materializes — in EITHER pass:
   elementwise pass and streamed like lse.
 
 The mask is a hashable description handed in where the scores would be
-masked: `False` (every position sees every other), `True` (causal) or
+masked: `False` (every position sees every other), `True` (causal),
 `BlockDiffusion(block, half)` (a clean and a noised copy of a record as
-one sequence of 2 * half rows, see the class). One function,
+one sequence of 2 * half rows, see the class) or `Band(window)` (causal,
+and a row sees its last `window` positions alone, its own among them; a
+window that covers the sequence IS `True`). One function,
 `_tile_kinds`, tells a tile's kind from the description and the plain
 ints `(i, j, block_q, block_k)`: not run; whole, accumulated by a body
 with no mask at all (every position is seen, and a select whose predicate
@@ -42,11 +44,12 @@ is all true returns its input); or crossed, masked. At trace time
 rows forward, by columns backward: the orders the sums have always been
 made in), each with its kind; the list's length is the grid's second axis
 (`grid_steps`: 10 / 36 / 136 a batch*head at S 4096 / 8192 / 16384 under
-the causal mask, 80 under `BlockDiffusion(4, 8192)`, over 1024 x 1024
-tiles), so no grid step is skipped and a row's (column's) last run tile
-is followed at once by the next one's first, its blocks fetched behind
-it. Index maps and kernel bodies read the step's `(i, j)`, its kind and
-whether it starts or ends a row off that one list, by arithmetic
+the causal mask, 80 under `BlockDiffusion(4, 8192)`, 31 under `Band(1024)`
+at S 16384, over 1024 x 1024 tiles), so no grid step is skipped and a
+row's (column's) last run tile is followed at once by the next one's
+first, its blocks fetched behind it. Index maps and kernel bodies read
+the step's `(i, j)`, its kind and whether it starts or ends a row off that
+one list, by arithmetic
 (`_step_value`: a constant plus each change the step has reached;
 `_at_any`; a division where every tile of the grid runs, as under
 `False`), not through a table operand: the calls stay q, k, v -> o, lse
@@ -59,8 +62,14 @@ diffusion a tile is crossed in one of three ways (clean rows on their own
 tile of clean columns, noised rows on it, noised rows on their own tile
 of noised columns: `block_diffusion_tile_kinds`), each with equal blocks
 a constant too, and a noised row's run set is not contiguous (`{0..i}`
-and `n + i`): the list holds it as it is. Every causal kind gives the
-bits of masking every tile whole, forward and backward. Under
+and `n + i`): the list holds it as it is. Under a band of n tiles
+(`window = n * tile`, equal blocks: `_check_mask` refuses another where
+the kernel runs) row i runs tiles i - n .. i: the last crossed by the
+diagonal (the causal way mask), the first by the window's far edge, where
+a row sees the columns `col_local > row_local` (a constant of the trace as
+well), those between whole; at a window of one tile no run tile is whole.
+Every causal kind gives the bits of masking every tile whole, forward and
+backward. Under
 ring/Ulysses sequence parallelism (parallel/ring_attention.py) the
 per-device S is the block, so VMEM bounds the per-shard sequence, not the
 global one.
@@ -153,13 +162,37 @@ class BlockDiffusion(NamedTuple):
     half: int
 
 
+class Band(NamedTuple):
+    """The sliding-window mask: row r sees column c iff c <= r and r - c <
+    `window` (HF's `kv_idx > q_idx - sliding_window`: the row's own
+    position and the `window` - 1 before it)."""
+
+    window: int
+
+
 def _unmasked(mask):
-    return not isinstance(mask, BlockDiffusion) and not mask
+    return not isinstance(mask, (BlockDiffusion, Band)) and not mask
+
+
+def _plain(mask, s):
+    """A band that covers the sequence is the causal mask itself."""
+    if isinstance(mask, Band) and mask.window >= s:
+        return True
+    return mask
 
 
 def _check_mask(mask, s, block_q=None, block_k=None):
     """A description the sequence (and, where the kernel runs, its tiles)
     cannot carry raises."""
+    if isinstance(mask, Band):
+        if mask.window < 1:
+            raise ValueError(f"flash_attention: {mask} sees no position")
+        tiles = {block_q, block_k} - {None}
+        if len(tiles) > 1 or any(mask.window % tile for tile in tiles):
+            raise ValueError(
+                f"flash_attention: a window of {mask.window} is not a "
+                f"whole number of equal tiles ({block_q}, {block_k})")
+        return
     if not isinstance(mask, BlockDiffusion):
         return
     if s != 2 * mask.half or mask.half % mask.block:
@@ -187,6 +220,11 @@ def dense_mask(mask, s_q, s_k):
             (x[:, None], x[None, :]) for x in (noised, beta))
         return jnp.where(
             rn, jnp.where(cn, cb == rb, cb < rb), ~cn & (cb <= rb))
+    if isinstance(mask, Band):
+        _check_mask(mask, s_q)
+        ahead = (jnp.arange(s_q)[:, None] + (s_k - s_q)
+                 - jnp.arange(s_k)[None, :])
+        return (ahead >= 0) & (ahead < mask.window)
     if mask:
         return jnp.tril(jnp.ones((s_q, s_k), bool), k=s_k - s_q)
     return None
@@ -246,6 +284,18 @@ def block_diffusion_scores(half, block, block_q=DEFAULT_BLOCK_Q,
     return half * (half + block), run * bq * bk
 
 
+def band_scores(s, window, block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K):
+    """(needed, run) scores of one batch*head under `Band(window)`: what
+    the mask lets through, W (W + 1) / 2 + (S - W) W at W = min(window, S),
+    and what the tiles that run hold, at the tiles the kernel takes for
+    these blocks."""
+    mask = _plain(Band(window), s)
+    bq, bk = _clamp_blocks(s, block_q, block_k, mask)
+    w = min(window, s)
+    return (w * (w + 1) // 2 + (s - w) * w,
+            grid_steps(mask, s, bq, bk) * bq * bk)
+
+
 def grid_steps(mask, s, block_q, block_k):
     """Steps a batch*head of either pass's grid: both enumerate the tiles
     that run under the mask's description, and nothing else (10 / 36 / 136
@@ -272,6 +322,16 @@ def _causal_mask_scores(scores, i, j, block_q, block_k):
     if block_q != block_k:
         row = row + (i * block_q - j * block_k)
     return jnp.where(row >= col, scores, NEG_INF)
+
+
+def _far_edge_mask_scores(scores):
+    """Mask the score tile the far edge of a band crosses: with the window
+    a whole number of equal tiles the tile's first column lies `window`
+    before its first row, so a row sees the columns past its own place in
+    the tile: a constant of the trace, as the diagonal's mask is."""
+    row = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+    return jnp.where(col > row, scores, NEG_INF)
 
 
 def _block_mask_scores(scores, sees, mask, i, j, block_q, block_k):
@@ -319,6 +379,10 @@ def _way_masks(mask, i, j, block_q, block_k):
     the masks read with unequal blocks alone) or plain ints."""
     if _unmasked(mask):
         return ()
+    if isinstance(mask, Band):
+        # Equal tiles (`_check_mask`): by the diagonal, by the far edge.
+        return (lambda s: _causal_mask_scores(s, i, j, block_q, block_k),
+                _far_edge_mask_scores)
     if not isinstance(mask, BlockDiffusion):
         return (lambda s: _causal_mask_scores(s, i, j, block_q, block_k),)
     return tuple(
@@ -336,6 +400,10 @@ def _tile_kinds(mask, i, j, block_q, block_k):
     tile that is neither does not run."""
     if _unmasked(mask):
         return True, ()
+    if isinstance(mask, Band):
+        # Equal tiles, the window n of them: row i runs tiles i - n .. i.
+        n = mask.window // block_k
+        return i - n < j < i, (j == i, j == i - n)
     if not isinstance(mask, BlockDiffusion):
         # Crossed: not above the diagonal, not below it.
         hit = (j * block_k <= (i + 1) * block_q - 1) and (
@@ -747,17 +815,21 @@ def _flash_backward(q, k, v, out, lse, g, mask, block_q, block_k):
 
 def _kernel_name(mask, name):
     """The causal and unmasked calls keep their names; a call under block
-    diffusion carries its own, so a trace tells them apart."""
-    return f"bd_{name}" if isinstance(mask, BlockDiffusion) else name
+    diffusion or under a band carries its own, so a trace tells them
+    apart."""
+    if isinstance(mask, BlockDiffusion):
+        return f"bd_{name}"
+    return f"band_{name}" if isinstance(mask, Band) else name
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def flash_attention(
     q, k, v, mask=False, block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K
 ):
-    """Attention over [B, H, S, D] under `mask`: False, True (causal) or a
-    `BlockDiffusion`. Where the kernel runs, S must be a multiple of the
-    (clamped) block sizes (ValueError otherwise)."""
+    """Attention over [B, H, S, D] under `mask`: False, True (causal), a
+    `BlockDiffusion` or a `Band`. Where the kernel runs, S must be a
+    multiple of the (clamped) block sizes (ValueError otherwise)."""
+    mask = _plain(mask, q.shape[2])
     bq, bk = _clamp_blocks(q.shape[2], block_q, block_k, mask)
     if _pallas_ok(q.shape[2], bq, bk, mask):
         return _per_batch_shard(
@@ -779,9 +851,12 @@ def _fit_block(s, requested):
 
 
 def _clamp_blocks(s, block_q, block_k, mask=False):
-    """Under block diffusion a tile lies inside one half."""
+    """Under block diffusion a tile lies inside one half, under a band
+    inside the window."""
     if isinstance(mask, BlockDiffusion):
         s = mask.half
+    elif isinstance(mask, Band):
+        s = min(s, mask.window)
     return _fit_block(s, block_q), _fit_block(s, block_k)
 
 
@@ -805,6 +880,7 @@ def _pallas_ok(s, block_q, block_k, mask=False):
 
 
 def _fwd(q, k, v, mask, block_q, block_k):
+    mask = _plain(mask, q.shape[2])
     bq, bk = _clamp_blocks(q.shape[2], block_q, block_k, mask)
     if _pallas_ok(q.shape[2], bq, bk, mask):
         out, lse = _per_batch_shard(
@@ -819,6 +895,7 @@ def _fwd(q, k, v, mask, block_q, block_k):
 
 def _bwd(mask, block_q, block_k, residuals, g):
     q, k, v, out, lse = residuals
+    mask = _plain(mask, q.shape[2])
     bq, bk = _clamp_blocks(q.shape[2], block_q, block_k, mask)
     if lse is not None:
         return _per_batch_shard(

@@ -79,10 +79,21 @@ def _rotate_next(blocks, t, axis_name, axis_size):
     )
 
 
+def refuse_mask_description(causal):
+    """Sequence parallelism takes `causal` alone: a mask description of
+    the flash kernels (`Band`, `BlockDiffusion`) is not built under ring or
+    Ulysses attention, and is truthy, so it would run as the causal mask."""
+    if isinstance(causal, tuple):
+        raise ValueError(
+            f"{causal} under ring / Ulysses attention is not built: they "
+            "take causal=True or False")
+
+
 def ring_attention(q, k, v, axis_name, causal=False):
     """Exact attention with Q/K/V sharded [B, H, S_local, D] along
     `axis_name`. Call INSIDE shard_map; returns the local output block.
     """
+    refuse_mask_description(causal)
     axis_size = jax.lax.psum(1, axis_name)
     my_idx = jax.lax.axis_index(axis_name)
     scale = q.shape[-1] ** -0.5
@@ -188,6 +199,7 @@ def zigzag_ring_attention(q, k, v, axis_name, causal=True):
     """Balanced causal ring attention; call INSIDE shard_map with Q/K/V
     sharded [B, H, S_local, D] contiguously along `axis_name`. The zigzag
     relayout is internal: inputs/outputs stay contiguously sharded."""
+    refuse_mask_description(causal)
     if not causal:
         return ring_attention(q, k, v, axis_name, causal=False)
     axis_size = jax.lax.psum(1, axis_name)

@@ -500,6 +500,13 @@ def granite_cut_one_chip(topo, no_persistent_compile_cache_in_module):
     return _compile_planned(lambda mp: _plan_step(m, 1, 8192, topo, mp))
 
 
+@pytest.fixture(scope="module")
+def mellum_cut_one_chip(topo, no_persistent_compile_cache_in_module):
+    from elasticdl_tpu.models.mellum import mellum2_12b_a2_5b_cut as m
+
+    return _compile_planned(lambda mp: _plan_step(m, 1, 16384, topo, mp))
+
+
 def test_flagship_step_compiles_and_fits_one_v5e(flagship_one_chip):
     """The WHOLE flagship training step of AllReduceTrainer — the program
     `edl train` runs at `flagship_config()` widths, minibatch 4 — for one
@@ -1029,3 +1036,112 @@ def test_the_readers_take_the_conv_calls_for_the_mixers_and_not_the_scans(
              or "bf16[1,1,64,64,8192]" in (c[0], *c[1])]
     assert len(scans) == 27 and all(
         taken(c, ops.scan_shape) for c in scans)
+
+
+def test_mellum_cut_step_compiles_and_fits_one_v5e(mellum_cut_one_chip):
+    """The WHOLE training step of the Mellum2-12B-A2.5B cut (595.2 M
+    parameters at 16 bytes each, minibatch 1 x S 16384, as `edl train` runs
+    `mellum2_12b_a2_5b_cut`) for one described chip: the flash kernels
+    under the band in three layers and under the causal mask in the
+    fourth, all at `[32, 16384, 128]`, handed the activation dtype, the
+    band's calls under names of their own; the gated grouped product over
+    16 held experts; the untied head over a quarter of the vocabulary; it
+    fits 16 GB with the remat the model-def states, and hands eight
+    counters back beside the loss."""
+    step = mellum_cut_one_chip
+    assert step.out_tree.children()[2].num_leaves == 9
+    calls = _kernel_calls(step.text)
+    # Four layers: a forward (and a rematerialised twin) and a backward.
+    assert 8 <= len(calls) <= 12
+    for results, operands in calls:
+        assert results.startswith("(bf16[32,16384,128], "), results
+        assert set(operands) <= {"bf16[32,16384,128]", "f32[32,16384,128]"}
+    # What the benchmark's readers key on: the names, and the counts the
+    # causal calls have (3 / 2 forward, 6 / 3 backward), under the band too.
+    named = {}
+    for line in step.text.split("\n"):
+        m = _HLO_OP.match(line)
+        if m and m.group(2) == "custom-call" and "tpu_custom_call" in line:
+            kernel = re.search(r"(band_)?flash_(fwd|bwd)", line).group(0)
+            operands = _HLO_ARRAY.findall(
+                _OPERAND_LAYOUTS.search(line).group(1))
+            named.setdefault(kernel, set()).add(
+                (len(operands), len(_HLO_ARRAY.findall(m.group(1)))))
+    assert named == {
+        "band_flash_fwd": {(3, 2)}, "band_flash_bwd": {(6, 3)},
+        "flash_fwd": {(3, 2)}, "flash_bwd": {(6, 3)}}
+    assert len(re.findall(r"= [^=]*custom-call[^\n]*band_flash_bwd",
+                          step.text)) == 3
+    assert step.resident < HBM_BYTES, f"{step.resident / 2**30:.2f} GiB"
+    # params + Adam m and v
+    assert step.argument_bytes > 7.1e9
+    assert {"f32[16,2304,1792]", "f32[16,896,2304]", "f32[64,2304]",
+            "f32[2304,32,128]", "f32[2304,4,128]", "f32[4096,2304]",
+            "f32[24576,2304]", "f32[2304,24576]"} <= step.weights
+    assert "tensor<1x16384x24576xf32>" in step.lowered
+    print(f"mellum cut: resident {step.resident / 2**30:.2f} GiB")
+
+
+@pytest.mark.parametrize("tile,steps", [(1024, 31), (512, 93)])
+def test_the_bands_grids_hold_the_run_tiles_alone(tile, steps):
+    """Both passes' `pallas_call`s under `Band(1024)` at the cell's shape:
+    the grid is (batch*heads, run tiles), the calls carry the band's names
+    and the causal calls' operand and result counts (no table operand)."""
+    mask = fa.Band(1024)
+    assert fa.grid_steps(mask, 16384, tile, tile) == steps
+    x = jax.ShapeDtypeStruct((1, 2, 16384, 128), jnp.bfloat16)
+
+    def both(q, k, v, g):
+        o, lse = fa._flash_forward(q, k, v, mask, tile, tile, True)
+        return fa._flash_backward(q, k, v, o, lse, g, mask, tile, tile)
+
+    calls = [
+        eqn for eqn in jax.make_jaxpr(both)(x, x, x, x).jaxpr.eqns
+        if eqn.primitive.name == "pallas_call"
+    ]
+    assert [
+        (c.params["name"], c.params["grid_mapping"].grid,
+         len(c.invars), len(c.outvars))
+        for c in calls
+    ] == [
+        ("band_flash_fwd", (2, steps), 3, 2),
+        ("band_flash_bwd", (2, steps), 6, 3),
+    ]
+
+
+@pytest.mark.parametrize("tile", [1024, 512])
+def test_band_kernels_compile_for_v5e(one_chip, kernel_on, tile):
+    """Forward and backward under `Band(1024)` at `[1, 32, 16384, 128]`
+    for the described chip, at both tiles the cut chose between."""
+    shape = (1, 32, 16384, 128)
+
+    def loss(q, k, v):
+        return fa.flash_attention(
+            q, k, v, fa.Band(1024), tile, tile).astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        *_qkv(shape, one_chip, jnp.bfloat16)).compile()
+    text = compiled.as_text()
+    assert "band_flash_fwd" in text and "band_flash_bwd" in text
+
+
+def test_band_kernels_partition_over_a_data_mesh(topo, kernel_on):
+    """The band's calls under the trainer's abstract mesh over the four
+    described chips: each batch shard runs them on its own rows, as the
+    causal calls do (`_per_batch_shard` knows no mask)."""
+    mesh = jax.sharding.Mesh(np.array(topo.devices), ("data",))
+    sharded = NamedSharding(mesh, P("data"))
+
+    def loss(q, k, v):
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return jnp.sum(fa.flash_attention(q, k, v, fa.Band(1024)))
+
+    text = (
+        jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+        .lower(*_qkv((4, 8, 4096, 128), sharded, jnp.bfloat16))
+        .compile().as_text()
+    )
+    assert text.count("tpu_custom_call") == 2
+    assert "band_flash_fwd" in text and "band_flash_bwd" in text
+    # Per device: 1 of the 4 rows.
+    assert "bf16[1,8,4096,128]" in text
