@@ -43,8 +43,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from elasticdl_tpu.layers.moe import RoutedExperts
-from elasticdl_tpu.models.lfm2.lfm2_moe import rotary
-from elasticdl_tpu.models.nemotron_h.nemotron_h import RMSNorm, rms_norm
+from elasticdl_tpu.models.nemotron_h.nemotron_h import RMSNorm
 from elasticdl_tpu.models.transformer import transformer_lm as tlm
 from elasticdl_tpu.ops import optimizers
 from elasticdl_tpu.ops.flash_attention import (
@@ -52,6 +51,7 @@ from elasticdl_tpu.ops.flash_attention import (
     band_scores,
     flash_attention,
 )
+from elasticdl_tpu.ops.qk_rotary import qk_rotary, rope_tables
 
 BAND, FULL = "sliding_attention", "full_attention"
 SCOPES = {BAND: "mellum_band_attention", FULL: "mellum_full_attention"}
@@ -198,7 +198,7 @@ class Attention(nn.Module):
     kind: str
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, rope):
         cfg = self.config
         dtype = jnp.dtype(cfg.activation_dtype)
         heads, kv, dh = (cfg.num_attention_heads, cfg.num_key_value_heads,
@@ -209,23 +209,19 @@ class Attention(nn.Module):
                 (n, dh), use_bias=False, dtype=dtype, kernel_init=cfg.init,
                 name=name)(x)
 
-        def head_norm(v, name):
-            weight = self.param(name, nn.initializers.ones, (dh,))
-            return rms_norm(v, weight, cfg.rms_norm_eps)
+        def turned(n, name, norm):
+            """[B, S, n, Dh] -> [B, n, S, Dh] in the activation dtype,
+            which crosses the flash kernels' boundary: the head's norm,
+            the turn by this kind of layer's tables, the rounding and the
+            layout in one op."""
+            weight = self.param(norm, nn.initializers.ones, (dh,))
+            return qk_rotary(
+                proj(n, name), weight, cfg.rms_norm_eps, *rope)
 
-        inv_freq, scale = rope_table(cfg.rope(self.kind), dh)
-        q, k = proj(heads, "q_proj"), proj(kv, "k_proj")
-        q = rotary(head_norm(q, "q_norm"), None, inv_freq=inv_freq,
-                   scale=scale)
-        k = rotary(head_norm(k, "k_norm"), None, inv_freq=inv_freq,
-                   scale=scale)
-        # [B, S, H, Dh] -> [B, H, S, Dh]; each key/value head serves
-        # heads / kv query heads: broadcast before the kernel, so the
-        # broadcast's gradient sums the group. The activation dtype
-        # crosses the kernels' boundary, as at the LFM2 call site.
-        q = jnp.swapaxes(q.astype(dtype), 1, 2)
-        k = jnp.repeat(
-            jnp.swapaxes(k.astype(dtype), 1, 2), heads // kv, axis=1)
+        # Each key/value head serves heads / kv query heads: broadcast
+        # before the kernel, so the broadcast's gradient sums the group.
+        q = turned(heads, "q_proj", "q_norm")
+        k = jnp.repeat(turned(kv, "k_proj", "k_norm"), heads // kv, axis=1)
         v = jnp.repeat(
             jnp.swapaxes(proj(kv, "v_proj"), 1, 2), heads // kv, axis=1)
         with jax.named_scope(SCOPES[self.kind]):
@@ -246,7 +242,7 @@ class Block(nn.Module):
     index: int
 
     @nn.compact
-    def __call__(self, h):
+    def __call__(self, h, rope):
         cfg = self.config
 
         def norm(name):
@@ -254,7 +250,7 @@ class Block(nn.Module):
 
         h = h + Attention(
             cfg, cfg.layer_types[self.index], name="self_attn")(
-                norm("input_layernorm")(h)).astype(h.dtype)
+                norm("input_layernorm")(h), rope).astype(h.dtype)
         with jax.named_scope(MOE_SCOPE):
             out, stats = RoutedExperts(
                 num_experts=cfg.num_experts,
@@ -290,10 +286,17 @@ class MellumMoe(nn.Module):
         h = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=dtype,
                      embedding_init=cfg.init, name="embed_tokens")(
                          tokens.astype(jnp.int32))
+        # One pair of cos and sin tables a kind of layer, for all of them.
+        ropes = {
+            kind: rope_tables(
+                jnp.arange(tokens.shape[1]),
+                *rope_table(cfg.rope(kind), cfg.head_dim))
+            for kind in sorted(set(cfg.layer_types))}
         totals = None
         for i in range(cfg.num_hidden_layers):
             block_cls = nn.remat(Block) if i in cfg.remat_layers else Block
-            h, stats = block_cls(cfg, i, name=f"layers_{i}")(h)
+            h, stats = block_cls(cfg, i, name=f"layers_{i}")(
+                h, ropes[cfg.layer_types[i]])
             totals = stats if totals is None else jax.tree_util.tree_map(
                 jnp.add, totals, stats)
         h = RMSNorm(cfg.rms_norm_eps, cfg.activation_dtype, name="norm")(h)

@@ -49,8 +49,7 @@ from elasticdl_tpu.common.evaluation_utils import MeanMetric
 from elasticdl_tpu.common.model_utils import Modes
 from elasticdl_tpu.data.example import batch_examples
 from elasticdl_tpu.layers.moe import RoutedExperts
-from elasticdl_tpu.models.lfm2.lfm2_moe import rotary
-from elasticdl_tpu.models.nemotron_h.nemotron_h import RMSNorm, rms_norm
+from elasticdl_tpu.models.nemotron_h.nemotron_h import RMSNorm
 from elasticdl_tpu.models.transformer.transformer_lm import token_ce
 from elasticdl_tpu.ops import optimizers
 from elasticdl_tpu.ops.flash_attention import (
@@ -58,6 +57,7 @@ from elasticdl_tpu.ops.flash_attention import (
     block_diffusion_scores,
     flash_attention,
 )
+from elasticdl_tpu.ops.qk_rotary import qk_rotary, rope_tables
 
 ATTENTION_SCOPE = "bd_attention"
 
@@ -133,7 +133,7 @@ class Attention(nn.Module):
     config: SdarMoeConfig
 
     @nn.compact
-    def __call__(self, x, positions, mask):
+    def __call__(self, x, rope, mask):
         cfg = self.config
         dtype = jnp.dtype(cfg.activation_dtype)
         heads, kv, dh = (cfg.num_attention_heads, cfg.num_key_value_heads,
@@ -144,20 +144,18 @@ class Attention(nn.Module):
                 (n, dh), use_bias=False, dtype=dtype, kernel_init=cfg.init,
                 name=name)(x)
 
-        def head_norm(v, name):
-            weight = self.param(name, nn.initializers.ones, (dh,))
-            return rms_norm(v, weight, cfg.rms_norm_eps)
+        def turned(n, name, norm):
+            """[B, S, n, Dh] -> [B, n, S, Dh] in the activation dtype,
+            which crosses the flash kernels' boundary: the head's norm,
+            the turn, the rounding and the layout in one op."""
+            weight = self.param(norm, nn.initializers.ones, (dh,))
+            return qk_rotary(
+                proj(n, name), weight, cfg.rms_norm_eps, *rope)
 
-        q, k = proj(heads, "q_proj"), proj(kv, "k_proj")
-        q = rotary(head_norm(q, "q_norm"), cfg.rope_theta, positions)
-        k = rotary(head_norm(k, "k_norm"), cfg.rope_theta, positions)
-        # [B, S, H, Dh] -> [B, H, S, Dh]; each key/value head serves
-        # heads / kv query heads: broadcast before the kernel, so the
-        # broadcast's gradient sums the group. The activation dtype
-        # crosses the kernels' boundary, as at the LFM2 call site.
-        q = jnp.swapaxes(q.astype(dtype), 1, 2)
-        k = jnp.repeat(
-            jnp.swapaxes(k.astype(dtype), 1, 2), heads // kv, axis=1)
+        # Each key/value head serves heads / kv query heads: broadcast
+        # before the kernel, so the broadcast's gradient sums the group.
+        q = turned(heads, "q_proj", "q_norm")
+        k = jnp.repeat(turned(kv, "k_proj", "k_norm"), heads // kv, axis=1)
         v = jnp.repeat(
             jnp.swapaxes(proj(kv, "v_proj"), 1, 2), heads // kv, axis=1)
         with jax.named_scope(ATTENTION_SCOPE):
@@ -175,14 +173,14 @@ class Block(nn.Module):
     index: int
 
     @nn.compact
-    def __call__(self, h, positions, mask):
+    def __call__(self, h, rope, mask):
         cfg = self.config
 
         def norm(name):
             return RMSNorm(cfg.rms_norm_eps, cfg.activation_dtype, name=name)
 
         h = h + Attention(cfg, name="self_attn")(
-            norm("input_layernorm")(h), positions, mask).astype(h.dtype)
+            norm("input_layernorm")(h), rope, mask).astype(h.dtype)
         out, stats = RoutedExperts(
             num_experts=cfg.num_experts,
             num_experts_per_tok=cfg.num_experts_per_tok,
@@ -207,17 +205,20 @@ class SdarMoe(nn.Module):
         noised = features["noised"].astype(jnp.int32)
         batch, half = clean.shape
         mask = BlockDiffusion(cfg.block_length, half)
-        # One sequence of 2L rows, both halves at positions 0 .. L - 1.
+        # One sequence of 2L rows, both halves at positions 0 .. L - 1:
+        # one pair of cos and sin tables for every layer.
         rows = jnp.concatenate([clean, noised], axis=1)
-        positions = jnp.tile(jnp.arange(half), 2)
+        rope = rope_tables(
+            jnp.tile(jnp.arange(half), 2),
+            cfg.rope_theta ** (-jnp.arange(
+                0, cfg.head_dim, 2, dtype=jnp.float32) / cfg.head_dim))
         h = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=dtype,
                      embedding_init=cfg.init, name="embed_tokens")(rows)
         block_cls = nn.remat(Block, static_argnums=(3,)) if cfg.remat else (
             Block)
         totals = None
         for i in range(cfg.num_hidden_layers):
-            h, stats = block_cls(cfg, i, name=f"layers_{i}")(
-                h, positions, mask)
+            h, stats = block_cls(cfg, i, name=f"layers_{i}")(h, rope, mask)
             totals = stats if totals is None else jax.tree_util.tree_map(
                 jnp.add, totals, stats)
         # The head over the noised half alone: the clean half predicts

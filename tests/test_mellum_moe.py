@@ -266,3 +266,89 @@ def test_remat_gives_the_same_loss_and_gradients():
     for x, y in zip(jax.tree_util.tree_leaves(ga),
                     jax.tree_util.tree_leaves(gb)):
         np.testing.assert_allclose(x, y, atol=1e-5)
+
+
+# ---------- the q / k stage: ops/qk_rotary.py at the call site ----------
+
+
+def _kernel_sized(layer_types=(BAND, FULL, BAND), remat_layers=()):
+    """A model whose heads the kernels tile (128 lanes), bfloat16
+    activations."""
+    config = mellum_moe.MellumMoeConfig(
+        layer_types=layer_types, sliding_window=WINDOW, hidden_size=256,
+        num_attention_heads=2, num_key_value_heads=1, head_dim=128,
+        expert_block_rows=16, remat_layers=remat_layers)
+    model = mellum_moe.custom_model(config)
+    tokens = np.random.default_rng(5).integers(
+        0, 256, (2, LENGTH)).astype(np.int32)
+    variables = model.init({"params": jax.random.PRNGKey(4)}, tokens)
+    return model, variables, tokens
+
+
+def _loss_and_gradients(model, variables, tokens):
+    def f(params):
+        return mellum_moe.loss(tokens[:, 1:], model.apply(
+            dict(variables, params=params), tokens[:, :-1], training=True))
+
+    return jax.jit(jax.value_and_grad(f))(variables["params"])
+
+
+@pytest.mark.parametrize("remat_layers", [(), (0, 1)], ids=["plain", "remat"])
+def test_the_loss_and_gradients_are_the_parents_call_sites_bits(
+        monkeypatch, remat_layers):
+    """Off the TPU the op is the parent's expression over tables built once
+    a kind of layer: with `rotary(head_norm(.))` by the layer's own table
+    and scale, the cast and the transpose put back at the call site (the
+    parent of PR 53), the loss and every gradient keep their bits under a
+    jit, rematerialised layers or none."""
+    from elasticdl_tpu.models.nemotron_h.nemotron_h import rms_norm
+
+    model, variables, tokens = _kernel_sized(remat_layers=remat_layers)
+    got = _loss_and_gradients(model, variables, tokens)
+
+    def parents(x, weight, eps, inv_freq, scale):
+        turned = rotary(rms_norm(x, weight, eps), None, inv_freq=inv_freq,
+                        scale=None if scale is None else scale[0])
+        return jnp.swapaxes(turned.astype(x.dtype), 1, 2)
+
+    # The table's description in the tables' place, down to the call site.
+    monkeypatch.setattr(
+        mellum_moe, "rope_tables",
+        lambda positions, inv_freq, scale: (
+            jnp.asarray(inv_freq),
+            None if scale is None else np.asarray([scale], np.float32)))
+    monkeypatch.setattr(mellum_moe, "qk_rotary", parents)
+    want = _loss_and_gradients(model, variables, tokens)
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_array_equal(g, w)
+
+
+@pytest.mark.parametrize("kernels", [False, True], ids=["cpu", "kernels"])
+def test_the_rope_tables_are_built_once_a_kind_of_layer(monkeypatch, kernels):
+    """Three windowed layers and two full ones: two cos and two sin a step
+    (the default table's and YaRN's), not one a layer's q and k; where the
+    kernels run, the layers of both kinds call one `qk_rotary_fwd` /
+    `qk_rotary_bwd` pair a shape, the table an operand [1, S, head_dim]."""
+    from elasticdl_tpu.ops import flash_attention as fa
+    from test_ssd_scan import _equations
+
+    model, variables, tokens = _kernel_sized(
+        (BAND, FULL, BAND, BAND, FULL))
+    if kernels:
+        monkeypatch.delenv("EDL_FORCE_PALLAS_INTERPRET", raising=False)
+        monkeypatch.setattr(fa, "_use_pallas", lambda: True)
+    jaxpr = jax.make_jaxpr(jax.grad(lambda p: mellum_moe.loss(
+        tokens, model.apply(
+            dict(variables, params=p), tokens, training=True))))(
+                variables["params"]).jaxpr
+    eqns = list(_equations(jaxpr))
+    names = [e.primitive.name for e in eqns]
+    assert names.count("cos") == 2 and names.count("sin") == 2
+    calls = [e for e in eqns if e.primitive.name == "pallas_call"
+             and e.params["name"].startswith("qk_rotary")]
+    assert sorted(e.params["name"] for e in calls) == (
+        10 * ["qk_rotary_bwd"] + 10 * ["qk_rotary_fwd"] if kernels else [])
+    for call in calls:
+        assert [v.aval.shape for v in call.invars[2:4]] == 2 * [
+            (2, LENGTH, 128)]

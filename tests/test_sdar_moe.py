@@ -265,3 +265,83 @@ def test_feed_noises_on_the_host_and_the_loss_is_the_weighted_sum():
     hits = (np.argmax(np.asarray(logits), -1) == tokens)[
         labels["weights"] > 0]
     assert metrics["masked_accuracy"].result() == pytest.approx(hits.mean())
+
+
+# ---------- the q / k stage: ops/qk_rotary.py at the call site ----------
+
+
+def _kernel_sized(layers=3):
+    """A model whose heads the kernels tile (128 lanes), bfloat16
+    activations, with its features and labels."""
+    config = dataclasses.replace(
+        CONFIG, num_hidden_layers=layers, head_dim=128,
+        activation_dtype="bfloat16")
+    rng = np.random.default_rng(5)
+    tokens = rng.integers(0, 255, (2, LENGTH)).astype(np.int32)
+    noised, weights = sdar_moe.noise(
+        tokens, rng.uniform(0.1, 1, (2, LENGTH // BLOCK)),
+        rng.random((2, LENGTH)), 255)
+    features = {"tokens": tokens, "noised": noised}
+    model = sdar_moe.custom_model(config)
+    variables = model.init({"params": jax.random.PRNGKey(4)}, features)
+    return (model, variables, features,
+            {"targets": tokens, "weights": weights})
+
+
+def _loss_and_gradients(model, variables, features, labels):
+    def f(params):
+        return sdar_moe.loss(labels, model.apply(
+            dict(variables, params=params), features, training=True))
+
+    return jax.jit(jax.value_and_grad(f))(variables["params"])
+
+
+def test_the_loss_and_gradients_are_the_parents_call_sites_bits(monkeypatch):
+    """Off the TPU the op is the parent's expression over tables built once:
+    with `rotary(head_norm(.))`, the cast and the transpose put back at the
+    call site (the parent of PR 53), the loss and every gradient keep their
+    bits under a jit."""
+    from elasticdl_tpu.models.nemotron_h.nemotron_h import rms_norm
+
+    model, variables, features, labels = _kernel_sized()
+    got = _loss_and_gradients(model, variables, features, labels)
+    positions = jnp.tile(jnp.arange(LENGTH), 2)
+
+    def parents(x, weight, eps, cos, sin):
+        turned = rotary(rms_norm(x, weight, eps), CONFIG.rope_theta,
+                        positions)
+        return jnp.swapaxes(turned.astype(x.dtype), 1, 2)
+
+    monkeypatch.setattr(sdar_moe, "qk_rotary", parents)
+    want = _loss_and_gradients(model, variables, features, labels)
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_array_equal(g, w)
+
+
+@pytest.mark.parametrize("kernels", [False, True], ids=["cpu", "kernels"])
+def test_the_rope_tables_are_built_once_a_model_call(monkeypatch, kernels):
+    """One cos and one sin a step whatever the number of layers, over the
+    doubled positions; where the kernels run, every layer's q and k go
+    through `qk_rotary_fwd` and come back through `qk_rotary_bwd`, handed
+    those tables as [1, 2L, head_dim]."""
+    from elasticdl_tpu.ops import flash_attention as fa
+    from test_ssd_scan import _equations
+
+    model, variables, features, labels = _kernel_sized(layers=3)
+    if kernels:
+        monkeypatch.delenv("EDL_FORCE_PALLAS_INTERPRET", raising=False)
+        monkeypatch.setattr(fa, "_use_pallas", lambda: True)
+    jaxpr = jax.make_jaxpr(jax.grad(lambda p: sdar_moe.loss(
+        labels, model.apply(dict(variables, params=p), features,
+                            training=True))))(variables["params"]).jaxpr
+    eqns = list(_equations(jaxpr))
+    names = [e.primitive.name for e in eqns]
+    assert names.count("cos") == 1 and names.count("sin") == 1
+    calls = [e for e in eqns if e.primitive.name == "pallas_call"
+             and e.params["name"].startswith("qk_rotary")]
+    assert sorted(e.params["name"] for e in calls) == (
+        6 * ["qk_rotary_bwd"] + 6 * ["qk_rotary_fwd"] if kernels else [])
+    for call in calls:
+        assert [v.aval.shape for v in call.invars[2:4]] == 2 * [
+            (2, 2 * LENGTH, 128)]

@@ -10,6 +10,7 @@ and every compile runs in this process. The persistent compile cache is
 off around them (an AOT entry cannot be read back without a chip).
 """
 
+import contextlib
 import os
 import re
 from typing import Any, NamedTuple
@@ -23,6 +24,7 @@ from jax.sharding import SingleDeviceSharding
 
 from elasticdl_tpu.ops import causal_conv as cc
 from elasticdl_tpu.ops import flash_attention as fa
+from elasticdl_tpu.ops import qk_rotary as qr
 from elasticdl_tpu.ops import ssd_scan as ss
 from elasticdl_tpu.parallel import step_plan
 
@@ -105,13 +107,15 @@ def _scatter_results(hlo_text):
     ]
 
 
-def _kernel_calls(hlo_text):
+def _kernel_calls(hlo_text, named=""):
     """[(result shapes, [operand shapes])] of the Mosaic calls in a
-    compiled text, without layouts."""
+    compiled text, without layouts: all of them, or those whose
+    instruction's name (the kernel's own) holds `named`."""
     calls = []
     for line in hlo_text.split("\n"):
         m = _HLO_OP.match(line)
-        if m and m.group(2) == "custom-call" and "tpu_custom_call" in line:
+        if (m and m.group(2) == "custom-call" and "tpu_custom_call" in line
+                and named in line.split(" = ")[0]):
             operands = _OPERAND_LAYOUTS.search(line).group(1)
             calls.append((
                 _without_layouts(m.group(1)),
@@ -307,6 +311,80 @@ def test_causal_conv_partitions_over_a_data_mesh(topo, kernel_on):
     assert "all-reduce" in text
 
 
+# The SDAR and the Mellum cells' projections [B, S, H, Dh]: q's 32 heads
+# and k's 4 at 16,384 rows, and the tables [1, S, Dh]. A layer's four
+# calls by their results: q and k turned, and their way back with d
+# weight's partial sums a grid block.
+QK_ROTARY_ROWS = 16384
+QK_ROTARY_CALLS = [
+    "bf16[1,32,16384,128]", "bf16[1,4,16384,128]",
+    "(bf16[1,16384,4096], f32[1,64,8,128])",
+    "(bf16[1,16384,512], f32[1,64,8,128])"]
+
+
+def _qk_operands(sharding, heads, bsz=1):
+    replicated = (NamedSharding(sharding.mesh, P())
+                  if isinstance(sharding, NamedSharding) else sharding)
+    table = jax.ShapeDtypeStruct(
+        (1, QK_ROTARY_ROWS, 128), jnp.float32, sharding=replicated)
+    return (jax.ShapeDtypeStruct((bsz, QK_ROTARY_ROWS, heads, 128),
+                                 jnp.bfloat16, sharding=sharding),
+            jax.ShapeDtypeStruct((128,), jnp.float32, sharding=replicated),
+            table, table)
+
+
+def _qk_loss(x, weight, cos, sin):
+    return jnp.sum(
+        qr.qk_rotary(x, weight, 1e-6, cos, sin).astype(jnp.float32) ** 2)
+
+
+@pytest.mark.parametrize("backward", [False, True],
+                         ids=["forward", "backward"])
+@pytest.mark.parametrize("heads", [32, 4], ids=["q32", "k4"])
+def test_qk_rotary_kernels_compile_for_v5e(one_chip, kernel_on, heads,
+                                           backward):
+    """The head norm and the rotary turn's two kernels at the SDAR and the
+    Mellum cells' shapes ([1, 16384, 32, 128] and [1, 16384, 4, 128],
+    bfloat16): the rotation by half a head, the sums over a head's lanes,
+    the blocks' VMEM and the fold of d weight a sublane apart are the
+    chip's compiler's to refuse. Forward: the turned heads [1, H, S, Dh].
+    Backward: those again, then the projection's cotangent [1, S, H * Dh]
+    and d weight's partial sums a grid block."""
+    fn = jax.grad(_qk_loss, argnums=(0, 1)) if backward else _qk_loss
+    text = jax.jit(fn).lower(
+        *_qk_operands(one_chip, heads)).compile().as_text()
+    tiles = qr._tiles((1, QK_ROTARY_ROWS, heads, 128)).tiles
+    turned = f"bf16[1,{heads},16384,128]"
+    back = f"(bf16[1,16384,{heads * 128}], f32[1,{tiles},8,128])"
+    assert sorted(r for r, _ in _kernel_calls(text)) == sorted(
+        [turned, back] if backward else [turned])
+    assert ("qk_rotary_bwd" in text) == backward and "qk_rotary_fwd" in text
+
+
+def test_qk_rotary_partitions_over_a_data_mesh(topo, kernel_on):
+    """As the scan's kernels: under the trainer's abstract mesh each batch
+    shard turns its own rows, and d weight is summed over the shards by
+    the program (the weight and the tables cross the boundary a copy a
+    row)."""
+    mesh = jax.sharding.Mesh(np.array(topo.devices), ("data",))
+    sharded = NamedSharding(mesh, P("data"))
+
+    def loss(*operands):
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return _qk_loss(*operands)
+
+    text = (
+        jax.jit(jax.grad(loss, argnums=(0, 1)))
+        .lower(*_qk_operands(sharded, 32, bsz=4))
+        .compile().as_text()
+    )
+    assert text.count("tpu_custom_call") == 2
+    # Per device: 1 of the 4 rows, and the weight's gradient reduced.
+    assert "bf16[1,32,16384,128]" in text
+    assert "bf16[4,32,16384,128]" not in text
+    assert "all-reduce" in text
+
+
 def test_unservable_sequence_raises_where_the_kernel_runs(kernel_on):
     q = jnp.zeros((1, 1, 1100, 128), jnp.float32)
     with pytest.raises(ValueError, match="not a multiple"):
@@ -408,6 +486,32 @@ def _resident_bytes(compiled):
     )
 
 
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_traces = {"heard": 0, "listening": False}
+
+
+def _hear_a_trace(event, duration, **kwargs):
+    if event == _TRACE_EVENT:
+        _traces["heard"] += 1
+
+
+@contextlib.contextmanager
+def _counted_traces():
+    """The number (a list of one) of jaxprs traced inside: the step's own
+    and every nested jit's, a `jnp` operator on a traced value among them
+    (what `step_load_s` sums the seconds of). Every cache is emptied
+    first, so the count is a new process's, whatever ran before."""
+    jax.clear_caches()
+    if not _traces["listening"]:
+        jax.monitoring.register_event_duration_secs_listener(_hear_a_trace)
+        _traces["listening"] = True
+    before, count = _traces["heard"], [0]
+    try:
+        yield count
+    finally:
+        count[0] = _traces["heard"] - before
+
+
 class _CompiledStep(NamedTuple):
     """A planned step, lowered and compiled once a module (30 to 60 s a
     compile): what the tests below read of it."""
@@ -420,6 +524,7 @@ class _CompiledStep(NamedTuple):
     text: str  # compiled.as_text(): what the TPU compiler made of it
     resident: int
     argument_bytes: int
+    traces: int  # jaxprs traced by step.lower(...)
 
 
 def _compile_planned(plan):
@@ -429,7 +534,8 @@ def _compile_planned(plan):
         _steer_to_the_kernel(mp)
         trainer, step, abstract, mesh = plan(mp)
         try:
-            lowered = step.lower(*abstract)
+            with _counted_traces() as traces:
+                lowered = step.lower(*abstract)
             compiled = lowered.compile()
             params = jax.tree_util.tree_leaves(
                 trainer._variables["params"]
@@ -447,6 +553,7 @@ def _compile_planned(plan):
         text=compiled.as_text(),
         resident=_resident_bytes(compiled),
         argument_bytes=compiled.memory_analysis().argument_size_in_bytes,
+        traces=traces[0],
     )
 
 
@@ -865,12 +972,18 @@ def test_sdar_cut_step_compiles_and_fits_one_v5e(sdar_cut_one_chip):
     ten counters back beside the loss."""
     step = sdar_cut_one_chip
     assert step.out_tree.children()[2].num_leaves == 11
-    calls = _kernel_calls(step.text)
+    calls = _kernel_calls(step.text, "flash_")
     # Six layers: bd_flash_fwd (and a rematerialised twin), bd_flash_bwd.
     assert 12 <= len(calls) <= 18
     for results, operands in calls:
         assert results.startswith("(bf16[32,16384,128], "), results
         assert set(operands) <= {"bf16[32,16384,128]", "f32[32,16384,128]"}
+    # q and k of six layers, turned by `qk_rotary_fwd` into the bfloat16
+    # the flash kernels take, and `qk_rotary_bwd` back: what a traced
+    # run's ops table counts a step.
+    assert sorted(r for r, _ in _kernel_calls(step.text, "qk_rotary_")) == (
+        sorted(6 * QK_ROTARY_CALLS))
+    assert len(_kernel_calls(step.text)) == len(calls) + 24
     assert step.text.count("bd_flash_fwd") >= 6
     assert step.text.count("bd_flash_bwd") >= 6
     assert step.resident < HBM_BYTES, f"{step.resident / 2**30:.2f} GiB"
@@ -883,6 +996,31 @@ def test_sdar_cut_step_compiles_and_fits_one_v5e(sdar_cut_one_chip):
     assert "tensor<1x8192x18992xf32>" in step.lowered
     assert "tensor<1x16384x18992xf32>" not in step.lowered
     print(f"sdar cut: resident {step.resident / 2**30:.2f} GiB")
+
+
+# jaxprs traced by `step.lower(...)` at the parent of PR 53 (the q / k
+# stage as `rotary(rms_norm(.))`, every cache empty), and what a later
+# change may add before this fails: a kernel whose body is traced a call
+# site, or written in `jnp` operators, adds hundreds (PR 52: 10.6 s of
+# `step_load_s`, PERF.md section 6).
+PARENT_TRACES = {"sdar_cut_one_chip": 3235, "mellum_cut_one_chip": 2221}
+TRACES_MARGIN = 40
+
+
+@pytest.mark.parametrize("fixture", sorted(PARENT_TRACES))
+def test_the_steps_trace_fires_no_more_traces_than_before_the_qk_kernels(
+        request, fixture):
+    """Set-up seconds are traces: each nested jit a step's trace enters is
+    2.5 to 4.4 ms of every job's start (PERF.md section 6, PR 44), on a
+    compile-cache hit too. With each `qk_rotary` kernel under a jit of its
+    own and its body in `lax` primitives the two steps trace fewer jaxprs
+    than with the expression (2 tables a step, not 2 a layer's q and k)."""
+    step = request.getfixturevalue(fixture)
+    ceiling = PARENT_TRACES[fixture] + TRACES_MARGIN
+    assert step.traces <= ceiling, (
+        f"{fixture}: step.lower traced {step.traces} jaxprs; the parent of "
+        f"PR 53 traced {PARENT_TRACES[fixture]} (+{TRACES_MARGIN} allowed)")
+    print(f"{fixture}: {step.traces} jaxprs traced")
 
 
 # The parent's step (PR 48) by the same compile: the convolution stage's
@@ -1050,18 +1188,25 @@ def test_mellum_cut_step_compiles_and_fits_one_v5e(mellum_cut_one_chip):
     counters back beside the loss."""
     step = mellum_cut_one_chip
     assert step.out_tree.children()[2].num_leaves == 9
-    calls = _kernel_calls(step.text)
+    calls = _kernel_calls(step.text, "flash_")
     # Four layers: a forward (and a rematerialised twin) and a backward.
     assert 8 <= len(calls) <= 12
     for results, operands in calls:
         assert results.startswith("(bf16[32,16384,128], "), results
         assert set(operands) <= {"bf16[32,16384,128]", "f32[32,16384,128]"}
+    # q and k of four layers turned for them (the two rematerialised
+    # layers' twice) and turned back: 8 + 4 and 8 calls a step, under the
+    # windowed layers' table and the full layer's alike.
+    turns = [r for r, _ in _kernel_calls(step.text, "qk_rotary_")]
+    assert set(turns) == set(QK_ROTARY_CALLS)
+    assert sorted(map(turns.count, QK_ROTARY_CALLS)) == [4, 4, 6, 6]
     # What the benchmark's readers key on: the names, and the counts the
     # causal calls have (3 / 2 forward, 6 / 3 backward), under the band too.
     named = {}
     for line in step.text.split("\n"):
         m = _HLO_OP.match(line)
-        if m and m.group(2) == "custom-call" and "tpu_custom_call" in line:
+        if (m and m.group(2) == "custom-call" and "tpu_custom_call" in line
+                and "flash_" in line.split(" = ")[0]):
             kernel = re.search(r"(band_)?flash_(fwd|bwd)", line).group(0)
             operands = _HLO_ARRAY.findall(
                 _OPERAND_LAYOUTS.search(line).group(1))
