@@ -137,6 +137,103 @@ GROUPED_SCOPE = "moe_grouped"
 SHARED_SCOPE = "moe_shared"
 
 
+def pick_chosen(scores, experts):
+    """scores[t, experts[t, j]] as [T, k]: `jnp.take_along_axis(scores,
+    experts, axis=-1)` without the gather, and without the scatter that is
+    its transpose. scores [T, E]; experts [T, k] ints, a token's k all
+    different (as `top_k` gives them); one outside [0, E) reads as the
+    gather read it (`_as_the_gather_reads`).
+
+    On the chip the gather moves the T * k numbers one at a time (10 ns
+    each) and the scatter likewise, into a zeroed [T, E]. Here both ways
+    are a compare of `experts` with an iota over E, a select and a
+    reduction: T * k * E of vector work in one fusion, never written. The
+    backward is written by hand so that what is kept for it is `experts`
+    alone, as before, and not the mask.
+
+    The same bits both ways. Forward: of the E numbers under the maximum
+    for (t, j) one is scores[t, experts[t, j]] and the rest are -inf, so
+    the chosen score itself comes back. (A maximum and not a sum with
+    zeros: the compiler folds a sum over E into the sum over k that
+    normalises the weights, one reduction over [k, E], and adds the k
+    weights in another order.) Backward: a token's k experts differ, so of
+    the k terms summed into d_scores[t, e] at most one is not +0.0, and the
+    sum is what the scatter's 0.0 + d into its zeroed array gave.
+
+    And the caller's bits. The sums round this (over k under the weights
+    and in their backward, over E in the scores' backward) add in the
+    order their operand's layout gives, and the compiler lays an operand
+    out by what produces and what else reads it: handed a plain [T, k] it
+    summed a token's weights tokens-minor in one configuration's step
+    where it had summed them k-minor round the gather, and k-minor in
+    another's where it had summed them tokens-minor, and every logged loss
+    moved (PERF.md section 6, PR 54). So each result is handed over as
+    the gather's and the scatter's were: a flat array (behind an
+    optimisation barrier, so that it stays one) reshaped, and forward
+    under the gather's own select over the indices' range. From there on
+    the compiler has the program it had, and every sum and product of the
+    routing stage keeps its operands' layouts in the four configurations'
+    compiled steps (tests/test_tpu_compile.py pins the two thinnest)."""
+    return _pick(scores, experts, scores.shape[-1])
+
+
+def _where_chosen(experts, values, values_on, fill, num_experts):
+    """[T, k, E]: `values` ([T, E] or [T, k], spread over the axes
+    `values_on`) where e is the expert experts[t, j], `fill` elsewhere. In
+    `lax` primitives: each `jnp` operator here would be one more nested
+    jit for the step's trace to enter, in every routed layer."""
+    shape = (*experts.shape, num_experts)
+    return jax.lax.select(
+        jax.lax.eq(
+            jax.lax.broadcast_in_dim(experts, shape, (0, 1)),
+            jax.lax.broadcasted_iota(experts.dtype, shape, 2)),
+        jax.lax.broadcast_in_dim(values, shape, values_on),
+        jax.lax.full(shape, fill, values.dtype))
+
+
+def _as_the_gather_reads(experts, num_experts):
+    """(experts with one below 0 counted from the end, which of them then
+    lie in [0, E)): `take_along_axis` gives NaN for the others, and their
+    cotangent goes nowhere."""
+    wrapped = jax.lax.select(
+        jax.lax.lt(experts, jax.lax.full_like(experts, 0)),
+        jax.lax.add(experts, jax.lax.full_like(experts, num_experts)),
+        experts)
+    return wrapped, jax.lax.bitwise_and(
+        jax.lax.ge(wrapped, jax.lax.full_like(experts, 0)),
+        jax.lax.le(wrapped, jax.lax.full_like(experts, num_experts - 1)))
+
+
+def _handed_over_flat(x):
+    shape = x.shape
+    return jax.lax.reshape(
+        jax.lax.optimization_barrier(jax.lax.reshape(x, (x.size,))), shape)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _pick(scores, experts, num_experts):
+    experts, within = _as_the_gather_reads(experts, num_experts)
+    picked = _handed_over_flat(jax.lax.reduce_max(_where_chosen(
+        experts, scores, (0, 2), -jnp.inf, num_experts), (2,)))
+    return jax.lax.select(
+        within, picked, jax.lax.full_like(picked, jnp.nan))
+
+
+def _pick_fwd(scores, experts, num_experts):
+    return _pick(scores, experts, num_experts), experts
+
+
+def _pick_bwd(num_experts, experts, d_picked):
+    experts, within = _as_the_gather_reads(experts, num_experts)
+    d_picked = jax.lax.select(
+        within, d_picked, jax.lax.full_like(d_picked, 0))
+    return _handed_over_flat(jax.lax.reduce_sum(_where_chosen(
+        experts, d_picked, (0, 1), 0, num_experts), (1,))), None
+
+
+_pick.defvjp(_pick_fwd, _pick_bwd)
+
+
 def route_top_k(scores, correction_bias, k, norm_topk_prob, scaling_factor,
                 eps=1e-20):
     """scores [T, E] float32 (after the sigmoid, or the softmax over all E)
@@ -146,7 +243,7 @@ def route_top_k(scores, correction_bias, k, norm_topk_prob, scaling_factor,
     (without the bias), divided by their sum (plus `eps`: HF `lfm2_moe`
     has 1e-6 there) if `norm_topk_prob`, times `scaling_factor`."""
     _, experts = jax.lax.top_k(scores + correction_bias, k)
-    weights = jnp.take_along_axis(scores, experts, axis=-1)
+    weights = pick_chosen(scores, experts)
     if norm_topk_prob:
         weights = weights / (jnp.sum(weights, -1, keepdims=True) + eps)
     return experts.astype(jnp.int32), weights * scaling_factor

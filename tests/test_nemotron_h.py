@@ -122,6 +122,23 @@ def _tiny(which):
     return tlm.custom_model(), tlm.loss, tlm.optimizer()
 
 
+def _barriers_outside_the_routing_stage(step, *args):
+    """The optimisation barriers a step is traced with, but the routed
+    layers' own: `layers/moe.py` hands the router's pick over behind one,
+    forward and backward, in its named scope."""
+    from elasticdl_tpu.layers.moe import ROUTING_SCOPE
+
+    def count(jaxpr):
+        # An inner jaxpr's name stacks start at its equation's.
+        return sum(
+            (eqn.primitive.name == "optimization_barrier")
+            + sum(map(count, jax.core.jaxprs_in_params(eqn.params)))
+            for eqn in jaxpr.eqns
+            if ROUTING_SCOPE not in str(eqn.source_info.name_stack))
+
+    return count(jax.make_jaxpr(step)(*args).jaxpr)
+
+
 @pytest.mark.parametrize("which", ["lm", "hybrid"])
 def test_the_update_apart_changes_no_value(which):
     """A one-device step keeps the optimizer's update out of the
@@ -139,11 +156,10 @@ def test_the_update_apart_changes_no_value(which):
         if update_apart:
             # What LocalTrainer always builds: nothing reduces its
             # gradients over devices.
-            lowered = trainer._train_step.lower(
-                trainer._variables, trainer._opt_state,
+            assert _barriers_outside_the_routing_stage(
+                trainer._train_step, trainer._variables, trainer._opt_state,
                 jax.random.PRNGKey(0), jnp.asarray(x[:, :-1]),
-                jnp.asarray(x[:, 1:])).as_text()
-            assert lowered.count("optimization_barrier") == len(
+                jnp.asarray(x[:, 1:])) == len(
                 jax.tree_util.tree_leaves(trainer._variables["params"]))
         else:
             trainer._train_step = jax.jit(
@@ -200,8 +216,8 @@ def test_the_step_event_says_whether_the_update_is_apart(
                 jax.random.PRNGKey(0), x[:, :-1], x[:, 1:])
         n_leaves = len(
             jax.tree_util.tree_leaves(trainer._variables["params"]))
-        assert step.lower(*args).as_text().count(
-            "optimization_barrier") == (n_leaves if apart else 0)
+        assert _barriers_outside_the_routing_stage(step, *args) == (
+            n_leaves if apart else 0)
         loss = step(*args)[2]["loss"]
         assert np.isfinite(float(loss))
     finally:

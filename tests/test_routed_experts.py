@@ -2,6 +2,8 @@
 under any skew; and the shares test: the routed parts of all the shares,
 plus the shared expert once, add up to the uncut layer."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -234,3 +236,140 @@ def test_an_expert_without_an_assignment_takes_no_block():
 def test_held_experts_outside_the_router_are_refused():
     with pytest.raises(ValueError, match="not among"):
         layer((14, 4)).init(jax.random.PRNGKey(0), jnp.zeros((1, 2, D)))
+
+
+# ---------- the pick of the chosen scores, against the gather ----------
+
+# (experts, chosen a token, score): the SDAR, Mellum, LFM2 and Nemotron-H
+# routers.
+ROUTERS = [(128, 8, "softmax"), (64, 8, "sigmoid"), (64, 4, "sigmoid"),
+           (128, 6, "sigmoid")]
+
+
+def route_by_gather(scores, bias, k, norm_topk_prob, scaling_factor,
+                    eps=1e-20):
+    """`route_top_k` with the pick as `take_along_axis`, whose transpose
+    is a scatter-add into zeros: what the layer ran before `pick_chosen`."""
+    _, experts = jax.lax.top_k(scores + bias, k)
+    weights = jnp.take_along_axis(scores, experts, axis=-1)
+    if norm_topk_prob:
+        weights = weights / (jnp.sum(weights, -1, keepdims=True) + eps)
+    return experts.astype(jnp.int32), weights * scaling_factor
+
+
+def router_case(e, k, score, signed, tokens=200):
+    """Scores [T, E] as the router's activation gives them, or, `signed`,
+    the raw logits (negative numbers among them), a correction bias that
+    moves the choice, and a cotangent [T, k] with zeros of both signs."""
+    rng = np.random.default_rng(e * 31 + k)
+    logits = jnp.asarray(rng.normal(size=(tokens, e)) * 3.0, jnp.float32)
+    scores = logits if signed else moe.SCORES[score](logits)
+    bias = jnp.asarray(rng.normal(size=e) * 0.2, jnp.float32)
+    cotangent = rng.normal(size=(tokens, k)).astype(np.float32)
+    cotangent[::7, 0] = 0.0
+    cotangent[3::11, -1] = -0.0
+    return scores, bias, jnp.asarray(cotangent)
+
+
+def same_bits(got, want):
+    got, want = np.asarray(got), np.asarray(want)
+    return (got.shape == want.shape and got.dtype == want.dtype
+            and got.tobytes() == want.tobytes())
+
+
+@pytest.mark.parametrize("signed", [False, True], ids=["scores", "signed"])
+@pytest.mark.parametrize("e,k,score", ROUTERS)
+def test_the_pick_is_the_gather_bit_for_bit_both_ways(e, k, score, signed):
+    scores, bias, cotangent = router_case(e, k, score, signed)
+    _, experts = jax.lax.top_k(scores + bias, k)
+    # The bias moved some choice: the pick is not the k largest scores.
+    assert not same_bits(experts, jax.lax.top_k(scores, k)[1])
+
+    def gather(s):
+        return jnp.take_along_axis(s, experts, axis=-1)
+
+    def pick(s):
+        return moe.pick_chosen(s, experts)
+
+    for run in (lambda f: f, jax.jit):
+        assert same_bits(run(pick)(scores), run(gather)(scores))
+        want = run(jax.grad(lambda s: jnp.sum(gather(s) * cotangent)))(scores)
+        got = run(jax.grad(lambda s: jnp.sum(pick(s) * cotangent)))(scores)
+        assert same_bits(got, want)
+    # What the scatter gave: a cotangent at each chosen place, +0.0 at
+    # every other.
+    assert int(np.count_nonzero(np.asarray(got))) <= scores.shape[0] * k
+    assert not np.signbit(np.asarray(got)[np.asarray(got) == 0]).any()
+
+
+def test_the_pick_keeps_the_choice_alone_for_its_backward():
+    """Left to autodiff the select would keep its [T, k, E] mask (16.8 MB
+    a layer at the SDAR shapes, six layers alive at once where the step
+    has 40 MB of room); the rule keeps what the gather kept."""
+    scores, bias, _ = router_case(64, 4, "sigmoid", False)
+    _, experts = jax.lax.top_k(scores + bias, 4)
+    _, back = jax.vjp(moe.pick_chosen, scores, experts)
+    kept = [a.shape for a in jax.tree_util.tree_leaves(back)
+            if hasattr(a, "shape")]
+    assert kept == [experts.shape]
+
+
+def test_the_pick_reads_an_index_outside_the_experts_as_the_gather_does():
+    """Not what `top_k` gives, but what the gather's result was defined
+    for: one below 0 counts from the end, one still outside gives NaN and
+    takes no cotangent."""
+    scores, _, cotangent = router_case(64, 4, "sigmoid", False, tokens=8)
+    experts = jnp.asarray(
+        [[0, 5, -1, 63], [64, 1, 2, 3], [-64, 7, -65, 9]] + 5 * [[4, 3, 2, 1]],
+        jnp.int32)
+    want, back = jax.vjp(
+        lambda s: jnp.take_along_axis(s, experts, axis=-1), scores)
+    got, back_pick = jax.vjp(lambda s: moe.pick_chosen(s, experts), scores)
+    assert np.isnan(np.asarray(want)[1, 0]) and np.isnan(
+        np.asarray(want)[2, 2])
+    assert same_bits(got, want)
+    assert same_bits(back_pick(cotangent)[0], back(cotangent)[0])
+
+
+@functools.lru_cache(maxsize=None)
+def _existing_route_cases():
+    sixteen = jax.nn.sigmoid(jnp.einsum(
+        "td,ed->te", some_tokens(2).reshape(-1, D),
+        whole_variables()["params"]["router"]))
+    uneven = np.full((18, E), -4.0)
+    uneven[:, 5] = 6.0
+    uneven[0::2, 6] = 3.0
+    uneven[1::2, 7] = 3.0
+    uneven[:, 9] = 1.0
+    uneven += np.random.default_rng(0).normal(size=uneven.shape) * 0.1
+    halves = jnp.asarray([[0.5, 0.25, 0.125, 0.0]])
+    return {
+        "dense_formulation": (sixteen, jnp.zeros(E), K, True, SCALE),
+        "correction_bias": (jnp.asarray([[0.9, 0.8, 0.1, 0.2]]),
+                            jnp.asarray([0.0, 0.0, 1.0, 0.0]), 2, True, 1.0),
+        "gated_uneven": (jax.nn.sigmoid(jnp.asarray(uneven, jnp.float32)),
+                         jnp.zeros(E), K, True, 1.0, 1e-6),
+        "eps_as_before": (halves, jnp.zeros(4), 2, True, 1.0),
+        "eps_wide": (halves, jnp.zeros(4), 2, True, 2.0, 0.25),
+        "not_normed": (sixteen, jnp.zeros(E), K, False, SCALE),
+    }
+
+
+@pytest.mark.parametrize("case", sorted(_existing_route_cases()))
+def test_route_top_k_is_unchanged_by_the_pick(case):
+    """The calls of this file's and tests/test_gated_experts.py's tests:
+    the choice, the weights and the weights' gradient to the scores are
+    the gather's bits."""
+    args = _existing_route_cases()[case]
+    experts, weights = moe.route_top_k(*args)
+    want_experts, want_weights = route_by_gather(*args)
+    assert same_bits(experts, want_experts)
+    assert same_bits(weights, want_weights)
+    cotangent = jnp.asarray(np.random.default_rng(5).normal(
+        size=weights.shape).astype(np.float32))
+    scores, rest = args[0], args[1:]
+    got, want = (
+        jax.jit(jax.grad(
+            lambda s: jnp.sum(route(s, *rest)[1] * cotangent)))(scores)
+        for route in (moe.route_top_k, route_by_gather))
+    assert same_bits(got, want)

@@ -922,10 +922,16 @@ def test_nemotron_h_cut_step_compiles_its_update_apart(
     together: the step still fits (the test above)."""
     step = nemotron_h_cut_one_chip
     # One a gradient leaf, beside the one `jax.checkpoint` gives each of
-    # the nine rematerialised blocks.
-    assert (
-        step.lowered.count("optimization_barrier") == step.n_grad_leaves + 9
-    )
+    # the nine rematerialised blocks, and beside the routed layers' own:
+    # `layers/moe.py` hands the router's pick over flat behind one, T * k
+    # numbers forward (again in the block's rematerialised twin) and
+    # T * E backward, in each of the four routed layers.
+    own = re.findall(
+        r"optimization_barrier [^\n]*: tensor<(98304|2097152)xf32>",
+        step.lowered)
+    assert sorted(own) == 4 * ["2097152"] + 8 * ["98304"]
+    assert step.lowered.count("optimization_barrier") == (
+        step.n_grad_leaves + 9 + len(own))
     fusions = _update_fusions(step)
     assert "kOutput" not in fusions, fusions["kOutput"]
     assert {"f32[2688,10304]", "f32[2688,16384]"} <= set(fusions["kLoop"])
@@ -959,6 +965,10 @@ def test_lfm2_cut_step_compiles_and_fits_one_v5e(lfm2_cut_one_chip):
     scatters = _scatter_results(step.text)
     assert scatters.count("f32[16384,16,128]") == 12, scatters
     assert "f32[16384,2048]" not in scatters
+
+
+# Bytes, by this compile at the parent of PR 54.
+SDAR_RESIDENT_BEFORE_THE_PICK = 16_744_711_168
 
 
 def test_sdar_cut_step_compiles_and_fits_one_v5e(sdar_cut_one_chip):
@@ -995,7 +1005,122 @@ def test_sdar_cut_step_compiles_and_fits_one_v5e(sdar_cut_one_chip):
     # The head runs over the noised half: 8192 rows of logits, not 16,384.
     assert "tensor<1x8192x18992xf32>" in step.lowered
     assert "tensor<1x16384x18992xf32>" not in step.lowered
-    print(f"sdar cut: resident {step.resident / 2**30:.2f} GiB")
+    # The thinnest step of the seven (15.75 GiB usable). The router's pick
+    # as a select keeps what the gather kept ([T, k] ints a layer); the
+    # compiler packs the step 3,129,856 bytes looser all the same.
+    assert step.resident <= SDAR_RESIDENT_BEFORE_THE_PICK + 4 * 2**20, (
+        step.resident)
+    print(f"sdar cut: resident {step.resident / 2**30:.2f} GiB "
+          f"({step.resident} bytes)")
+
+
+def _routed_layer_at(fixture):
+    """(the `RoutedExperts` a block of the cell's model builds, the cut's
+    configuration)."""
+    from elasticdl_tpu.layers.moe import RoutedExperts
+
+    if fixture == "sdar_cut_one_chip":
+        from elasticdl_tpu.models.sdar import sdar_30b_a3b_cut as m
+    else:
+        from elasticdl_tpu.models.mellum import mellum2_12b_a2_5b_cut as m
+    c = m.cut_config()
+    return RoutedExperts(
+        num_experts=c.num_experts,
+        num_experts_per_tok=c.num_experts_per_tok,
+        d_hidden=c.moe_intermediate_size, gated=True, score="softmax",
+        held=c.experts_held, norm_topk_prob=c.norm_topk_prob, topk_eps=0.0,
+        block_rows=c.expert_block_rows, force_balance_seed=0,
+        dtype=c.activation_dtype), c
+
+
+def _moves(jaxpr):
+    """(primitive, shapes of its operands and results) of every gather and
+    scatter in a jaxpr, those inside its loops and rules too."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if "gather" in eqn.primitive.name or "scatter" in eqn.primitive.name:
+            found.append((eqn.primitive.name, [
+                tuple(v.aval.shape) for v in (*eqn.invars, *eqn.outvars)]))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _moves(sub)
+    return found
+
+
+_REDUCE = re.compile(
+    r"\s*(?:ROOT )?%\S+ = \S+ reduce\(%(\S+?), .*?dimensions=\{([0-9,]*)\}"
+    r'.*?op_name="([^"]*)"')
+_F32_DEFINED = re.compile(r"\s*(?:ROOT )?%(\S+) = (f32\[[0-9,]*\]\{[0-9,]*)")
+
+
+def _sums_over_the_chosen(hlo_text, rows, chosen):
+    """{(operand's shape and minor-to-major order, reduced dimensions)} of
+    the routing stage's float reductions over an array of one number a
+    (token, choice), whichever way round it lies."""
+    defined = dict(
+        m.groups() for m in map(_F32_DEFINED.match, hlo_text.split("\n"))
+        if m)
+    return {
+        (defined[m.group(1)], m.group(2))
+        for m in map(_REDUCE.match, hlo_text.split("\n"))
+        if m and "moe_routing" in m.group(3)
+        and defined.get(m.group(1), "").split("{")[0] in (
+            f"f32[{rows},{chosen}]", f"f32[{chosen},{rows}]")}
+
+
+# The minor-to-major order in which the parent of PR 54 kept a token's k
+# weights where it summed them (this compile at the parent).
+CHOSEN_LAYOUT_BEFORE_THE_PICK = {
+    "sdar_cut_one_chip": "{1,0", "mellum_cut_one_chip": "{0,1"}
+
+
+@pytest.mark.parametrize("fixture", sorted(CHOSEN_LAYOUT_BEFORE_THE_PICK))
+def test_the_routing_stage_moves_no_single_numbers(request, fixture):
+    """The pick of a token's k scores out of its E was `take_along_axis`:
+    on the chip a gather of T * k single float32 numbers at 10 ns each
+    (1.34 ms a layer call) and, backward, their scatter into a zeroed
+    [T, E] (0.87 to 1.14 ms), 3% of either cell's step (PERF.md section 6,
+    PR 54). `pick_chosen` is a compare, a select and a reduction: the
+    layer's forward and backward at the cell's shapes hold no gather and no
+    scatter over [T, E] or [T, k], and neither does the compiled step; the
+    grouped loops' row gathers and scatter-adds are still what they were."""
+    layer, c = _routed_layer_at(fixture)
+    rows, hidden = 16384, c.hidden_size
+    experts, chosen = c.num_experts, c.num_experts_per_tok
+    x = jax.ShapeDtypeStruct((1, rows, hidden), jnp.bfloat16)
+    variables = jax.eval_shape(layer.init, jax.random.PRNGKey(0), x)
+
+    def loss(params, x):
+        y, _ = layer.apply(
+            {"params": params, "buffers": variables["buffers"]}, x)
+        return jnp.sum(y.astype(jnp.float32))
+
+    moves = _moves(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(
+        variables["params"], x).jaxpr)
+    # The walk is not blind: a block's rows are gathered from [T, D].
+    assert any((rows, hidden) in shapes for _, shapes in moves), moves
+    routing = [(name, shapes) for name, shapes in moves
+               if {(rows, experts), (rows, chosen)} & set(shapes)]
+    assert not routing, routing
+    # Nor does the compiler make one of what it is given.
+    step = request.getfixturevalue(fixture)
+    single = {f"f32[{rows},{experts}]", f"f32[{rows},{chosen}]"}
+    made = [
+        (m.group(2), _without_layouts(m.group(1)))
+        for m in map(_HLO_OP.match, step.text.split("\n"))
+        if m and m.group(2) in ("gather", "scatter")
+        and _without_layouts(m.group(1)) in single]
+    assert not made, made
+    # The sums over a token's k weights (under the normalised weights, and
+    # in their backward) add in the order their operand's layout gives,
+    # and the compiler lays the operand out by what hands it over. It has
+    # to stay the parent's: with the pick made tokens-last the
+    # block-diffusion step summed `f32[8,16384]{1,0` and `f32[16384,8]{0,1`
+    # and every logged loss of the cell moved; handed over flat with no
+    # select over the indices' range, the Mellum step summed k-minor
+    # (PERF.md section 6, PR 54).
+    assert _sums_over_the_chosen(step.text, rows, chosen) == {
+        (f"f32[{rows},{chosen}]" + CHOSEN_LAYOUT_BEFORE_THE_PICK[fixture],
+         "1")}
 
 
 # jaxprs traced by `step.lower(...)` at the parent of PR 53 (the q / k
