@@ -519,8 +519,11 @@ class RoutedExperts(nn.Module):
     `down(relu(up(x))^2)` without a gate, and a shared expert of the same
     form for every token. No auxiliary loss, no capacity: nothing is dropped.
     `gated` makes the experts `down(silu(gate(x)) * up(x))` (HF `lfm2_moe`;
-    parameters `w_gate_up`, `w_down`; no shared expert in that form), and
-    the layer then also counts the rows its loops multiplied.
+    parameters `w_gate_up`, `w_down`) and the shared expert, where
+    `d_shared` asks for one, the same form (HF `deepseek_v3`, whose
+    `n_shared_experts` are one MLP of their widths' sum; `shared_gate_up`
+    holds the gate's columns first, `shared_down`), and the layer then also
+    counts the rows its loops multiplied.
 
     The layer is told which experts it holds, `held = (first, count)`: it
     routes over all `num_experts`, computes its own experts' part of the
@@ -588,8 +591,6 @@ class RoutedExperts(nn.Module):
                 self.norm_topk_prob, self.routed_scaling_factor,
                 self.topk_eps)
             plan = plan_held_blocks(experts, first, count, self.block_rows)
-        if self.gated and self.d_shared:
-            raise ValueError("the gated form has no shared expert")
         name, grouped, width = (
             ("w_gate_up", grouped_swiglu_experts, 2 * self.d_hidden)
             if self.gated else
@@ -600,10 +601,18 @@ class RoutedExperts(nn.Module):
         y = grouped(tokens, weights, w_up, w_down, plan, self.block_rows, k)
         if self.d_shared:
             with jax.named_scope(SHARED_SCOPE):
-                h = nn.Dense(
-                    self.d_shared, use_bias=False, dtype=dtype,
-                    kernel_init=self.kernel_init, name="shared_up")(tokens)
-                h = jnp.square(jax.nn.relu(h))
+                if self.gated:
+                    g, u = jnp.split(nn.Dense(
+                        2 * self.d_shared, use_bias=False, dtype=dtype,
+                        kernel_init=self.kernel_init,
+                        name="shared_gate_up")(tokens), 2, axis=-1)
+                    h = jax.nn.silu(g) * u
+                else:
+                    h = nn.Dense(
+                        self.d_shared, use_bias=False, dtype=dtype,
+                        kernel_init=self.kernel_init,
+                        name="shared_up")(tokens)
+                    h = jnp.square(jax.nn.relu(h))
                 y = y + nn.Dense(
                     d, use_bias=False, dtype=dtype,
                     kernel_init=self.kernel_init, name="shared_down",

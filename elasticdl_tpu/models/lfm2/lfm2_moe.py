@@ -121,10 +121,15 @@ class Lfm2MoeConfig:
         return nn.initializers.normal(self.initializer_range)
 
 
-def rotary(x, theta, positions=None, inv_freq=None, scale=None):
+def rotary(x, theta, positions=None, inv_freq=None, scale=None,
+           interleave=False):
     """x [B, S, H, Dh] -> x turned by its position, over the whole head:
     x * cos + rotate_half(x) * sin with angles position * theta^(-2i / Dh)
     for i < Dh / 2, repeated over the two halves (the HF `default` rope).
+    A caller that turns part of a head hands in that part. `interleave`:
+    the channels arrive paired (2i, 2i + 1) (HF `rope_interleave`) and are
+    brought to the two halves first, evens then odds, where they stay: q
+    and k are permuted alike, so their products are the published ones.
     `positions` [S] are the rows' positions where they are not 0 .. S - 1
     (a sequence that holds two copies of a record). A long-context
     frequency scaling hands in its own table `inv_freq` [Dh / 2] in place
@@ -139,6 +144,8 @@ def rotary(x, theta, positions=None, inv_freq=None, scale=None):
     angles = positions.astype(f32)[:, None] * inv_freq[None]  # [S, Dh/2]
     angles = jnp.concatenate([angles, angles], axis=-1)[None, :, None, :]
     x = x.astype(f32)
+    if interleave:
+        x = jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
     x1, x2 = jnp.split(x, 2, axis=-1)
 
     def scaled(table):

@@ -13,7 +13,11 @@ Design: the standard blockwise online-softmax scheme over a
 forward. K/V stream through VMEM one [block_k, D] tile a step (within a
 row consecutive steps revisit the same q/output block while new K/V tiles
 DMA in), running (max, sum, acc) live in VMEM scratch, and the S x S score
-matrix never materializes — in EITHER pass:
+matrix never materializes — in EITHER pass. q and k share one width (Dk,
+which sets the scale Dk^-0.5) and v, the output and its cotangent another
+(Dv): equal in every model but the latent-attention one, whose keys of 192
+(128 without position + 64 rotary) meet values of 128 as one [block, 192]
+operand; such calls carry `mla_` before their names:
 
 - forward emits the per-row log-sum-exp as a residual, lane-replicated to
   [bh, S, 128] (the (8,128) tiling makes a plain 1-D row vector an illegal
@@ -234,7 +238,9 @@ def dense_mask(mask, s_q, s_k):
 
 
 def reference_attention(q, k, v, mask=False):
-    """[B, H, S, D] full attention in plain XLA."""
+    """Full attention in plain XLA: q, k [B, H, S, Dk], v [B, H, S, Dv]
+    (a key width beside a value width: latent attention's 192 against
+    128), scale Dk^-0.5, the result [B, H, S, Dv]."""
     scale = q.shape[-1] ** -0.5
     scores = jnp.einsum("bhqd,bhkd->bhqk", q, k) * scale
     seen = dense_mask(mask, scores.shape[-2], scores.shape[-1])
@@ -589,7 +595,8 @@ def _flash_forward(q, k, v, mask, block_q, block_k, emit_lse):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    b, h, s, d = q.shape
+    b, h, s, d = q.shape  # d: the key width, q's and k's
+    dv = v.shape[-1]  # the value width, v's and the output's
     bh = b * h
     # The grid's second axis runs over the run tiles alone, by rows: a
     # row's q, o and lse blocks stay while its k/v tiles stream.
@@ -611,12 +618,15 @@ def _flash_forward(q, k, v, mask, block_q, block_k, emit_lse):
             memory_space=pltpu.VMEM,
         )
 
-    k_spec = pl.BlockSpec(
-        (None, block_k, d), lambda b_, t: (b_, _minor_at(t, run), 0),
-        memory_space=pltpu.VMEM,
-    )
-    out_specs = [q_spec(d)]
-    out_shape = [jax.ShapeDtypeStruct((bh, s, d), q.dtype)]
+    def k_spec(width):
+        return pl.BlockSpec(
+            (None, block_k, width),
+            lambda b_, t: (b_, _minor_at(t, run), 0),
+            memory_space=pltpu.VMEM,
+        )
+
+    out_specs = [q_spec(dv)]
+    out_shape = [jax.ShapeDtypeStruct((bh, s, dv), q.dtype)]
     if emit_lse:
         out_specs.append(q_spec(LANES))
         out_shape.append(
@@ -625,24 +635,24 @@ def _flash_forward(q, k, v, mask, block_q, block_k, emit_lse):
     res = pl.pallas_call(
         kernel,
         grid=(bh, len(run.major)),
-        in_specs=[q_spec(d), k_spec, k_spec],
+        in_specs=[q_spec(d), k_spec(d), k_spec(dv)],
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((block_q, LANES), jnp.float32),
             pltpu.VMEM((block_q, LANES), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
         ],
         interpret=_interpret(),
-        name=_kernel_name(mask, "flash_fwd"),
+        name=_kernel_name(mask, "flash_fwd", d != dv),
     )(
-        q.reshape(bh, s, d), k.reshape(bh, s, d), v.reshape(bh, s, d)
+        q.reshape(bh, s, d), k.reshape(bh, s, d), v.reshape(bh, s, dv)
     )
     if not emit_lse:
-        return res[0].reshape(b, h, s, d), None
+        return res[0].reshape(b, h, s, dv), None
     out, lse = res
     # Keep the residual compact between passes: one lane is the value.
-    return out.reshape(b, h, s, d), lse[:, :, 0].reshape(b, h, s)
+    return out.reshape(b, h, s, dv), lse[:, :, 0].reshape(b, h, s)
 
 
 # ---------- backward kernel ----------
@@ -707,19 +717,21 @@ def _bwd_kernel(
         dq_ref[:] = dq_scr[:].astype(dq_ref.dtype)
 
 
-def _bwd_vmem_bytes(s, d, block_q, block_k, itemsize):
-    """VMEM the backward call asks for, from its shapes: the float32
-    score-sized tiles (scores, p, dp, ds and the two transposes), the
-    double-buffered input and output blocks, dq's row (scratch plus its
-    double-buffered output block) and the dk/dv scratch."""
+def _bwd_vmem_bytes(s, d, dv, block_q, block_k, itemsize):
+    """VMEM the backward call asks for, from its shapes (d the key width,
+    q's, k's and their gradients'; dv the value width, v's, dO's and
+    dv's): the float32 score-sized tiles (scores, p, dp, ds and the two
+    transposes), the double-buffered input and output blocks, dq's row
+    (scratch plus its double-buffered output block) and the dk/dv
+    scratch."""
     tiles = 6 * block_q * block_k * 4
     blocks = 2 * (
-        2 * (block_q + block_k) * d * itemsize  # q, dO; k, v
+        (block_q + block_k) * (d + dv) * itemsize  # q, dO; k, v
         + 2 * block_q * LANES * 4  # lse, delta
-        + 2 * block_k * d * itemsize  # dk, dv
+        + block_k * (d + dv) * itemsize  # dk, dv
     )
     dq_row = s * d * (4 + 2 * itemsize)
-    scratch = 2 * block_k * d * 4
+    scratch = block_k * (d + dv) * 4
     return tiles + blocks + dq_row + scratch
 
 
@@ -727,9 +739,11 @@ def _flash_backward(q, k, v, out, lse, g, mask, block_q, block_k):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    b, h, s, d = q.shape
+    b, h, s, d = q.shape  # d: the key width, q's and k's
+    dv = v.shape[-1]  # the value width: v's, the output's, its cotangent's
     bh = b * h
-    vmem_bytes = _bwd_vmem_bytes(s, d, block_q, block_k, q.dtype.itemsize)
+    vmem_bytes = _bwd_vmem_bytes(
+        s, d, dv, block_q, block_k, q.dtype.itemsize)
     if vmem_bytes > VMEM_BUDGET_BYTES:
         raise ValueError(
             f"flash_attention: the backward keeps one [{s}, {d}] float32 "
@@ -739,8 +753,8 @@ def _flash_backward(q, k, v, out, lse, g, mask, block_q, block_k):
             "(parallel/ring_attention.py, parallel/ulysses.py)"
         )
 
-    q3, k3, v3 = (x.reshape(bh, s, d) for x in (q, k, v))
-    g3 = g.reshape(bh, s, d)
+    q3, k3 = (x.reshape(bh, s, d) for x in (q, k))
+    v3, g3 = (x.reshape(bh, s, dv) for x in (v, g))
     # delta = rowsum(dout * out): one fused elementwise+reduce XLA pass.
     delta = jnp.sum(
         g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1
@@ -761,11 +775,14 @@ def _flash_backward(q, k, v, out, lse, g, mask, block_q, block_k):
             memory_space=pltpu.VMEM,
         )
 
-    k_spec = pl.BlockSpec(
-        (None, block_k, d), lambda b_, t: (b_, _major_at(t, run), 0),
-        memory_space=pltpu.VMEM,
-    )
-    dq, dk, dv = pl.pallas_call(
+    def k_spec(width):
+        return pl.BlockSpec(
+            (None, block_k, width),
+            lambda b_, t: (b_, _major_at(t, run), 0),
+            memory_space=pltpu.VMEM,
+        )
+
+    dq, dk, dv_ = pl.pallas_call(
         functools.partial(
             _bwd_kernel,
             block_q=block_q,
@@ -776,7 +793,7 @@ def _flash_backward(q, k, v, out, lse, g, mask, block_q, block_k):
         ),
         grid=(bh, len(run.major)),
         in_specs=[
-            q_spec(d), k_spec, k_spec, q_spec(d),
+            q_spec(d), k_spec(d), k_spec(dv), q_spec(dv),
             q_spec(LANES), q_spec(LANES),
         ],
         out_specs=[
@@ -785,50 +802,57 @@ def _flash_backward(q, k, v, out, lse, g, mask, block_q, block_k):
                 (None, s, d), lambda b_, t: (b_, 0, 0),
                 memory_space=pltpu.VMEM,
             ),
-            k_spec,
-            k_spec,
+            k_spec(d),
+            k_spec(dv),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, s, d), q.dtype),
             jax.ShapeDtypeStruct((bh, s, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, s, d), v.dtype),
+            jax.ShapeDtypeStruct((bh, s, dv), v.dtype),
         ],
         scratch_shapes=[
             pltpu.VMEM((s, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, dv), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_bytes),
         interpret=_interpret(),
-        name=_kernel_name(mask, "flash_bwd"),
+        name=_kernel_name(mask, "flash_bwd", d != dv),
     )(q3, k3, v3, g3, lse_fat, delta_fat)
 
     return (
         dq.reshape(b, h, s, d),
         dk.reshape(b, h, s, d),
-        dv.reshape(b, h, s, d),
+        dv_.reshape(b, h, s, dv),
     )
 
 
 # ---------- public API with custom VJP ----------
 
 
-def _kernel_name(mask, name):
+def _kernel_name(mask, name, latent=False):
     """The causal and unmasked calls keep their names; a call under block
-    diffusion or under a band carries its own, so a trace tells them
-    apart."""
+    diffusion or under a band carries its own, and so does one whose key
+    width is not its value width (`latent`: `mla_flash_fwd`), so a trace
+    tells them apart."""
     if isinstance(mask, BlockDiffusion):
-        return f"bd_{name}"
-    return f"band_{name}" if isinstance(mask, Band) else name
+        name = f"bd_{name}"
+    elif isinstance(mask, Band):
+        name = f"band_{name}"
+    return f"mla_{name}" if latent else name
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def flash_attention(
     q, k, v, mask=False, block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K
 ):
-    """Attention over [B, H, S, D] under `mask`: False, True (causal), a
-    `BlockDiffusion` or a `Band`. Where the kernel runs, S must be a
-    multiple of the (clamped) block sizes (ValueError otherwise)."""
+    """Attention of q, k [B, H, S, Dk] and v [B, H, S, Dv] under `mask`:
+    False, True (causal), a `BlockDiffusion` or a `Band`; scale Dk^-0.5,
+    the result and its cotangent [B, H, S, Dv]. Dk is Dv in every model
+    but the latent-attention one (192 = 128 without position + 64 rotary,
+    against 128), whose q and k enter as one [block, 192] operand. Where
+    the kernel runs, S must be a multiple of the (clamped) block sizes
+    (ValueError otherwise)."""
     mask = _plain(mask, q.shape[2])
     bq, bk = _clamp_blocks(q.shape[2], block_q, block_k, mask)
     if _pallas_ok(q.shape[2], bq, bk, mask):

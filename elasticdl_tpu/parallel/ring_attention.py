@@ -89,11 +89,23 @@ def refuse_mask_description(causal):
             "take causal=True or False")
 
 
+def refuse_unequal_widths(q, v):
+    """Sequence parallelism takes one head width: a key width beside a
+    value width (latent attention through the flash kernels) is not built
+    under ring or Ulysses attention."""
+    if q.shape[-1] != v.shape[-1]:
+        raise ValueError(
+            f"keys of {q.shape[-1]} against values of {v.shape[-1]} under "
+            "ring / Ulysses attention are not built: they take one head "
+            "width")
+
+
 def ring_attention(q, k, v, axis_name, causal=False):
     """Exact attention with Q/K/V sharded [B, H, S_local, D] along
     `axis_name`. Call INSIDE shard_map; returns the local output block.
     """
     refuse_mask_description(causal)
+    refuse_unequal_widths(q, v)
     axis_size = jax.lax.psum(1, axis_name)
     my_idx = jax.lax.axis_index(axis_name)
     scale = q.shape[-1] ** -0.5
@@ -200,6 +212,7 @@ def zigzag_ring_attention(q, k, v, axis_name, causal=True):
     sharded [B, H, S_local, D] contiguously along `axis_name`. The zigzag
     relayout is internal: inputs/outputs stay contiguously sharded."""
     refuse_mask_description(causal)
+    refuse_unequal_widths(q, v)
     if not causal:
         return ring_attention(q, k, v, axis_name, causal=False)
     axis_size = jax.lax.psum(1, axis_name)

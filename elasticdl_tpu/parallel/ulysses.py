@@ -15,13 +15,17 @@ import functools
 import jax
 
 from elasticdl_tpu.ops.flash_attention import flash_attention
-from elasticdl_tpu.parallel.ring_attention import refuse_mask_description
+from elasticdl_tpu.parallel.ring_attention import (
+    refuse_mask_description,
+    refuse_unequal_widths,
+)
 
 
 def ulysses_attention(q, k, v, axis_name, attention_fn=None, causal=False):
     """Call INSIDE shard_map with q/k/v local blocks [B, H, S_local, D].
     Requires num_heads % axis_size == 0."""
     refuse_mask_description(causal)
+    refuse_unequal_widths(q, v)
     if attention_fn is None:
         # Flash attention by default: the whole point of the re-shard is
         # attending over S_global, and a full score matrix there is the

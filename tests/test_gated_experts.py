@@ -311,6 +311,12 @@ def test_the_epsilon_under_the_normalising_sum_is_an_argument():
     np.testing.assert_allclose(wide[0], [1.0, 0.5], rtol=1e-6)
 
 
-def test_the_gated_form_has_no_shared_expert():
-    with pytest.raises(ValueError, match="no shared expert"):
-        layer(d_shared=8).init(jax.random.PRNGKey(0), jnp.zeros((1, 2, D)))
+def test_the_gated_form_takes_a_gated_shared_expert():
+    """Since PR 55 `d_shared` in the gated form builds one SwiGLU MLP for
+    every token (`shared_gate_up`, `shared_down`) where it raised;
+    tests/test_kanana_moe.py holds it to the mathematics."""
+    params = layer(d_shared=8).init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 2, D)))["params"]
+    assert params["shared_gate_up"]["kernel"].shape == (D, 16)
+    assert params["shared_down"]["kernel"].shape == (8, D)
+    assert "shared_up" not in params

@@ -614,6 +614,13 @@ def mellum_cut_one_chip(topo, no_persistent_compile_cache_in_module):
     return _compile_planned(lambda mp: _plan_step(m, 1, 16384, topo, mp))
 
 
+@pytest.fixture(scope="module")
+def kanana_cut_one_chip(topo, no_persistent_compile_cache_in_module):
+    from elasticdl_tpu.models.kanana import kanana_2_30b_a3b_cut as m
+
+    return _compile_planned(lambda mp: _plan_step(m, 1, 16384, topo, mp))
+
+
 def test_flagship_step_compiles_and_fits_one_v5e(flagship_one_chip):
     """The WHOLE flagship training step of AllReduceTrainer — the program
     `edl train` runs at `flagship_config()` widths, minibatch 4 — for one
@@ -1415,3 +1422,101 @@ def test_band_kernels_partition_over_a_data_mesh(topo, kernel_on):
     assert "band_flash_fwd" in text and "band_flash_bwd" in text
     # Per device: 1 of the 4 rows.
     assert "bf16[1,8,4096,128]" in text
+
+
+# ---------- latent attention: a key width beside a value width ----------
+
+
+def _latent_qkv(sharding, s=16384, bsz=1, heads=32):
+    q = jax.ShapeDtypeStruct((bsz, heads, s, 192), jnp.bfloat16,
+                             sharding=sharding)
+    v = jax.ShapeDtypeStruct((bsz, heads, s, 128), jnp.bfloat16,
+                             sharding=sharding)
+    return q, q, v
+
+
+def test_latent_kernels_compile_for_v5e(one_chip, kernel_on):
+    """Forward and backward at keys of 192 against values of 128,
+    `[1, 32, 16384, .]`, for the described chip: q and k enter as one
+    [1024, 192] operand each (a block's last dimension may be the array's
+    whole one), the output and dv at 128, dq and dk at 192, under names of
+    their own."""
+    def loss(q, k, v):
+        return fa.flash_attention(q, k, v, True).astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        *_latent_qkv(one_chip)).compile()
+    fwd, = _kernel_calls(compiled.as_text(), "mla_flash_fwd")
+    bwd, = _kernel_calls(compiled.as_text(), "mla_flash_bwd")
+    assert fwd[0].startswith("(bf16[32,16384,128], f32[32,16384,128]")
+    assert fwd[1] == ["bf16[32,16384,192]"] * 2 + ["bf16[32,16384,128]"]
+    assert bwd[0].startswith(
+        "(bf16[32,16384,192], bf16[32,16384,192], bf16[32,16384,128]")
+    assert bwd[1][:4] == ["bf16[32,16384,192]"] * 2 + [
+        "bf16[32,16384,128]"] * 2
+    assert len(_kernel_calls(compiled.as_text())) == 2
+
+
+def test_latent_kernels_partition_over_a_data_mesh(topo, kernel_on):
+    """The unequal widths under the trainer's abstract mesh over the four
+    described chips: each batch shard runs the calls on its own rows."""
+    mesh = jax.sharding.Mesh(np.array(topo.devices), ("data",))
+    sharded = NamedSharding(mesh, P("data"))
+
+    def loss(q, k, v):
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return jnp.sum(fa.flash_attention(q, k, v, True))
+
+    text = (
+        jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+        .lower(*_latent_qkv(sharded, s=4096, bsz=4, heads=8))
+        .compile().as_text()
+    )
+    assert text.count("tpu_custom_call") == 2
+    assert "mla_flash_fwd" in text and "mla_flash_bwd" in text
+    assert "bf16[1,8,4096,192]" in text and "bf16[1,8,4096,128]" in text
+
+
+def test_kanana_cut_step_compiles_and_fits_one_v5e(kanana_cut_one_chip):
+    """The WHOLE training step of the kanana-2-30b-a3b cut (687.5 M
+    parameters at 16 bytes each, minibatch 1 x S 16384, as `edl train` runs
+    `kanana_2_30b_a3b_cut`) for one described chip: the flash kernels at
+    keys of 192 against values of 128 in all six layers, handed the
+    activation dtype, under names of their own and no causal call of one
+    width beside them; the gated grouped product over 16 held experts and
+    the shared experts' gated MLP in five layers, the dense MLP in the
+    first; the untied head over an eighth of the vocabulary; it fits 16 GB
+    with the remat the model-def states (the fit reading of the
+    configuration file's `model.remat_reason`), and hands six counters
+    back beside the loss."""
+    from elasticdl_tpu.models.kanana import kanana_2_30b_a3b_cut as m
+
+    step = kanana_cut_one_chip
+    assert step.out_tree.children()[2].num_leaves == 7  # the loss and six
+    calls = _kernel_calls(step.text, "flash_")
+    assert calls == _kernel_calls(step.text, "mla_flash_")
+    # Six layers: a forward (and a rematerialised twin in five) and a
+    # backward.
+    remat = len(m.REMAT_LAYERS)
+    assert len(_kernel_calls(step.text, "mla_flash_bwd")) == 6
+    assert len(_kernel_calls(step.text, "mla_flash_fwd")) == 6 + remat
+    for results, operands in calls:
+        assert results.startswith((
+            "(bf16[32,16384,128], f32[32,16384,128]",
+            "(bf16[32,16384,192], bf16[32,16384,192], bf16[32,16384,128]",
+        )), results
+        assert set(operands) <= {
+            "bf16[32,16384,192]", "bf16[32,16384,128]",
+            "f32[32,16384,128]"}
+    assert step.resident < HBM_BYTES, f"{step.resident / 2**30:.2f} GiB"
+    # The chip gives a program 15.75 GiB: the remat chosen leaves a GiB.
+    assert step.resident < 14.75 * 2**30, f"{step.resident / 2**30:.2f} GiB"
+    # params + Adam m and v
+    assert step.argument_bytes > 8.2e9
+    assert {"f32[16,2048,1536]", "f32[16,768,2048]", "f32[128,2048]",
+            "f32[2048,3072]", "f32[1536,2048]", "f32[2048,32,192]",
+            "f32[2048,576]", "f32[512,32,256]", "f32[4096,2048]",
+            "f32[2048,6144]", "f32[6144,2048]", "f32[16032,2048]",
+            "f32[2048,16032]"} <= step.weights
+    assert "tensor<1x16384x16032xf32>" in step.lowered
+    print(f"kanana cut: resident {step.resident / 2**30:.2f} GiB")
