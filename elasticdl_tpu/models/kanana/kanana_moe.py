@@ -57,7 +57,7 @@ from elasticdl_tpu.models.lfm2.lfm2_moe import rotary
 from elasticdl_tpu.models.nemotron_h.nemotron_h import RMSNorm
 from elasticdl_tpu.models.transformer import transformer_lm as tlm
 from elasticdl_tpu.ops import optimizers
-from elasticdl_tpu.ops.flash_attention import flash_attention
+from elasticdl_tpu.ops.flash_attention import KEPT, flash_attention
 
 Q_SCOPE = "kanana_q_proj"
 KV_DOWN_SCOPE = "kanana_kv_down"
@@ -104,7 +104,8 @@ class KananaMoeConfig:
     # Rows of one block of the grouped expert product.
     expert_block_rows: int = 1024
     activation_dtype: str = "bfloat16"
-    # The layers rematerialised in the backward pass (memory for FLOPs).
+    # The layers rematerialised in the backward pass (memory for FLOPs);
+    # each keeps its attention's output and lse (`KananaMoe.__call__`).
     remat_layers: Tuple[int, ...] = ()
 
     def __post_init__(self):
@@ -263,8 +264,13 @@ class KananaMoe(nn.Module):
                      embedding_init=cfg.init, name="embed_tokens")(
                          tokens.astype(jnp.int32))
         totals = None
+        # A rematerialised layer keeps what its flash kernel made (128 MiB
+        # of output and 2 MiB of lse at the cut's shapes): q, k and v are
+        # projections away, the kernel is a tenth of the step.
+        remat_block = nn.remat(
+            Block, policy=jax.checkpoint_policies.save_only_these_names(*KEPT))
         for i in range(cfg.num_hidden_layers):
-            block_cls = nn.remat(Block) if i in cfg.remat_layers else Block
+            block_cls = remat_block if i in cfg.remat_layers else Block
             h, stats = block_cls(cfg, i, name=f"layers_{i}")(h)
             if stats is not None:
                 totals = stats if totals is None else jax.tree_util.tree_map(

@@ -912,9 +912,9 @@ def _fwd(q, k, v, mask, block_q, block_k):
                 q, k, v, mask, bq, bk, emit_lse=True
             )
         )(q, k, v)
-        return out, (q, k, v, out, lse)
+        return _kept(q, k, v, out, lse)
     out = _fallback_attention(q, k, v, mask)
-    return out, (q, k, v, out, None)
+    return _kept(q, k, v, out, None)
 
 
 def _bwd(mask, block_q, block_k, residuals, g):
@@ -926,6 +926,26 @@ def _bwd(mask, block_q, block_k, residuals, g):
             lambda *a: _flash_backward(*a, mask, bq, bk)
         )(q, k, v, out, lse, g)
     return _bwd_xla(q, k, v, out, g, mask)
+
+
+# What the kernel made, by the names a rematerialised layer's policy may
+# save (`jax.checkpoint_policies.save_only_these_names(*KEPT)`): the
+# output and the compact lse [B, H, S], never the kernel's lane-replicated
+# one. Without such a policy the names are identities.
+KEPT = ("flash_out", "flash_lse")
+
+
+def _kept(q, k, v, out, lse):
+    """`_fwd`'s result and residuals with `out` and `lse` under their
+    names: the layer's next product and `_bwd` then read what a policy
+    saved, and the recomputed call is dead code. (Kept below `_fwd` and
+    `_bwd`, like `_fallback_attention`.)"""
+    from jax.ad_checkpoint import checkpoint_name
+
+    out = checkpoint_name(out, KEPT[0])
+    if lse is not None:  # the fallback path keeps none
+        lse = checkpoint_name(lse, KEPT[1])
+    return out, (q, k, v, out, lse)
 
 
 def _fallback_attention(q, k, v, mask):

@@ -426,20 +426,44 @@ def test_the_step_hands_back_the_routed_layers_counts():
     assert set(dense.apply(dense_vars, x, training=True)) == {"logits"}
 
 
+def _loss_and_gradients(model, variables, tokens):
+    """params -> (loss, gradients) of one record through `model`."""
+    def f(params):
+        out = model.apply(dict(variables, params=params), tokens[:, :-1],
+                          training=True)
+        return kanana_moe.loss(tokens[:, 1:], out)
+    return jax.value_and_grad(f)
+
+
 def test_remat_gives_the_same_loss_and_gradients():
+    """A rematerialised layer computes what the layer computes and keeps
+    its attention's bits: the loss and every gradient leaf to the bit."""
     model, variables, tokens = tiny()
     again = kanana_moe.custom_model(
         dataclasses.replace(model.config, remat_layers=(0, 2)))
-
-    def loss_of(m):
-        def f(params):
-            out = m.apply(dict(variables, params=params), tokens[:, :-1],
-                          training=True)
-            return kanana_moe.loss(tokens[:, 1:], out)
-        return jax.value_and_grad(f)(variables["params"])
-
-    (a, ga), (b, gb) = loss_of(model), loss_of(again)
-    assert float(a) == pytest.approx(float(b), rel=1e-6)
+    (a, ga), (b, gb) = (
+        _loss_and_gradients(m, variables, tokens)(variables["params"])
+        for m in (model, again))
+    assert float(a) == float(b)
     for x, y in zip(jax.tree_util.tree_leaves(ga),
                     jax.tree_util.tree_leaves(gb)):
-        np.testing.assert_allclose(x, y, atol=1e-5)
+        np.testing.assert_array_equal(x, y)
+
+
+@pytest.mark.parametrize("remat_layers", [(), (0, 2), (0, 1, 2)])
+def test_a_rematerialised_layer_keeps_its_attention_result(
+        monkeypatch, remat_layers):
+    """The step as the chip would trace it: one forward flash call a layer
+    and one backward, however many of the layers are rematerialised (the
+    policy at the remat site saves `flash_attention.KEPT`, so no layer's
+    backward pass runs the forward kernel again)."""
+    from elasticdl_tpu.ops import flash_attention as fa
+
+    model, variables, tokens = tiny(remat_layers=remat_layers)
+    monkeypatch.delenv("EDL_FORCE_PALLAS_INTERPRET", raising=False)
+    monkeypatch.setattr(fa, "_use_pallas", lambda: True)
+    text = str(jax.make_jaxpr(_loss_and_gradients(
+        model, variables, tokens))(variables["params"]))
+    layers = model.config.num_hidden_layers
+    assert text.count("name=mla_flash_fwd") == layers
+    assert text.count("name=mla_flash_bwd") == layers

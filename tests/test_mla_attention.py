@@ -3,7 +3,8 @@
 position + 64 rotary against values of 128): the kernels in interpret mode
 against the dense oracle, forward and all three gradients; equal widths as
 the calls they were before the widths parted; the names the unequal calls
-carry; what sequence parallelism refuses."""
+carry; what a rematerialised caller keeps by the names of `KEPT`; what
+sequence parallelism refuses."""
 
 import hashlib
 
@@ -222,6 +223,68 @@ def test_unequal_widths_carry_names_and_shapes_of_their_own(monkeypatch):
         + 16384 * 192 * 8 + 1024 * 320 * 4)
     assert fa._bwd_vmem_bytes(
         16384, 192, 128, 1024, 1024, 2) < fa.VMEM_BUDGET_BYTES
+
+
+# ---------- a rematerialised caller that keeps the kernel's result ----------
+
+
+KEPT_CASES = [
+    ("causal192_128", True, 192, 128), ("causal128", True, 128, 128),
+    ("band", Band(128), 128, 128),
+    ("block_diffusion", BlockDiffusion(4, 128), 128, 128),
+]
+
+
+def _forward_calls(jaxpr, path):
+    """Attention forwards in a traced value-and-gradient: on the kernel's
+    path the `pallas_call`s named `*flash_fwd`; on the XLA path, where a
+    forward is two products and the one backward five, by the products."""
+    if path == "kernel":
+        return sum(e.params["name"].endswith("flash_fwd")
+                   for e in _pallas_calls(jaxpr))
+    return (str(jaxpr).count("dot_general") - 5) // 2
+
+
+@pytest.mark.parametrize("path", ["kernel", "fallback"])
+@pytest.mark.parametrize("case", KEPT_CASES, ids=lambda c: c[0])
+def test_a_remat_that_keeps_the_names_runs_the_forward_once(
+        monkeypatch, case, path):
+    """Under `jax.checkpoint(f, policy=save_only_these_names(*KEPT))` the
+    output and the compact lse (on the XLA path, where lse is None, the
+    output alone) are saved, so the backward pass holds no forward call,
+    where a plain `jax.checkpoint` runs it a second time; the gradients
+    are those of no remat at all, to the bit, under every mask that
+    exists. Counted as the chip would trace it, run interpreted."""
+    _, mask, dk, dv = case
+    q, k, v, _ = _qkvg(dk, 256, dk, dv, "bfloat16")
+    policy = jax.checkpoint_policies.save_only_these_names(*fa.KEPT)
+
+    def forms():  # anew a trace: `jax.checkpoint` keeps a function's
+        def f(q, k, v):  # a product after the call, as a layer has
+            out = flash_attention(q, k, v, mask, 128, 128)
+            return jnp.sum(out.astype(jnp.float32) ** 2)
+
+        return {"none": f, "plain": jax.checkpoint(f),
+                "kept": jax.checkpoint(f, policy=policy)}
+
+    monkeypatch.delenv("EDL_FORCE_PALLAS_INTERPRET", raising=False)
+    monkeypatch.setattr(fa, "_use_pallas", lambda: path == "kernel")
+    calls = {
+        name: _forward_calls(jax.make_jaxpr(
+            jax.value_and_grad(f, (0, 1, 2)))(q, k, v).jaxpr, path)
+        for name, f in forms().items()}
+    assert calls == {"none": 1, "plain": 2, "kept": 1}
+    monkeypatch.undo()
+    if path == "kernel":
+        monkeypatch.setenv("EDL_FORCE_PALLAS_INTERPRET", "1")
+    got = {name: jax.value_and_grad(f, (0, 1, 2))(q, k, v)
+           for name, f in forms().items()}
+    for name in ("plain", "kept"):
+        for a, b in zip(jax.tree_util.tree_leaves(got[name]),
+                        jax.tree_util.tree_leaves(got["none"])):
+            assert a.dtype == b.dtype
+            np.testing.assert_array_equal(
+                np.asarray(a, np.float32), np.asarray(b, np.float32), name)
 
 
 def test_unequal_widths_under_ring_or_ulysses_attention_raise():
