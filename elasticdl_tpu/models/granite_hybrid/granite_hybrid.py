@@ -56,7 +56,7 @@ from elasticdl_tpu.models.nemotron_h.nemotron_h import (  # noqa: F401
 )
 from elasticdl_tpu.ops.causal_conv import causal_conv_silu
 from elasticdl_tpu.ops.flash_attention import flash_attention
-from elasticdl_tpu.ops.ssd_scan import runs_as_kernel, ssd_scan
+from elasticdl_tpu.ops.ssd_scan import ssd_scan
 
 MIXERS = ("mamba", "attention")
 MIXER_SCOPE = "granite_mixer"
@@ -291,11 +291,7 @@ class GraniteHybrid(nn.Module):
         if not training:
             return logits
         scanned = jnp.asarray(tokens.size * cfg.scanning_layers, f32)
-        stats = {"ssd_scan_tokens": scanned}
-        if runs_as_kernel():
-            # Every mixer was handed the kernels, and here they run.
-            stats["ssd_kernel_tokens"] = scanned
-        return {"logits": logits, "stats": stats}
+        return {"logits": logits, "stats": {"ssd_scan_tokens": scanned}}
 
 
 # ---------- model spec contract ----------

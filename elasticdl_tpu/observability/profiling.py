@@ -578,6 +578,11 @@ def capture_device_profile(seconds, out_dir):
         finally:
             tracing.set_profiler_session(False)
             jax.profiler.stop_trace()
+        # Which scope every instruction of the training step belongs to,
+        # beside the trace it names them in (a worker; else nothing).
+        from elasticdl_tpu.observability import step_scopes
+
+        step_scopes.write_for_running_step(target)
         files, total = [], 0
         for root, _, names in os.walk(target):
             for n in names:

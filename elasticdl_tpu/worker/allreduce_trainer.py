@@ -1362,6 +1362,38 @@ class AllReduceTrainer(JaxTrainer):
         )
         return jax.tree_util.tree_map(np.asarray, outputs)
 
+    def step_for_scopes(self):
+        """(the sharded step of the newest batch, the shapes of its
+        arguments, the mesh it is called in) for a map of its scopes
+        (`observability/step_scopes.py`), or None before the first step.
+        Each shape carries the sharding its array is placed with, the
+        batch's as `shard_batch` places it: with the mesh, that is what
+        jax keys a trace on, so that `lower` finds the step as it runs."""
+        from elasticdl_tpu.observability.step_scopes import abstract_of
+        from elasticdl_tpu.parallel.mesh import data_sharding
+
+        if self._variables is None or self._last_batch_abstract is None:
+            return None
+        feat_abs, label_abs, real_n = self._last_batch_abstract
+        multiple = step_plan.batch_multiple(self._step_model(), self._mesh)
+        padded_n = -(-real_n // multiple) * multiple
+        step = self._sharded_steps.get((real_n, padded_n))
+        if step is None:
+            return None
+        data = data_sharding(self._mesh)
+
+        def placed(s):
+            return jax.ShapeDtypeStruct(
+                (padded_n,) + tuple(s.shape[1:]),
+                jax.dtypes.canonicalize_dtype(s.dtype), sharding=data)
+
+        return step, (
+            abstract_of(self._variables), abstract_of(self._opt_state),
+            abstract_of(self._step_rng_base),
+            jax.tree_util.tree_map(placed, feat_abs),
+            jax.tree_util.tree_map(placed, label_abs),
+        ), self._mesh
+
     def close(self):
         self._speculator.stop()
         self._broadcast_server.stop()

@@ -289,11 +289,31 @@ class JaxTrainer(Trainer):
         self._variables, self._opt_state, loss = self._train_step(
             *step_args
         )
+        self._last_batch = step_args[3:]
         loss, self.last_step_stats = split_stats(loss)
         self._version += 1
         # Lazy device scalar: converting to float here would block the host
         # on every step and serialize dispatch (the round-1 bench ceiling).
         return True, self._version, loss
+
+    # The newest step's (features, labels) on the device: their shapes are
+    # what a map of the step's scopes is lowered with. Down here, not in
+    # the constructor: the lines above the step's call are in the compile
+    # cache's key (`profiling.open_compile`).
+    _last_batch = None
+
+    def step_for_scopes(self):
+        """(the training step, the shapes of its arguments, the context it
+        is called in: none) for a map of its scopes
+        (`observability/step_scopes.py`), or None before the first step.
+        Shapes, never the live buffers: those are donated."""
+        from elasticdl_tpu.observability.step_scopes import abstract_of
+
+        if self._last_batch is None:
+            return None
+        return self._train_step, abstract_of(
+            (self._variables, self._opt_state, self._rng)
+            + tuple(self._last_batch)), None
 
     def evaluate_minibatch(self, features, model_version=-1):
         self.init_variables_if_needed(features)

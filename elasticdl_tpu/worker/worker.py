@@ -473,6 +473,20 @@ class Worker:
             "setup.first_task", started, time.time() - started,
             cat=tracing.SETUP,
         )
+        # A profile taken from outside this loop (`/debug/profile`) asks
+        # here for the step it saw. Said once, down here and not in the
+        # constructor: the lines above a step's call are in the compile
+        # cache's key (`profiling.open_compile`); imported here likewise.
+        from elasticdl_tpu.observability import step_scopes
+
+        step_scopes.note_running_step(self._running_step)
+
+    def _running_step(self):
+        """(the trainer's training step, its arguments' shapes, the
+        context it is called in) or None: what
+        `observability/step_scopes.py` makes its map of."""
+        ask = getattr(self._trainer, "step_for_scopes", None)
+        return ask() if ask is not None else None
 
     def _log_unread_loss(self):
         """Read and log the loss of the last logging step. Called once
@@ -539,11 +553,38 @@ class Worker:
                 self._profile_dir,
             )
         elif self._profiling and next_step >= end:
-            self._stop_profile_if_running()
+            self._stop_profile_if_running(last=False)
 
-    def _stop_profile_if_running(self):
-        if not self._profiling:
-            return
+    # The thread that writes the profiled step's scopes, in a profiled run
+    # and from the window's end until its file is written; else None.
+    _scopes_writer = None
+
+    def _stop_profile_if_running(self, last=True):
+        """Close the trace window if it is open. `last`: the run ends
+        here, so the thread that writes the step's scopes is waited for."""
+        if self._profiling:
+            self._close_profile()
+        if last and self._scopes_writer is not None:
+            # Seconds of work; a map that hangs must not hold the job.
+            self._scopes_writer.join(timeout=120)
+            self._scopes_writer = None
+
+    def _start_step_scopes(self):
+        """Which scope every instruction of the step belongs to, beside
+        the trace: written on a thread of its own while this one
+        dispatches on. A trainer that cannot say is a warning, never the
+        profile's failure."""
+        from elasticdl_tpu.observability import step_scopes
+
+        try:
+            step = self._running_step()
+            if step is not None:
+                self._scopes_writer = step_scopes.start_writing(
+                    self._profile_dir, *step)
+        except Exception:
+            logger.warning("No step scopes for this profile", exc_info=True)
+
+    def _close_profile(self):
         import jax
 
         self._profiling = False
@@ -552,6 +593,7 @@ class Worker:
         try:
             with self._step_clock.profile_call():
                 jax.profiler.stop_trace()
+                self._start_step_scopes()
             logger.info(
                 "Profile written to %s (view: tensorboard --logdir %s)",
                 self._profile_dir,

@@ -200,8 +200,6 @@ def test_the_step_counts_what_it_scans(tiny):
     out = gh.custom_model(CONFIG).apply(
         {"params": params}, tokens, training=True)
     batch = tokens.shape[0]
-    # Off the TPU the scan's call runs `ssd_chunked`: no token by kernel,
-    # and no key for them.
     assert {k: float(v) for k, v in out["stats"].items()} == {
         "ssd_scan_tokens": batch * LENGTH * 3}
     # Evaluation hands back plain logits.
@@ -209,10 +207,18 @@ def test_the_step_counts_what_it_scans(tiny):
         {"params": params}, tokens).shape == (batch, LENGTH, 256)
 
 
-def test_where_the_kernels_run_every_scanned_token_is_a_kernels(monkeypatch):
-    """`ssd_kernel_tokens == ssd_scan_tokens` where `ops/ssd_scan.py` runs
-    its kernels (the TPU; here the interpreter), at sizes they tile, and
-    the loss is the one the same model reads through `ssd_chunked`."""
+def test_where_the_kernels_run_every_scan_is_a_kernels(monkeypatch):
+    """Where `ops/ssd_scan.py` runs its kernels (the TPU; here the
+    interpreter), at sizes they tile, every scan of the compiled step is
+    the kernels': under `SCAN_SCOPE` the step's scopes
+    (`observability/step_scopes.py`, what a traced run writes beside its
+    profile) hold `ssd_scan_fwd` and `ssd_scan_bwd` and nothing of
+    `ssd_chunked`, which is all they hold where the kernels do not run;
+    the statistics are the same either way, and the loss is the one the
+    same model reads through `ssd_chunked`."""
+    from elasticdl_tpu.layers.mamba2 import SCAN_SCOPE
+    from elasticdl_tpu.observability import step_scopes
+
     config = dataclasses.replace(
         CONFIG, layer_types=("mamba", "attention", "mamba"),
         mamba_n_heads=8, mamba_d_head=64, mamba_d_state=128,
@@ -222,16 +228,31 @@ def test_where_the_kernels_run_every_scanned_token_is_a_kernels(monkeypatch):
     model = gh.custom_model(config)
     params = model.init({"params": jax.random.PRNGKey(2)}, tokens[:, :-1])
 
-    def run():
-        out = model.apply(params, tokens[:, :-1], training=True)
-        return ({k: float(v) for k, v in out["stats"].items()},
-                float(gh.loss(tokens[:, 1:], out)))
+    def loss_and_stats(p):
+        out = model.apply(p, tokens[:, :-1], training=True)
+        return gh.loss(tokens[:, 1:], out), out["stats"]
 
-    chunked_stats, chunked_loss = run()
+    def run():
+        step = jax.jit(jax.value_and_grad(loss_and_stats, has_aux=True))
+        ((loss, stats), _), text = step(params), step.lower(
+            params).compile().as_text()
+        scans = {}
+        for row in step_scopes.rows_of(text)[1]:
+            parts = row["scope"].split("/")
+            if SCAN_SCOPE in parts:
+                scans.setdefault(row["phase"], set()).update(
+                    parts[parts.index(SCAN_SCOPE) + 1:])
+                assert row["kind"] == step_scopes.MIXER
+        return {k: float(v) for k, v in stats.items()}, float(loss), scans
+
+    chunked_stats, chunked_loss, chunked_scans = run()
     monkeypatch.setenv("EDL_FORCE_PALLAS_INTERPRET", "1")
-    stats, loss = run()
-    assert chunked_stats == {"ssd_scan_tokens": 2 * 256 * 2}
-    assert stats["ssd_kernel_tokens"] == stats["ssd_scan_tokens"] == 1024
+    stats, loss, scans = run()
+    assert chunked_stats == stats == {"ssd_scan_tokens": 2 * 256 * 2}
+    assert "ssd_chunked" in chunked_scans["fwd"] & chunked_scans["bwd"]
+    assert not any("ssd_scan_" in s for s in set().union(
+        *chunked_scans.values()))
+    assert scans == {"fwd": {"ssd_scan_fwd"}, "bwd": {"ssd_scan_bwd"}}
     assert abs(loss - chunked_loss) < 1e-4 * abs(chunked_loss)
 
 

@@ -1193,8 +1193,8 @@ def test_granite_cut_step_compiles_and_fits_one_v5e(granite_cut_one_chip):
     )
 
     step = granite_cut_one_chip
-    # The loss and the two counters.
-    assert step.out_tree.children()[2].num_leaves == 3
+    # The loss and the one counter (`ssd_scan_tokens`).
+    assert step.out_tree.children()[2].num_leaves == 2
     calls = _kernel_calls(step.text)
     # One attention layer: flash_fwd (and a rematerialised twin) and
     # flash_bwd, the kernels every causal cell runs.
@@ -1520,3 +1520,101 @@ def test_kanana_cut_step_compiles_and_fits_one_v5e(kanana_cut_one_chip):
             "f32[2048,16032]"} <= step.weights
     assert "tensor<1x16384x16032xf32>" in step.lowered
     print(f"kanana cut: resident {step.resident / 2**30:.2f} GiB")
+
+
+# ---------- the map of a step's scopes, over the steps compiled above ----------
+
+_COMMON_KINDS = {"attention", "attention_kernel", "embed_head_loss", "update",
+                 "other"}
+# fixture -> (the kinds its model has beside the common ones, the layers it
+# rematerialises, the most fusions the compiler may leave without a name).
+SCOPES_OF = {
+    "flagship_one_chip": ({"mlp"}, set(), 0),
+    "flagship_dp4": ({"mlp"}, set(), 0),
+    "nemotron_h_cut_one_chip": ({"mixer", "moe"}, set(range(9)), 4),
+    "lfm2_cut_one_chip": ({"mixer", "moe", "mlp"}, set(), 6),
+    "sdar_cut_one_chip": ({"moe"}, set(), 8),
+    "granite_cut_one_chip": ({"mixer", "mlp"}, set(range(10)), 2),
+    "mellum_cut_one_chip": ({"moe"}, {0, 1}, 6),
+    "kanana_cut_one_chip": ({"moe", "mlp"}, {1, 2, 3, 4, 5}, 7),
+}
+# What the compiler makes and names for no line of the program: prefetches
+# and their waits, parameters, reshapes of layout, the pieces of tuples,
+# and the scalar comparators and reducers that sorts and reductions call.
+UNNAMED_OPCODES = {
+    "slice-start", "slice-done", "copy-start", "copy-done", "copy",
+    "parameter", "bitcast", "bitcast-convert", "get-tuple-element", "tuple",
+    "custom-call", "fusion", "constant", "iota", "broadcast", "reshape",
+    "add", "and", "xor", "compare", "select", "reduce", "reduce-window",
+}
+# The Pallas calls, by the name each `pallas_call` was given, and the kind
+# and the passes the map has to put them in. The head norm and rotary
+# turn's calls (`ops/qk_rotary.py`) are the attention's and no attention
+# call: `attention_kernel` is `ops/flash_attention.py`'s alone, so that its
+# share is what the by-name readers of the flash calls read.
+_HLO_NAME = re.compile(r"\s*(?:ROOT )?%(\S+) = ")
+PALLAS_CALLS = {
+    "flash_fwd": ("attention_kernel", {"fwd", "remat"}),
+    "flash_bwd": ("attention_kernel", {"bwd"}),
+    "qk_rotary_fwd": ("attention", {"fwd", "remat"}),
+    "qk_rotary_bwd": ("attention", {"bwd"}),
+    "ssd_scan_fwd": ("mixer", {"fwd", "remat"}),
+    "ssd_scan_bwd": ("mixer", {"bwd"}),
+    "causal_conv_fwd": ("mixer", {"fwd", "remat"}),
+    "causal_conv_bwd": ("mixer", {"bwd"}),
+}
+
+
+@pytest.mark.parametrize("fixture", sorted(SCOPES_OF))
+def test_the_steps_scopes_tell_every_kernel_kind_and_pass(request, fixture):
+    """`observability/step_scopes.py` over what the v5e compiler made of
+    each cell's step: every instruction a row, every Pallas call of the
+    kind and pass its name says, the kinds the model has and no other, the
+    rematerialised layers and no other, and nothing that takes time left
+    without a name beyond the compiler's own."""
+    from elasticdl_tpu.observability import step_scopes
+
+    kinds, remat_layers, unnamed_fusions = SCOPES_OF[fixture]
+    text = request.getfixturevalue(fixture).text
+    module, rows = step_scopes.rows_of(text)
+    assert module == "jit_step_fn"
+    names = [r["name"] for r in rows]
+    assert len(set(names)) == len(names)
+    named = [r for r in rows if r["phase"] != "none"]
+    assert len(named) > 1400 and len(rows) > 4000
+
+    pallas = {
+        _HLO_NAME.match(line).group(1) for line in text.splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line}
+    calls = [r for r in rows if r["name"] in pallas]
+    assert len(calls) == len(pallas) >= 3
+    for row in calls:
+        call = row["scope"].split("/")[-1]
+        known = next(k for k in PALLAS_CALLS if call.endswith(k))
+        kind, phases = PALLAS_CALLS[known]
+        assert (row["kind"], row["phase"] in phases) == (kind, True), row
+        assert row["layer"] is not None
+
+    assert {r["kind"] for r in named} == _COMMON_KINDS | kinds
+    assert {r["kind"] for r in rows if r["phase"] == "none"} == {"other"}
+    assert {r["layer"] for r in rows
+            if r["phase"] == "remat"} - {None} == remat_layers
+    assert {r["kind"] for r in named if r["phase"] == "update"} == {
+        "update"}
+
+    unnamed = [r for r in rows if r["phase"] == "none"]
+    assert {r["opcode"] for r in unnamed} <= UNNAMED_OPCODES
+    assert not [r for r in unnamed if r["opcode"] in (
+        "while", "call", "conditional")]
+    assert sum(r["opcode"] == "fusion"
+               for r in unnamed) <= unnamed_fusions
+    if fixture == "kanana_cut_one_chip":
+        # PR 56's policy through the map: a rematerialised layer runs
+        # everything again but the attention's kernel.
+        again = {r["scope"].split("/")[2] for r in named
+                 if r["phase"] == "remat" and r["scope"].count("/") >= 2}
+        assert not [r for r in named if r["phase"] == "remat"
+                    and r["kind"] == "attention_kernel"]
+        assert "kanana_latent_attention" not in again
+        assert {"kanana_q_proj", "kanana_kv_down", "kanana_kv_up",
+                "kanana_rope", "kanana_o_proj", "mlp"} <= again
