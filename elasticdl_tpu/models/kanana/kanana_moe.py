@@ -13,6 +13,8 @@ Layer l, each half behind its own pre-norm residual (RMSNorm, `rms_norm_eps`):
         channels alone (`rope_theta`, the published pairing (2i, 2i + 1):
         `rope_interleave`; lfm2_moe.rotary), q_nope and k_nope not at all
         k = [k_nope | k_rope for every head], q = [q_nope | q_rope]
+        (the turn, the joins, the split of k_nope and v and the change to
+        the kernels' layout in one op: ops/mla_rotary.py)
         A causal softmax attention at scale (nope + rope)^-0.5 inside the
         flash kernels, keys of nope + rope against values of `v_head_dim`
         (ops/flash_attention.py: `mla_flash_fwd`, `mla_flash_bwd`)
@@ -30,6 +32,9 @@ Layer l, each half behind its own pre-norm residual (RMSNorm, `rms_norm_eps`):
                                   (the shared experts, as HF builds them);
                                   no auxiliary loss; layers/moe.py
     final RMSNorm, logits through an untied head, next-token cross-entropy.
+    Each layer and the head hand their cotangents out together in the
+    backward pass (`_cotangents_together`): the same values, a schedule that
+    makes every weight gradient beside the cotangent it reads.
 
 `KananaMoeConfig` takes the keys of the public `config.json` under their own
 names (`from_public`), plus `experts_held = (first, count)`: the share of
@@ -53,11 +58,11 @@ import jax
 import jax.numpy as jnp
 
 from elasticdl_tpu.layers.moe import RoutedExperts
-from elasticdl_tpu.models.lfm2.lfm2_moe import rotary
 from elasticdl_tpu.models.nemotron_h.nemotron_h import RMSNorm
 from elasticdl_tpu.models.transformer import transformer_lm as tlm
 from elasticdl_tpu.ops import optimizers
 from elasticdl_tpu.ops.flash_attention import KEPT, flash_attention
+from elasticdl_tpu.ops.mla_rotary import mla_rotary, rope_tables
 
 Q_SCOPE = "kanana_q_proj"
 KV_DOWN_SCOPE = "kanana_kv_down"
@@ -110,6 +115,7 @@ class KananaMoeConfig:
 
     def __post_init__(self):
         for key, built in (("q_lora_rank", None), ("rope_scaling", None),
+                           ("rope_interleave", True),
                            ("n_group", 1), ("topk_group", 1),
                            ("scoring_func", "sigmoid")):
             if getattr(self, key) != built:
@@ -146,12 +152,17 @@ class KananaMoeConfig:
     def init(self):
         return nn.initializers.normal(self.initializer_range)
 
+    def rope_tables(self, s):
+        """(cos, sin) of rows 0 .. s - 1 for `mla_rotary`: once a step."""
+        return rope_tables(s, self.rope_theta, self.qk_rope_head_dim)
+
 
 class LatentAttention(nn.Module):
     config: KananaMoeConfig
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, tables):
+        """`tables`: the step's (cos, sin) of `KananaMoeConfig.rope_tables`."""
         cfg = self.config
         dtype = jnp.dtype(cfg.activation_dtype)
         heads, nope, rope, dv = (
@@ -164,8 +175,7 @@ class LatentAttention(nn.Module):
                 kernel_init=cfg.init, name=name)(u)
 
         with jax.named_scope(Q_SCOPE):
-            q_nope, q_rope = jnp.split(
-                heads_of(nope + rope, "q_proj", x), [nope], axis=-1)
+            q_proj = heads_of(nope + rope, "q_proj", x)
         with jax.named_scope(KV_DOWN_SCOPE):
             latent, k_rope = jnp.split(nn.Dense(
                 cfg.kv_lora_rank + rope, use_bias=False, dtype=dtype,
@@ -174,22 +184,15 @@ class LatentAttention(nn.Module):
             latent = RMSNorm(cfg.rms_norm_eps, cfg.activation_dtype,
                              name="kv_a_layernorm")(latent)
         with jax.named_scope(KV_UP_SCOPE):
-            k_nope, v = jnp.split(
-                heads_of(nope + dv, "kv_b_proj", latent), [nope], axis=-1)
-        with jax.named_scope(ROPE_SCOPE):
-            q_rope, k_rope = (
-                rotary(t, cfg.rope_theta,
-                       interleave=cfg.rope_interleave).astype(dtype)
-                for t in (q_rope, k_rope[:, :, None, :]))
+            kv_up = heads_of(nope + dv, "kv_b_proj", latent)
         # [B, S, H, D] -> [B, H, S, D] in the activation dtype, which
-        # crosses the flash kernels' boundary. The one rope key is
-        # broadcast to the heads before the kernel, so the broadcast's
-        # gradient sums them.
-        q = jnp.swapaxes(jnp.concatenate([q_nope, q_rope], -1), 1, 2)
-        k = jnp.swapaxes(jnp.concatenate(
-            [k_nope, jnp.broadcast_to(k_rope, q_rope.shape)], -1), 1, 2)
+        # crosses the flash kernels' boundary: q = [q_nope | q_rope turned],
+        # k = [k_nope | the one rope key turned, for every head] (its
+        # gradient sums the heads), v.
+        with jax.named_scope(ROPE_SCOPE):
+            q, k, v = mla_rotary(q_proj, kv_up, k_rope, *tables)
         with jax.named_scope(ATTENTION_SCOPE):
-            out = flash_attention(q, k, jnp.swapaxes(v, 1, 2), True)
+            out = flash_attention(q, k, v, True)
         out = jnp.swapaxes(out, 1, 2).reshape(*x.shape[:2], heads * dv)
         with jax.named_scope(O_SCOPE):
             return nn.Dense(
@@ -223,14 +226,14 @@ class Block(nn.Module):
     index: int
 
     @nn.compact
-    def __call__(self, h):
+    def __call__(self, h, tables):
         cfg = self.config
 
         def norm(name):
             return RMSNorm(cfg.rms_norm_eps, cfg.activation_dtype, name=name)
 
         h = h + LatentAttention(cfg, name="self_attn")(
-            norm("input_layernorm")(h)).astype(h.dtype)
+            norm("input_layernorm")(h), tables).astype(h.dtype)
         u = norm("post_attention_layernorm")(h)
         if self.index < cfg.first_k_dense_replace:
             return h + GatedMLP(cfg, name="mlp")(u).astype(h.dtype), None
@@ -253,6 +256,27 @@ class Block(nn.Module):
         return h + out.astype(h.dtype), stats
 
 
+def _call(module, x, *constants):
+    return module(x, *constants)
+
+
+def _made_together(vjp_fn, cotangents):
+    parameters, x, *constants = vjp_fn(cotangents)
+    return (*jax.lax.optimization_barrier((parameters, x)), *constants)
+
+
+# `module(x, *constants)`, and in the backward pass neither the parameters'
+# cotangents nor x's are used before all of them are made. Left to itself
+# the v5e scheduler puts weight-gradient products layers later than the
+# cotangents they read (the head's three layers on, with the logits'
+# cotangent `bf16[S, vocab]` alive until then, 501 MiB at the cut's shapes;
+# the last layer's at the step's end): 0.76 GiB of the step's resident
+# bytes (PERF.md section 6, PR 58).
+_cotangents_together = nn.custom_vjp(
+    _call, backward_fn=_made_together,
+    forward_fn=lambda module, *inputs: nn.vjp(_call, module, *inputs))
+
+
 class KananaMoe(nn.Module):
     config: KananaMoeConfig = KananaMoeConfig()
 
@@ -264,6 +288,8 @@ class KananaMoe(nn.Module):
                      embedding_init=cfg.init, name="embed_tokens")(
                          tokens.astype(jnp.int32))
         totals = None
+        # One pair of cos and sin tables for every layer.
+        tables = cfg.rope_tables(tokens.shape[1])
         # A rematerialised layer keeps what its flash kernel made (128 MiB
         # of output and 2 MiB of lse at the cut's shapes): q, k and v are
         # projections away, the kernel is a tenth of the step.
@@ -271,14 +297,15 @@ class KananaMoe(nn.Module):
             Block, policy=jax.checkpoint_policies.save_only_these_names(*KEPT))
         for i in range(cfg.num_hidden_layers):
             block_cls = remat_block if i in cfg.remat_layers else Block
-            h, stats = block_cls(cfg, i, name=f"layers_{i}")(h)
+            h, stats = _cotangents_together(
+                block_cls(cfg, i, name=f"layers_{i}"), h, tables)
             if stats is not None:
                 totals = stats if totals is None else jax.tree_util.tree_map(
                     jnp.add, totals, stats)
         h = RMSNorm(cfg.rms_norm_eps, cfg.activation_dtype, name="norm")(h)
-        logits = nn.Dense(
+        logits = _cotangents_together(nn.Dense(
             cfg.vocab_size, use_bias=False, dtype=dtype,
-            kernel_init=cfg.init, name="lm_head")(h).astype(jnp.float32)
+            kernel_init=cfg.init, name="lm_head"), h).astype(jnp.float32)
         if not training:
             return logits
         out = {"logits": logits}

@@ -71,11 +71,12 @@ _LAYER = re.compile(r"^(?:layers|Block)_(\d+)$")
 # What a transform or a control-flow primitive leaves in a name, and no
 # scope of the program's: `jit(f)`, `jvp(M)`, `transpose(jvp(M))`,
 # `checkpoint`, `rematted_computation`, `while`, `body`, `cond`,
-# `branch_1_fun`, `custom_vjp_call`, `shard_map`, `pallas_call` and the like.
+# `branch_1_fun`, `custom_vjp_call`, `shard_map`, `pallas_call` and the like;
+# `layers_1.shared_forward_fn` is flax's lifted `custom_vjp` round a module.
 _WRAPPER = re.compile(
     r"^(?:\w+\(.*\)|checkpoint|rematted_computation|while|body|cond"
     r"|body_fun|cond_fun|branch_\d+_fun|custom_[a-z_]+|pallas_call"
-    r"|closed_call|core_call|shard_map)$")
+    r"|closed_call|core_call|shard_map|\w+\.shared_forward_fn)$")
 
 
 def scope_kinds():

@@ -237,8 +237,9 @@ def test_the_one_rope_key_serves_every_head():
     attention = kanana_moe.LatentAttention(cfg)
     p = variables["params"]["layers_0"]["self_attn"]
     x = jax.random.normal(jax.random.PRNGKey(4), (1, LENGTH, 64))
+    tables = cfg.rope_tables(LENGTH)
     with jax.default_matmul_precision("highest"):
-        got = np.asarray(attention.apply({"params": p}, x))
+        got = np.asarray(attention.apply({"params": p}, x, tables))
         nope, rope, rank = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                             cfg.kv_lora_rank)
         q = jnp.einsum("bsd,dhe->bshe", x, p["q_proj"]["kernel"])[0]
@@ -271,8 +272,8 @@ def test_the_one_rope_key_serves_every_head():
             only = jnp.zeros_like(o_by_head).at[h].set(o_by_head[h])
             part = {**p, "o_proj": {"kernel": only.reshape(-1, 64)}}
             other = {**moved, "o_proj": part["o_proj"]}
-            a = attention.apply({"params": part}, x)
-            b = attention.apply({"params": other}, x)
+            a = attention.apply({"params": part}, x, tables)
+            b = attention.apply({"params": other}, x, tables)
             assert float(jnp.max(jnp.abs(a - b))) > 1e-4, h
 
 

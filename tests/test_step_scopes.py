@@ -137,6 +137,10 @@ def test_the_rows_hold_all_four_phases_and_the_toys_kinds(toy_rows):
     ("jit(step_fn)/transpose(jvp(M))/jvp(M)/checkpoint/"
      "rematted_computation/layers_3/mixer/jit(relu)/max", ss.REMAT, 3,
      "layers_3/mixer"),
+    ("jit(step_fn)/transpose(jvp(M))/transpose(jvp(layers_3._call))/jvp(M)/"
+     "layers_3.shared_forward_fn/jvp(layers_3._call)/checkpoint/"
+     "rematted_computation/layers_3/self_attn/a_scope/mul", ss.REMAT, 3,
+     "layers_3/self_attn/a_scope"),
     ("jit(step_fn)/jvp(M)/Block_11/Dense_0/dot_general;"
      "jit(step_fn)/jvp(M)/Block_11/Dense_0/add", ss.FWD, 11,
      "Block_11/Dense_0"),

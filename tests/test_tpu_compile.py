@@ -1135,7 +1135,8 @@ def test_the_routing_stage_moves_no_single_numbers(request, fixture):
 # change may add before this fails: a kernel whose body is traced a call
 # site, or written in `jnp` operators, adds hundreds (PR 52: 10.6 s of
 # `step_load_s`, PERF.md section 6).
-PARENT_TRACES = {"sdar_cut_one_chip": 3235, "mellum_cut_one_chip": 2221}
+PARENT_TRACES = {"sdar_cut_one_chip": 3235, "mellum_cut_one_chip": 2221,
+                 "kanana_cut_one_chip": 3153}
 TRACES_MARGIN = 40
 
 
@@ -1146,7 +1147,9 @@ def test_the_steps_trace_fires_no_more_traces_than_before_the_qk_kernels(
     2.5 to 4.4 ms of every job's start (PERF.md section 6, PR 44), on a
     compile-cache hit too. With each `qk_rotary` kernel under a jit of its
     own and its body in `lax` primitives the two steps trace fewer jaxprs
-    than with the expression (2 tables a step, not 2 a layer's q and k)."""
+    than with the expression (2 tables a step, not 2 a layer's q and k);
+    so does the Kanana step with `mla_rotary`'s four (PR 58: 3,153 at its
+    parent)."""
     step = request.getfixturevalue(fixture)
     ceiling = PARENT_TRACES[fixture] + TRACES_MARGIN
     assert step.traces <= ceiling, (
@@ -1490,9 +1493,42 @@ def test_kanana_cut_step_compiles_and_fits_one_v5e(kanana_cut_one_chip):
     each keep their attention's output (128 MiB) and compact lse (2 MiB)
     by the names of `flash_attention.KEPT`: 14.87 GiB where the
     configuration file's `model.remat_reason` read 14.49 with nothing
-    kept; and hands six counters back beside the loss."""
+    kept, and no more since (PR 58: 14.83); what lies between the projections and those kernels is
+    `ops/mla_rotary.py`'s two passes each way (PR 58) and none of the
+    fusions and copies it was; and hands six counters back beside the
+    loss."""
     step = kanana_cut_one_chip
     assert step.out_tree.children()[2].num_leaves == 7  # the loss and six
+    # q's pass and k's and v's: six layers forward, five of them again
+    # under remat (q, k and v are what a rematerialised layer recomputes),
+    # six backward.
+    assert {name: len(_kernel_calls(step.text, f"mla_rotary_{name}"))
+            for name in ("q_fwd", "kv_fwd", "q_bwd", "kv_bwd")} == {
+                "q_fwd": 11, "kv_fwd": 11, "q_bwd": 6, "kv_bwd": 6}
+    assert _kernel_calls(step.text, "mla_rotary_q_fwd")[0] == (
+        "bf16[1,32,16384,192]",
+        ["bf16[1,16384,6144]", "bf16[2,128,128]", "f32[1,16384,128]",
+         "f32[1,16384,128]"])
+    assert _kernel_calls(step.text, "mla_rotary_kv_bwd")[0] == (
+        "(bf16[1,16384,8192], bf16[1,16384,64])",
+        ["bf16[1,32,16384,192]", "bf16[1,32,16384,128]", "bf16[2,128,128]",
+         "f32[1,16384,128]", "f32[1,16384,128]"])
+    # Under the rope's scope nothing is left but those calls, their
+    # results' pieces and what the compiler moves for them: none of the
+    # slices, float32 turns, joins, broadcasts and copies over
+    # [16384, 32, w] the stage was as XLA's (PERF.md section 6, PR 58).
+    under_rope = [m for m in map(_HLO_OP.match, step.text.split("\n"))
+                  if m and "kanana_rope" in m.string]
+    assert len(under_rope) >= 34
+    assert {m.group(2) for m in under_rope} <= {
+        "custom-call", "get-tuple-element", "copy", "constant",
+        "copy-start", "copy-done", "bitcast"}, sorted(
+            {m.group(2) for m in under_rope})
+    for gone in ("f32[32,8192,32]", "f32[8192,32,64]", "f32[1,16384,32,64]",
+                 "f32[1,16384,32,32]", "f32[32,16384,32]",
+                 "f32[16384,32,64]", "bf16[1,16384,32,64]",
+                 "bf16[16384,32,64]"):
+        assert gone not in step.text, gone
     calls = _kernel_calls(step.text, "flash_")
     assert calls == _kernel_calls(step.text, "mla_flash_")
     # Six layers, a forward and a backward each: no rematerialised layer
@@ -1508,9 +1544,25 @@ def test_kanana_cut_step_compiles_and_fits_one_v5e(kanana_cut_one_chip):
             "bf16[32,16384,192]", "bf16[32,16384,128]",
             "f32[32,16384,128]"}
     assert step.resident < HBM_BYTES, f"{step.resident / 2**30:.2f} GiB"
-    # The chip gives a program 15.75 GiB: what is kept leaves 0.88 of it
-    # (AOT reads 15,971,736,576 B; 64 MiB allowed above that).
+    # The chip gives a program 15.75 GiB. AOT read 15,971,736,576 B (14.87
+    # GiB) before PR 58 and reads 15,927,114,752 (14.83) with it; 64 MiB
+    # allowed above the parent's reading, as PR 56 set it. It holds because
+    # every layer and the head hand their cotangents out together
+    # (`kanana_moe._cotangents_together`, one barrier each in the backward
+    # pass): without them the scheduler puts the head's weight gradient
+    # three layers' backward later, the logits' cotangent `bf16[1,16032,
+    # 16384]` (501 MiB) alive until then, and the last layer's to the
+    # step's end, and the step reads 15.59 GiB (PERF.md section 6, PR 58).
     assert step.resident < 14.94 * 2**30, f"{step.resident / 2**30:.2f} GiB"
+    # One barrier a gradient leaf, the routed layers' own over one flat
+    # array each, `jax.checkpoint`'s over a rematerialised layer's 19
+    # inputs, and the model's: the head's kernel and input, a layer's
+    # parameters (10 in the dense layer, 12 in a routed one) and input.
+    barriers = [operands.count("tensor<") for operands in re.findall(
+        r"optimization_barrier [^\n]*: (tensor<[^\n]*)", step.lowered)]
+    assert sorted(n for n in barriers if n > 1) == (
+        [2, 11] + 5 * [13] + 5 * [19])
+    assert barriers.count(1) == step.n_grad_leaves + 20
     # params + Adam m and v
     assert step.argument_bytes > 8.2e9
     assert {"f32[16,2048,1536]", "f32[16,768,2048]", "f32[128,2048]",
@@ -1549,15 +1601,20 @@ UNNAMED_OPCODES = {
 }
 # The Pallas calls, by the name each `pallas_call` was given, and the kind
 # and the passes the map has to put them in. The head norm and rotary
-# turn's calls (`ops/qk_rotary.py`) are the attention's and no attention
-# call: `attention_kernel` is `ops/flash_attention.py`'s alone, so that its
-# share is what the by-name readers of the flash calls read.
+# turn's calls (`ops/qk_rotary.py`) and the latent attention's passes
+# (`ops/mla_rotary.py`) are the attention's and no attention call:
+# `attention_kernel` is `ops/flash_attention.py`'s alone, so that its share
+# is what the by-name readers of the flash calls read.
 _HLO_NAME = re.compile(r"\s*(?:ROOT )?%(\S+) = ")
 PALLAS_CALLS = {
     "flash_fwd": ("attention_kernel", {"fwd", "remat"}),
     "flash_bwd": ("attention_kernel", {"bwd"}),
     "qk_rotary_fwd": ("attention", {"fwd", "remat"}),
     "qk_rotary_bwd": ("attention", {"bwd"}),
+    "mla_rotary_q_fwd": ("attention", {"fwd", "remat"}),
+    "mla_rotary_kv_fwd": ("attention", {"fwd", "remat"}),
+    "mla_rotary_q_bwd": ("attention", {"bwd"}),
+    "mla_rotary_kv_bwd": ("attention", {"bwd"}),
     "ssd_scan_fwd": ("mixer", {"fwd", "remat"}),
     "ssd_scan_bwd": ("mixer", {"bwd"}),
     "causal_conv_fwd": ("mixer", {"fwd", "remat"}),
@@ -1618,3 +1675,16 @@ def test_the_steps_scopes_tell_every_kernel_kind_and_pass(request, fixture):
         assert "kanana_latent_attention" not in again
         assert {"kanana_q_proj", "kanana_kv_down", "kanana_kv_up",
                 "kanana_rope", "kanana_o_proj", "mlp"} <= again
+        # PR 58's passes through the map: under the rope's scope, the
+        # forward ones in every layer and again in the rematerialised.
+        passes = {}
+        for r in calls:
+            scope = r["scope"].split("/")
+            if scope[-1].startswith("mla_rotary_"):
+                assert scope[1:3] == ["self_attn", "kanana_rope"], r
+                passes.setdefault(scope[-1], []).append(r["phase"])
+        assert {k: sorted(set(v)) for k, v in passes.items()} == {
+            "mla_rotary_q_fwd": ["fwd", "remat"],
+            "mla_rotary_kv_fwd": ["fwd", "remat"],
+            "mla_rotary_q_bwd": ["bwd"], "mla_rotary_kv_bwd": ["bwd"]}
+        assert sorted(map(len, passes.values())) == [6, 6, 11, 11]
